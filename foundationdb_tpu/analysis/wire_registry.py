@@ -59,7 +59,7 @@ _EXPAND = {
 }
 
 #: except-clause types that count as classifying a wire RPC's failure
-#: (wire.unclassified-error): the transport taxonomy, the asyncio/OS
+#: (wire.unclassified-error): the transport classification, the asyncio/OS
 #: errors a call can surface, and the broad catches control-plane
 #: callers use deliberately. CancelledError alone is NOT classification.
 CLASSIFIER_LEAVES = {

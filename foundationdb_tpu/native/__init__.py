@@ -35,14 +35,20 @@ def build_shared(src: str, stem: str) -> str:
     """Compile src into a content-hash-named .so and return its path.
 
     Hash-named outputs mean a library on disk can never be stale relative
-    to its source OR its build flags — a fresh clone always compiles (no
-    binaries are committed; ADVICE r1: an mtime check let a checked-in
-    .so shadow the source it was supposed to be built from).
+    to its source, its build flags OR the host CPU — a fresh clone always
+    compiles (no binaries are committed; ADVICE r1: an mtime check let a
+    checked-in .so shadow the source it was supposed to be built from),
+    and a copy of the tree on another host builds its own `-march=native`
+    library instead of loading one that may use ISA extensions its CPU
+    lacks (SIGILL).
     """
+    from foundationdb_tpu.utils.compile_cache import host_fingerprint
+
     flags = ["-O3", "-march=native", "-std=c++17", "-shared", "-fPIC"]
     with open(src, "rb") as f:
         hasher = hashlib.sha256(f.read())
     hasher.update(" ".join(flags).encode())
+    hasher.update(host_fingerprint().encode())
     digest = hasher.hexdigest()[:16]
     out = os.path.join(_DIR, f"{stem}-{digest}.so")
     if os.path.exists(out):

@@ -41,26 +41,12 @@ from foundationdb_tpu.utils import packing
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """`shard_map` across jax versions (>= 0.5 promoted it out of
-    experimental and renamed check_rep -> check_vma). Replication
-    checking is OFF: the group kernel's residual while_loop has no
-    replication rule, and every output's cross-shard agreement is
-    established explicitly by the pmin/psum combines."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        # the transition generation: promoted to jax.shard_map but the
-        # flag still has its experimental name
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """`jax.shard_map` with replication checking OFF: the group
+    kernel's residual while_loop has no replication rule, and every
+    output's cross-shard agreement is established explicitly by the
+    pmin/psum combines."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class ShardedVerdict(NamedTuple):

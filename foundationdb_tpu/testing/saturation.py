@@ -47,15 +47,10 @@ DEFAULTS = {
 
 def load_saturation_config(spec_name: str = "saturation") -> dict:
     """The `[saturation]` table of a spec file, over DEFAULTS."""
-    import tomli
-
-    from foundationdb_tpu.testing.spec import SPEC_DIR
+    from foundationdb_tpu.testing.spec import load_toml
 
     cfg = dict(DEFAULTS)
-    path = SPEC_DIR / f"{spec_name}.toml"
-    if path.exists():
-        with open(path, "rb") as f:
-            cfg.update(tomli.load(f).get("saturation", {}))
+    cfg.update((load_toml(spec_name) or {}).get("saturation", {}))
     return cfg
 
 

@@ -338,22 +338,30 @@ def list_specs() -> list[str]:
     return sorted(p.stem for p in SPEC_DIR.glob("*.toml"))
 
 
+def load_toml(name: str) -> dict | None:
+    """The parsed testing/specs/<name>.toml, or None when there is no
+    such file."""
+    import tomllib
+
+    path = SPEC_DIR / f"{name}.toml"
+    if not path.exists():
+        return None
+    with open(path, "rb") as f:
+        return tomllib.load(f)
+
+
 def load_spec(name) -> SoakSpec:
     """Load a named spec (or pass a SoakSpec through unchanged)."""
     if isinstance(name, SoakSpec):
         return name
-    import tomli
-
-    path = SPEC_DIR / f"{name}.toml"
-    if not path.exists():
+    d = load_toml(name)
+    if d is None:
         raise SpecError(
             f"no such spec {name!r}; checked in: {list_specs()}"
         )
-    with open(path, "rb") as f:
-        d = tomli.load(f)
     if d.get("name") != name:
         raise SpecError(
-            f"spec file {path.name} declares name={d.get('name')!r}; "
+            f"spec file {name}.toml declares name={d.get('name')!r}; "
             f"the name must match the file stem"
         )
     return SoakSpec.from_dict(d)

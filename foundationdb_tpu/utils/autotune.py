@@ -228,7 +228,12 @@ def run_search(
     cache_hits = ran = since_improve = 0
     stopped = "exhausted"
     roofline = frac = None
-    fingerprint = perf.device_fingerprint()
+    # only a device-scoped cache compares fingerprints; take it in a
+    # child, so this parent never holds the chip its trials need
+    fingerprint = (
+        perf.device_fingerprint_from_child() if cache_scope == "device"
+        else None
+    )
     history = perf.load_history(ledger)
     for knobs in space.points():
         key = trial_key(knobs)

@@ -2,7 +2,7 @@
 
 The driver re-runs bench.py in a fresh process every round; without a
 persistent cache each run re-pays the full trace+compile of the resolver
-kernel (137s at 64K-txn shapes in BENCH_r01.json). JAX's persistent
+kernel (minutes at 64K-txn shapes). JAX's persistent
 cache keys on (HLO, compile options, backend version), so a warm cache
 drops that to de/serialization time.
 """
@@ -16,8 +16,11 @@ from foundationdb_tpu.utils.probes import code_probe, declare
 
 declare("perf.compile_cache_miss")
 
-_BASE = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+#: the fixed cache dir when JAX_COMPILATION_CACHE_DIR is not set — the
+#: path is part of JAX's cache key, so it must not move between runs
+DEFAULT_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), ".jax_compile_cache")
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 
 def _host_feature_lines() -> str:
@@ -42,29 +45,13 @@ def _host_feature_lines() -> str:
     return "|".join(sorted(lines)) or platform.processor()
 
 
-def _machine_tag() -> str:
-    """Short hash of the host's CPU identity.
-
-    XLA:CPU cache entries embed AOT machine code; loading an entry
-    compiled on a host with different ISA features risks SIGILL (the
-    loader only warns). The container this repo lives in migrates
-    between hosts across rounds, so the cache dir is keyed per-machine.
-    """
-    import hashlib
-    import platform
-
-    return hashlib.md5(
-        (platform.machine() + ":" + _host_feature_lines()).encode()
-    ).hexdigest()[:8]
-
-
-_DEFAULT = _BASE + "." + _machine_tag()
-
 #: sentinel recording which host populated a cache dir (the scrub key)
 _FINGERPRINT_NAME = "HOST_FINGERPRINT"
 
 
-def _host_fingerprint() -> str:
+def host_fingerprint() -> str:
+    """Hash of this host's CPU identity (ISA feature lines + model
+    name): the key for anything holding host machine code."""
     import hashlib
     import platform
 
@@ -78,14 +65,14 @@ def scrub_on_host_mismatch(path: str) -> bool:
     fingerprint doesn't match THIS host; stamp the current fingerprint
     either way. Returns whether a scrub happened.
 
-    The dir-name tag can't protect a pinned dir ($FDBTPU_COMPILE_CACHE)
-    or a dir baked into a migrating container: loading another
+    The cache dir is fixed, so a copy of the repo that moves to another
+    host carries its cache along: loading another
     machine's XLA:CPU AOT entries spams machine-feature-mismatch errors
     on stderr — which polluted the multichip lane's JSON `tail`
     (MULTICHIP_r05) — and risks SIGILL. Scrubbing trades one warm cache
     for a clean, safe run on the new host."""
     marker = os.path.join(path, _FINGERPRINT_NAME)
-    want = _host_fingerprint()
+    want = host_fingerprint()
     try:
         with open(marker) as f:
             have = f.read().strip()
@@ -136,20 +123,26 @@ def scrub_on_host_mismatch(path: str) -> bool:
 def enable(path: str | None = None) -> str:
     """Turn on the persistent compilation cache; returns the cache dir.
 
-    Safe to call multiple times and before/after backend init (the cache
-    is consulted at compile time, not backend-init time). Also arms the
-    compile-observability listeners (`instrument()`), so every enabled
-    process carries hit/miss counters and compile seconds in `stats()`.
-    A dir whose recorded host fingerprint mismatches this machine is
-    scrubbed first (see `scrub_on_host_mismatch`) — stale cross-host
-    XLA:CPU AOT entries must never load.
+    When JAX_COMPILATION_CACHE_DIR is set, JAX already reads its cache
+    dir from it and this leaves the dir alone (no override, no scrub).
+    Otherwise the cache goes to `path` or the fixed DEFAULT_DIR, after
+    a scrub of entries another host wrote (see
+    `scrub_on_host_mismatch`). Safe to call multiple times and
+    before/after backend init (the cache is consulted at compile time).
+    Also arms the compile-observability listeners (`instrument()`), so
+    every enabled process carries hit/miss counters and compile seconds
+    in `stats()`.
     """
     import jax
 
-    path = path or os.environ.get("FDBTPU_COMPILE_CACHE", _DEFAULT)
-    os.makedirs(path, exist_ok=True)
-    scrub_on_host_mismatch(path)
-    jax.config.update("jax_compilation_cache_dir", path)
+    env_dir = os.environ.get(ENV_DIR)
+    if env_dir:
+        path = env_dir
+    else:
+        path = path or DEFAULT_DIR
+        os.makedirs(path, exist_ok=True)
+        scrub_on_host_mismatch(path)
+        jax.config.update("jax_compilation_cache_dir", path)
     # Cache everything: the kernel's many specializations are each well
     # over the default thresholds anyway, and tiny entries are harmless.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)

@@ -15,7 +15,7 @@ as a view. A ledger row is self-describing:
 * `fingerprint` — the host/device identity a comparator needs to avoid
   comparing a CPU-host structural run against a v5e hardware run:
   backend, device kind/count, jax/jaxlib versions, python, machine.
-  (BENCH_r01..r06 recorded only `backend`, so CPU-host and v5e rows
+  (BENCH_r06 recorded only `backend`, so CPU-host and v5e rows
   were indistinguishable — the r10 satellite this field set fixes.)
 * `workload` — the shapes that make two runs comparable (txns, batches,
   mode, spec, seeds, ...).
@@ -124,6 +124,23 @@ def device_fingerprint() -> dict:
     except Exception:
         pass
     return fp
+
+
+def device_fingerprint_from_child() -> dict:
+    """device_fingerprint() taken in a child process, which lets go of
+    the chip when it exits: for a parent whose own subprocesses need
+    the chip (a process that touched the TPU holds it until it exits)."""
+    import subprocess
+    import sys
+
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import json; from foundationdb_tpu.utils import perf; "
+         "print(json.dumps(perf.device_fingerprint()))"],
+        cwd=_REPO_ROOT, capture_output=True, text=True, timeout=300,
+        check=True,
+    )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def _git_sha() -> Optional[str]:

@@ -309,10 +309,26 @@ class RpcConnection:
         self._loop: asyncio.AbstractEventLoop | None = None
         self._census_live = False  # tracked in census.CONNECTIONS
 
-    async def connect(self, *, retries: int = 50, delay: float = 0.1) -> None:
+    async def connect(self, *, retries: int = 50, delay: float = 0.1,
+                      alive=None) -> None:
+        """Connect, retrying every `delay` seconds while the peer is not
+        listening yet: up to `retries` attempts or, when `alive` is
+        given, for as long as `alive()` says the serving process still
+        runs."""
         last = None
         ssl_ctx = self.tls.client_context() if self.tls else None
-        for _ in range(retries):
+        attempt = 0
+        while True:
+            if alive is not None and not alive():
+                raise TransportError(
+                    f"cannot connect to {self.address}: its process "
+                    f"exited ({last})"
+                )
+            if alive is None and attempt >= retries:
+                raise TransportError(
+                    f"cannot connect to {self.address}: {last}"
+                )
+            attempt += 1
             try:
                 if isinstance(self.address, str):
                     self._reader, self._writer = await asyncio.open_unix_connection(
@@ -335,8 +351,6 @@ class RpcConnection:
             except (ConnectionError, FileNotFoundError, OSError) as e:
                 last = e
                 await asyncio.sleep(delay)
-        else:
-            raise TransportError(f"cannot connect to {self.address}: {last}")
         if self.tls is not None:
             # verify_peers-style subject check on the SERVER cert
             try:

@@ -51,8 +51,6 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: bench.py's documented env-knob surface (the "path" pseudo-knob picks

@@ -33,9 +33,9 @@ async def run(n_clients: int, n_ops: int, backend: str) -> None:
             mp.spawn_role("storage", sock_dir),
         ]
         try:
-            resolver = await mp.connect(procs[0].address)
-            tlog = await mp.connect(procs[1].address)
-            storage = await mp.connect(procs[2].address)
+            resolver = await mp.connect(procs[0])
+            tlog = await mp.connect(procs[1])
+            storage = await mp.connect(procs[2])
             pipe = mp.ProxyPipeline(
                 [resolver], tlog, storage, batch_interval=0.001, max_batch=4096
             )

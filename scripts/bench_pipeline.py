@@ -218,12 +218,12 @@ async def _run_wire(backend: str, args) -> dict:
                 + [os.path.join(sock_dir, "proxy0.sock")],
             ))
         try:
-            resolver = await mp.connect(procs[0].address)
-            tlog = await mp.connect(procs[1].address)
-            storage = await mp.connect(procs[2].address)
+            resolver = await mp.connect(procs[0])
+            tlog = await mp.connect(procs[1])
+            storage = await mp.connect(procs[2])
             seq_conn = None
             if seq_proc is not None:
-                seq_conn = await mp.connect(seq_proc.address)
+                seq_conn = await mp.connect(seq_proc)
                 # boot the resolver's version chain at the sequencer's
                 # recovery version (what the controller's recovery walk
                 # does) so the first grant's prev_version resolves
@@ -236,7 +236,7 @@ async def _run_wire(backend: str, args) -> dict:
                 )
             rk_conn = None
             if getattr(args, "ratekeeper", False):
-                rk_conn = await mp.connect(procs[-1].address)
+                rk_conn = await mp.connect(procs[-1])
             # resolve-hop frame A/B (r12): --resolve-path pins the
             # columnar vs object frame per run; None = RESOLVE_COLUMNAR
             # env default (columnar)
@@ -260,6 +260,8 @@ async def _run_wire(backend: str, args) -> dict:
             stats = {"committed": 0, "conflicted": 0, "reads": 0,
                      "grv_throttled": 0}
             committed_by_key: dict[bytes, int] = {}
+            #: (key, commit version, value written) per acknowledged RMW
+            acked: list[tuple[bytes, int, bytes]] = []
             lat: list[float] = []
 
             async def grv():
@@ -314,7 +316,10 @@ async def _run_wire(backend: str, args) -> dict:
                                     _cdbg.COMMIT_BEFORE,
                                 )
                             try:
-                                await pipe.commit(txn)
+                                cv = await pipe.commit(txn)
+                                acked.append(
+                                    (key, cv, txn.mutations[0].param2)
+                                )
                                 if trace_dir:
                                     _tr.g_trace_batch.add_event(
                                         "CommitDebug", txn.debug_id,
@@ -348,6 +353,17 @@ async def _run_wire(backend: str, args) -> dict:
                 assert got.get(key, 0) == cnt, (
                     f"{key}: storage={got.get(key, 0)} committed={cnt}"
                 )
+            # every acknowledged write reads back at its own commit
+            # version: a later increment of the key lands at a later
+            # version, and a same-batch one would have conflicted
+            readback = await asyncio.gather(
+                *(pipe.read(key, cv) for key, cv, _ in acked)
+            )
+            for (key, cv, value), got_v in zip(acked, readback):
+                assert got_v == value, (
+                    f"{key}@{cv}: storage={got_v!r} acknowledged={value!r}"
+                )
+            stats["acked_read_back"] = len(acked)
 
             # columnar-vs-object structural accounting from the resolver
             # role (status qos.resolve_path): full key-data copies per
@@ -358,7 +374,11 @@ async def _run_wire(backend: str, args) -> dict:
             st = await resolver.call(
                 mp.TOKEN_STATUS, mp.StatusRequest(pad=0)
             )
-            ps = json.loads(st.payload)["qos"]["resolve_path"]
+            rqos = json.loads(st.payload)["qos"]
+            ps = rqos["resolve_path"]
+            # what served the resolves: conflict set, JAX backend and
+            # device kind, plus the kernel's dispatch/compile counters
+            stats["resolver_kernel"] = rqos["kernel"]
             n_batches = ps["columnar_batches"] + ps["object_batches"]
             stats["resolve_copies_per_batch"] = round(
                 ps["copies"] / max(1, n_batches), 3

@@ -25,7 +25,7 @@ Two tiers:
   and latencies inside median +/- max(4*1.4826*MAD, 5%).
 
 The migration half (`--import`): converts the historical root artifacts
-(BENCH_r01..r06.json, PIPELINE_r06/r07.json, SATURATION_r08.json,
+(BENCH_r06.json, PIPELINE_r06/r07.json, SATURATION_r08.json,
 MULTICHIP_r0*.json) into schema rows — `schema_version` stamped,
 `timestamp: null`, `imported_from` naming the artifact — and writes
 them to perf/history.jsonl. The conversion is BYTE-STABLE: re-running

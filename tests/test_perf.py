@@ -574,7 +574,7 @@ def test_perfcheck_scaling_splits_on_knobs(tmp_path):
     assert '"delta_capacity": 512' not in r.stdout
 
 
-def test_compile_cache_scrub_on_host_mismatch(tmp_path):
+def test_compile_cache_scrub_on_host_mismatch(tmp_path, monkeypatch):
     """A persistent-cache dir stamped by a DIFFERENT host — or holding
     entries with NO stamp at all (a container baked before the marker
     existed: it cannot be proven local) — is scrubbed, so stale
@@ -588,7 +588,7 @@ def test_compile_cache_scrub_on_host_mismatch(tmp_path):
     marker = d / "HOST_FINGERPRINT"
     # empty unstamped dir: stamp, nothing to scrub
     assert cc.scrub_on_host_mismatch(str(d)) is False
-    assert marker.read_text().strip() == cc._host_fingerprint()
+    assert marker.read_text().strip() == cc.host_fingerprint()
     # this host's stamp: untouched
     (d / "entry_a").write_bytes(b"aot blob")
     assert cc.scrub_on_host_mismatch(str(d)) is False
@@ -598,7 +598,7 @@ def test_compile_cache_scrub_on_host_mismatch(tmp_path):
     marker.unlink()
     assert cc.scrub_on_host_mismatch(str(d)) is True
     assert not (d / "entry_a").exists()
-    assert marker.read_text().strip() == cc._host_fingerprint()
+    assert marker.read_text().strip() == cc.host_fingerprint()
     # another host's stamp: entries scrubbed, marker re-stamped
     (d / "entry_a").write_bytes(b"aot blob")
     (d / "subdir").mkdir()
@@ -607,8 +607,9 @@ def test_compile_cache_scrub_on_host_mismatch(tmp_path):
     assert cc.scrub_on_host_mismatch(str(d)) is True
     assert not (d / "entry_a").exists()
     assert not (d / "subdir").exists()
-    assert marker.read_text().strip() == cc._host_fingerprint()
+    assert marker.read_text().strip() == cc.host_fingerprint()
     # enable() routes through the scrub and still configures the cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     marker.write_text("0" * 32 + "\n")
     (d / "entry_c").write_bytes(b"stale")
     path = cc.enable(str(d))

@@ -1,7 +1,7 @@
 """RESOLVER_TPU_MIN_BATCH is the MEASURED routing crossover, not a guess.
 
-VERDICT r4 task 3. The sweep (scripts/sweep_small.py on the real v5e,
-logs sweep_small_r5*.log) measured single-dispatch throughput per batch
+VERDICT r4 task 3. The round-5 sweep (scripts/sweep_small.py on a v5e,
+classic kernel) measured single-dispatch throughput per batch
 size; the device first beats the CPU skiplist at n=65536 (347K vs 338K
 txn/s device-resident; below that the CPU wins by 2-40x). This test
 pins (a) the knob default to that measurement and (b) the
