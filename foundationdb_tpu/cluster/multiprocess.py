@@ -33,6 +33,11 @@ import sys
 from typing import Any, Optional
 
 from foundationdb_tpu.cluster.grv_proxy import GrvThrottledError  # noqa: F401
+from foundationdb_tpu.models.conflict_base import (
+    STAGE_NAMES,
+    KernelStageMetrics,
+)
+from foundationdb_tpu.utils.metrics import stage
 from foundationdb_tpu.utils.probes import code_probe, declare
 from foundationdb_tpu.models.types import (
     CommitTransaction,
@@ -686,6 +691,11 @@ class ResolverRole:
         self.queue_wait_latency = LatencySample("queueWaitLatency")
         self.compute_time = LatencySample("computeTime")
         self.resolver_latency = LatencySample("resolverLatency")
+        # the per-batch stages the role times itself (STAGE_NAMES); the
+        # conflict set's stages are in its KernelStageMetrics
+        self.decode_time = LatencySample("decodeSeconds")
+        self.key_sample_time = LatencySample("keySampleSeconds")
+        self.encode_time = LatencySample("encodeSeconds")
         # busy-fraction smoother (the Ratekeeper's resolver-occupancy
         # input): compute seconds accumulate as a rate — a resolver
         # spending ~every wall second inside _resolve_now reads ~1.0.
@@ -694,9 +704,6 @@ class ResolverRole:
         # blocking compute keeps arrivals out of the parked count.
         self.occupancy = TimerSmoother(2.0)
         if backend == "native":
-            from foundationdb_tpu.models.conflict_set import (
-                KernelStageMetrics,
-            )
             from foundationdb_tpu.native import NativeSkipListConflictSet
 
             self._cs = NativeSkipListConflictSet(window=window)
@@ -746,11 +753,8 @@ class ResolverRole:
                 )
 
                 ensure_host_device_count(kcfg.n_shards)
-            from foundationdb_tpu.models.conflict_set import (
-                KernelStageMetrics,
-                TpuConflictSet,
-                make_conflict_set,
-            )
+            from foundationdb_tpu.models.conflict_set import TpuConflictSet
+            from foundationdb_tpu.models.conflict_base import make_conflict_set
 
             self._cs = make_conflict_set(kcfg, backend)
             self._kernel_metrics = (
@@ -787,7 +791,7 @@ class ResolverRole:
 
         import jax
 
-        from foundationdb_tpu.models.conflict_set import make_conflict_set
+        from foundationdb_tpu.models.conflict_base import make_conflict_set
 
         t0 = _time.perf_counter()
         scratch = make_conflict_set(kcfg, backend)
@@ -848,10 +852,15 @@ class ResolverRole:
         # it — one trace spanning proxy and resolver OS processes.
         span = None
         if req.span is not None:
+            import time as _time
+
             from foundationdb_tpu.utils.spans import Span, SpanContext
 
+            # the wall clock of the role's trace sink (main), so the
+            # span lines up with the other roles' files
             span = Span(
-                "Resolver.resolveBatch", parent=SpanContext(*req.span)
+                "Resolver.resolveBatch", parent=SpanContext(*req.span),
+                clock=_time.time,
             ).attribute("Version", req.version)
         if req.debug_id is not None:
             from foundationdb_tpu.utils import commit_debug as _cdbg
@@ -1008,7 +1017,8 @@ class ResolverRole:
     def _resolve_now(self, req) -> ResolveTransactionBatchReply:
         columnar = isinstance(req, codec.ResolveBatchColumnar)
         stats = self.path_stats
-        self._note_key_sample(req)
+        with stage(self.key_sample_time, STAGE_NAMES["key_sample"]):
+            self._note_key_sample(req)
         if columnar:
             stats["columnar_batches"] += 1
             stats["txns"] += req.cols.n_txns
@@ -1065,6 +1075,17 @@ class ResolverRole:
             debug_id=req.debug_id,
         )
 
+    def wire_stages(self) -> tuple:
+        """The (decode, encode) stage timers RpcServer.register takes
+        for TOKEN_RESOLVE: a resolve request's frame decode and its
+        reply's encode, on the role's event loop."""
+        import functools
+
+        return (
+            functools.partial(stage, self.decode_time, STAGE_NAMES["decode"]),
+            functools.partial(stage, self.encode_time, STAGE_NAMES["encode"]),
+        )
+
     def status(self) -> dict:
         """StatusRequest payload: role kind, version, and the qos
         sensor block (the four reference distributions + kernel
@@ -1082,6 +1103,18 @@ class ResolverRole:
         # the role-owned block (compute seconds + process-global
         # compile-cache counters)
         qos["kernel"] = {**self._kernel_metrics.qos(), **self._device_info}
+        # per-batch stage samples in STAGE_NAMES order, then the bytes
+        # each batch hands the device (the benchmark's per-layer readers)
+        stages = {
+            "decode": self.decode_time.as_dict(),
+            "key_sample": self.key_sample_time.as_dict(),
+            "encode": self.encode_time.as_dict(),
+            **self._kernel_metrics.stages(),
+        }
+        qos["stages"] = {
+            k: stages[k]
+            for k in (*STAGE_NAMES, "arg_bytes", "device_arg_bytes")
+        }
         # columnar-vs-object frame accounting (r12): bench_pipeline
         # reads this to land the structural copy/alloc metrics
         qos["resolve_path"] = dict(self.path_stats)
@@ -4408,7 +4441,7 @@ async def _serve_role(
         )
     if role_name == "resolver":
         role = ResolverRole(backend=backend)
-        server.register(TOKEN_RESOLVE, role.resolve)
+        server.register(TOKEN_RESOLVE, role.resolve, stages=role.wire_stages())
 
         async def rv(req: RoleVersionReq) -> RoleVersionReply:
             return RoleVersionReply(version=role.version)

@@ -26,7 +26,6 @@ for txns the history phase didn't already condemn (:880-899).
 
 from __future__ import annotations
 
-import dataclasses
 import time
 
 import jax
@@ -34,11 +33,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from foundationdb_tpu.config import KernelConfig
+from foundationdb_tpu.models.conflict_base import (
+    STAGE_NAMES,
+    BatchResult,
+    HistoryOverflowError,
+    KernelStageMetrics,
+)
 from foundationdb_tpu.models.types import CommitTransaction, TransactionResult
 from foundationdb_tpu.ops import conflict as C
 from foundationdb_tpu.ops import history as H
 from foundationdb_tpu.utils import packing
-from foundationdb_tpu.utils.metrics import CounterCollection, LatencySample
+from foundationdb_tpu.utils.metrics import stage
 from foundationdb_tpu.utils.probes import code_probe, declare
 
 # ISSUE 14 rare-path coverage: the range-scan sweep probe actually
@@ -49,198 +54,6 @@ declare("resolver.range_sweep", "resolver.delta_spill")
 
 # Rebase when offsets pass 2**30 (window is ~5e6; huge safety margin).
 REBASE_THRESHOLD = 1 << 30
-
-
-class KernelStageMetrics:
-    """Always-on per-stage telemetry for the resolver kernel.
-
-    First-class `LatencySample`/`CounterCollection` metrics emitted
-    continuously from the resolve paths — pack / transfer / kernel /
-    fence stage timings, tier occupancy, compaction cadence, dedup
-    latch and exact-kernel fallback counts, overflow events. bench.py's
-    ablation ledger and `cluster_status()`'s `resolver.kernel` section
-    are READERS of this object; neither carries private timers.
-
-    Timing semantics: stage samples are host wall-clock seconds.
-    "kernel" covers the jitted dispatch call (on asynchronous backends
-    that is issue time; the fenced remainder lands in "fence" when the
-    caller syncs through this module). Counters are event counts and
-    deterministic per run; the periodic trace_counters flush ships only
-    those, so traced simulation output stays bit-reproducible.
-    """
-
-    def __init__(self):
-        self.counters = CounterCollection(
-            "ResolverKernelMetrics",
-            [
-                "resolveBatches",
-                "groupDispatches",
-                "columnarBatches",
-                "stagedChunks",
-                "compactions",
-                # pressure-driven delta->MAIN folds (delta_spill): the
-                # compactions counter includes these; spills counts the
-                # pressure-triggered subset — the "no raise, no host
-                # re-dispatch" accounting the ISSUE-14 gate pins
-                "spills",
-                # overflow-check syncs where the measured live delta
-                # occupancy tightened the host-side spill bound (ISSUE
-                # 15 — the PR-14 headroom (b) fix: real occupancy, not
-                # the 2*max_writes worst case, drives pressure spills)
-                "spillBoundAnchors",
-                # groups dispatched through the sorted-endpoint sweep
-                # probe (range_sweep) — the range-path structural count
-                "sweepGroups",
-                "latchTrips",
-                "exactFallbacks",
-                "rebases",
-                "overflowRaised",
-                "warmCompiles",
-            ],
-        )
-        # warm-compile / first-dispatch seconds (ResolverRole startup
-        # prewarm records here so a compile stall is attributed to
-        # startup, never hidden inside the first batch's commit latency)
-        self.compile = LatencySample("compileSeconds")
-        self.pack = LatencySample("packSeconds")
-        self.transfer = LatencySample("transferSeconds")
-        self.kernel = LatencySample("kernelSeconds")
-        self.fence = LatencySample("fenceSeconds")
-        # tier occupancy (tiered kernel): live boundary rows per tier,
-        # sampled at the overflow-check syncs (no extra device fences).
-        # On a MESH-SHARDED instance the samples are the WORST shard's
-        # counts (per-shard tiers fill independently; the panel wants
-        # the one closest to overflow).
-        self.delta_occupancy = LatencySample("deltaLiveBoundaries")
-        self.main_occupancy = LatencySample("mainLiveBoundaries")
-        # mesh-sharded kernel (ISSUE 11): shard count + the measured
-        # per-group collective (pmin/psum combine) seconds, sampled
-        # from the combine-only probe program on the overflow-check
-        # syncs — the fdbtop kernel panel's per-shard columns
-        self.shard_count = 1
-        self.collective = LatencySample("collectiveSeconds")
-        # device-memory gauges (ISSUE 10): live-buffer + peak bytes on
-        # the dispatch device, sampled on the same overflow-check syncs
-        # (no extra fences); zero on backends that don't report (CPU)
-        self.device_bytes_in_use = 0
-        self.device_peak_bytes = 0
-
-    def sample_device_memory(self, device=None) -> None:
-        """Pull the device allocator's live/peak byte gauges — called
-        from the overflow-check sync the resolve paths already pay,
-        with the DISPATCH device (where the history state lives): on a
-        multi-device host, device 0's allocator says nothing about an
-        impending OOM on the device actually resolving batches.
-        Host-dependent values: they feed status/qos readers only, never
-        a CounterCollection the deterministic trace flush ships."""
-        from foundationdb_tpu.utils import perf as _perf
-
-        stats = _perf.device_memory_stats(device)
-        if stats:
-            self.device_bytes_in_use = stats.get("bytes_in_use", 0)
-            self.device_peak_bytes = max(
-                self.device_peak_bytes, stats.get("peak_bytes_in_use", 0)
-            )
-
-    def as_dict(self) -> dict:
-        out: dict = dict(self.counters.as_dict())
-        for s in (self.compile, self.pack, self.transfer, self.kernel,
-                  self.fence, self.delta_occupancy, self.main_occupancy,
-                  self.collective):
-            out[s.name] = s.as_dict()
-        out["shardCount"] = self.shard_count
-        out["deviceBytesInUse"] = self.device_bytes_in_use
-        out["devicePeakBytes"] = self.device_peak_bytes
-        return out
-
-    def qos(self) -> dict:
-        """The compressed occupancy view the saturation layer reads
-        (status `qos` / fdbtop): per-batch kernel seconds (the fixed
-        per-dispatch cost the tpu-force p99 backup rides on), the share
-        of resolve wall time inside the device stages, and tier fill —
-        one small dict, not the full stage-sample dump (as_dict)."""
-        from foundationdb_tpu.utils import compile_cache as _cc
-
-        batches = self.counters.get("resolveBatches")
-        stage_total = (
-            self.pack.total + self.transfer.total + self.kernel.total
-            + self.fence.total
-        )
-        cc = _cc.stats()
-        d_occ = self.delta_occupancy.max or 0.0
-        m_occ = self.main_occupancy.max or 0.0
-        return {
-            "batches": batches,
-            "group_dispatches": self.counters.get("groupDispatches"),
-            "kernel_seconds_per_batch": (
-                stage_total / batches if batches else 0.0
-            ),
-            "kernel_p99_seconds": self.kernel.quantile(0.99),
-            # per-stage p99s (the fdbtop kernel panel's columns)
-            "stage_p99_seconds": {
-                "pack": self.pack.quantile(0.99),
-                "transfer": self.transfer.quantile(0.99),
-                "kernel": self.kernel.quantile(0.99),
-                "fence": self.fence.quantile(0.99),
-            },
-            "compile_seconds": self.compile.total,
-            # compile-cache observability (utils/compile_cache.py —
-            # process-global: the XLA compiler and its cache are too)
-            "compile_cache_hits": cc["cache_hits"],
-            "compile_cache_misses": cc["cache_misses"],
-            "last_compile_seconds": cc["last_compile_seconds"],
-            # device-memory gauges from the overflow-check syncs
-            "device_bytes_in_use": self.device_bytes_in_use,
-            "device_peak_bytes": self.device_peak_bytes,
-            "delta_occupancy": d_occ,
-            "main_occupancy": m_occ,
-            "compactions": self.counters.get("compactions"),
-            # ISSUE 14: pressure spills (delta_spill) and sweep-probed
-            # groups (range_sweep) — the "router has nothing left to
-            # route away" accounting, zero on unconfigured instances
-            "spills": self.counters.get("spills"),
-            "sweep_groups": self.counters.get("sweepGroups"),
-            "fallbacks": (
-                self.counters.get("latchTrips")
-                + self.counters.get("exactFallbacks")
-            ),
-            # mesh-sharded kernel columns (fdbtop per-shard panel;
-            # zeros/1 on single-device backends so REQUIRED_SENSORS
-            # pins them on every backend). The worst_shard_* keys ALIAS
-            # the occupancy values above — sharded instances sample the
-            # worst shard's counts into the same LatencySamples, so one
-            # source value feeds both names and they cannot drift. The
-            # collective share is measured combine-probe seconds over
-            # per-batch resolve seconds.
-            "shards": self.shard_count,
-            "worst_shard_delta_occupancy": d_occ,
-            "worst_shard_main_occupancy": m_occ,
-            "collective_time_share": (
-                min(
-                    1.0,
-                    (self.collective.total / self.collective.count)
-                    / (stage_total / batches),
-                )
-                if self.collective.count and batches and stage_total
-                else 0.0
-            ),
-        }
-
-
-class HistoryOverflowError(RuntimeError):
-    """Compacted history exceeded `history_capacity`.
-
-    The reference's skip list grows without bound inside the MVCC window;
-    our capacity is static. Overflow means the config is undersized for
-    the write rate x window product — a config error, never silent
-    wrong answers.
-    """
-
-
-@dataclasses.dataclass
-class BatchResult:
-    verdicts: list[TransactionResult]
-    conflicting_key_ranges: dict[int, list[int]]
 
 
 def _rebase(state: H.VersionHistory, delta):
@@ -305,6 +118,18 @@ _GROUP_JITS: dict = {}
 _TIERED_JITS: dict = {}
 
 
+def _named_partial(fn, **static):
+    """functools.partial(fn, **static) under fn's own name: jax.jit
+    names a program after its function's __name__, which a bare
+    partial lacks (its program would be `jit__unknown`), and a profiler
+    trace finds a program by that name."""
+    import functools
+
+    bound = functools.partial(fn, **static)
+    bound.__name__ = fn.__name__
+    return bound
+
+
 def _resolve_group_jit(short_span_limit: int, fixpoint_unroll: int = 3,
                        fixpoint_latch: bool = False):
     """One compiled group kernel per (short_span_limit, fixpoint_unroll,
@@ -313,9 +138,7 @@ def _resolve_group_jit(short_span_limit: int, fixpoint_unroll: int = 3,
     key = (short_span_limit, fixpoint_unroll, fixpoint_latch)
     fn = _GROUP_JITS.get(key)
     if fn is None:
-        import functools
-
-        fn = jax.jit(functools.partial(
+        fn = jax.jit(_named_partial(
             _G.resolve_group, short_span_limit=short_span_limit,
             fixpoint_unroll=fixpoint_unroll,
             fixpoint_latch=fixpoint_latch,
@@ -337,9 +160,7 @@ def _resolve_tiered_jit(short_span_limit: int, fixpoint_unroll: int = 3,
            range_sweep)
     fn = _TIERED_JITS.get(key)
     if fn is None:
-        import functools
-
-        fn = jax.jit(functools.partial(
+        fn = jax.jit(_named_partial(
             _D.resolve_group_tiered, short_span_limit=short_span_limit,
             fixpoint_unroll=fixpoint_unroll,
             fixpoint_latch=fixpoint_latch,
@@ -463,11 +284,10 @@ class TpuConflictSet:
         into history at `version`.
         """
         self._maybe_rebase(version)
-        t0 = time.perf_counter()
-        batch = packing.pack_batch(
-            transactions, version, self.base_version, self.config
-        )
-        self.metrics.pack.sample(time.perf_counter() - t0)
+        with stage(self.metrics.pack, STAGE_NAMES["pack"]):
+            batch = packing.pack_batch(
+                transactions, version, self.base_version, self.config
+            )
         return self._dispatch_and_assemble(
             batch,
             report=[t.report_conflicting_keys for t in transactions],
@@ -488,11 +308,10 @@ class TpuConflictSet:
         from resolve_columnar so the wire ResolverRole can bracket
         exactly this stage with its ColumnarDecode trace event."""
         self._maybe_rebase(version)
-        t0 = time.perf_counter()
-        batch = packing.pack_batch_columnar(
-            cols, version, self.base_version, self.config
-        )
-        self.metrics.pack.sample(time.perf_counter() - t0)
+        with stage(self.metrics.pack, STAGE_NAMES["pack"]):
+            batch = packing.pack_batch_columnar(
+                cols, version, self.base_version, self.config
+            )
         self.metrics.counters.add("columnarBatches")
         return batch
 
@@ -532,19 +351,37 @@ class TpuConflictSet:
     def _dispatch_and_assemble(
         self, batch: packing.PackedBatch, report, begin_key_of_row
     ) -> BatchResult:
-        """The shared tail of resolve()/resolve_columnar(): dispatch the
-        packed batch (tiered or classic) and assemble the BatchResult."""
-        t1 = time.perf_counter()
+        """The shared tail of resolve()/resolve_columnar(): place the
+        packed batch on the device, dispatch it (tiered or classic) and
+        assemble the BatchResult."""
         self.metrics.counters.add("resolveBatches")
         if self.tiered:
             out = self._resolve_args_tiered(batch.device_args())
         else:
-            self.state, out = self._resolve(self.state, batch.device_args())
-            self.metrics.kernel.sample(time.perf_counter() - t1)
+            args = self._transfer(batch.device_args())
+            with stage(self.metrics.kernel, STAGE_NAMES["dispatch"]):
+                self.state, out = self._resolve(self.state, args)
         t2 = time.perf_counter()
         result = self._assemble_result(batch, out, report, begin_key_of_row)
         self.metrics.fence.sample(time.perf_counter() - t2)
         return result
+
+    def _transfer(self, args: dict) -> dict:
+        """Place one dispatch's kernel arguments on the device as the
+        jitted call would place them itself (uncommitted on the default
+        device, or replicated over the mesh on a sharded instance). The
+        transfer stage times the copy's issue; the copy completes on a
+        runtime thread, and the device's wait for it lands in the
+        readback. Samples the host bytes handed over and the bytes they
+        take on the device."""
+        m = self.metrics
+        m.arg_bytes.sample(sum(v.nbytes for v in args.values()))
+        with stage(m.transfer, STAGE_NAMES["transfer"]):
+            placed = jax.device_put(args, self._batch_sharding)
+        m.device_arg_bytes.sample(
+            sum(v.on_device_size_in_bytes() for v in placed.values())
+        )
+        return placed
 
     def _raise_overflow(self) -> None:
         self._batches_since_check = 0
@@ -601,17 +438,9 @@ class TpuConflictSet:
 
     def _resolve_args_tiered(self, args, check_latch: bool = True):
         """One batch through the tiered kernel (G=1): BatchVerdict."""
-        outs = self._dispatch_tiered(
-            _stack_one(args), check_latch=check_latch
-        )
-        return C.BatchVerdict(
-            verdict=outs.verdict[0],
-            hist_conflict_read=outs.hist_conflict_read[0],
-            intra_first_range=outs.intra_first_range[0],
-            committed_count=outs.committed_count[0],
-            conflict_count=outs.conflict_count[0],
-            too_old_count=outs.too_old_count[0],
-            overflow=outs.overflow[0],
+        return self._dispatch_tiered(
+            self._transfer(_stack_one(args)), check_latch=check_latch,
+            first_row=True,
         )
 
     def _tiered_jit(self, ssl, unroll, latch, dedup, sweep=False):
@@ -631,14 +460,17 @@ class TpuConflictSet:
         )
         return lambda st, args: fn(st, args, self._part_lo, self._part_hi)
 
-    def _dispatch_tiered(self, stacked_args, check_latch: bool = True):
+    def _dispatch_tiered(self, stacked_args, check_latch: bool = True,
+                         first_row: bool = False):
         """Dispatch one stacked group on the tiered kernel, honoring the
         latch contract (fixpoint latch OR dedup overflow both surface as
         GroupVerdict.unconverged with the state unchanged): by default
         the host re-dispatches the same args on the exact kernel
         (fixpoint_latch=False, dedup_reads=0). Pipelined callers pass
         check_latch=False and fall back themselves. Auto-compaction runs
-        every config.compact_interval BATCHES."""
+        every config.compact_interval BATCHES. With `first_row` (a G=1
+        group of one batch) the dispatch stage also slices that batch's
+        BatchVerdict out of the group result."""
         cfg = self.config
         ssl = getattr(cfg, "short_span_limit", 0)
         unroll = getattr(cfg, "fixpoint_unroll", 3)
@@ -686,20 +518,29 @@ class TpuConflictSet:
                 self._tiered_jit(ssl, unroll, False, 0, sweep)(
                     self.state, stacked_args
                 )
-        t0 = time.perf_counter()
-        state2, outs = self._tiered_jit(ssl, unroll, latch, dedup, sweep)(
-            self.state, stacked_args
-        )
-        self.metrics.counters.add("groupDispatches")
-        if (latch or dedup) and check_latch and bool(
-            np.asarray(outs.unconverged).any()
-        ):
-            self.metrics.counters.add("latchTrips")
-            self.metrics.counters.add("exactFallbacks")
-            state2, outs = self._tiered_jit(ssl, unroll, False, 0, sweep)(
+        with stage(self.metrics.kernel, STAGE_NAMES["dispatch"]):
+            state2, outs = self._tiered_jit(ssl, unroll, latch, dedup, sweep)(
                 self.state, stacked_args
             )
-        self.metrics.kernel.sample(time.perf_counter() - t0)
+            self.metrics.counters.add("groupDispatches")
+            if (latch or dedup) and check_latch and bool(
+                np.asarray(outs.unconverged).any()
+            ):
+                self.metrics.counters.add("latchTrips")
+                self.metrics.counters.add("exactFallbacks")
+                state2, outs = self._tiered_jit(ssl, unroll, False, 0, sweep)(
+                    self.state, stacked_args
+                )
+            if first_row:
+                outs = C.BatchVerdict(
+                    verdict=outs.verdict[0],
+                    hist_conflict_read=outs.hist_conflict_read[0],
+                    intra_first_range=outs.intra_first_range[0],
+                    committed_count=outs.committed_count[0],
+                    conflict_count=outs.conflict_count[0],
+                    too_old_count=outs.too_old_count[0],
+                    overflow=outs.overflow[0],
+                )
         self.state = state2
         self._batches_since_check += kb - 1
         self._maybe_check_overflow()
@@ -722,14 +563,16 @@ class TpuConflictSet:
         self._batches_since_compact = 0
         self._spill_bound_rows = 0
         self.metrics.counters.add("compactions")
-        if self.sharded:
-            from foundationdb_tpu.parallel import sharding as _sh
+        with stage(self.metrics.compact, STAGE_NAMES["compact"]):
+            if self.sharded:
+                from foundationdb_tpu.parallel import sharding as _sh
 
-            self.state = _sh.compact_sharded_jit(
-                self._mesh, axis=getattr(self.config, "shard_axis", _sh.AXIS)
-            )(self.state)
-        else:
-            self.state = _COMPACT(self.state)
+                self.state = _sh.compact_sharded_jit(
+                    self._mesh,
+                    axis=getattr(self.config, "shard_axis", _sh.AXIS),
+                )(self.state)
+            else:
+                self.state = _COMPACT(self.state)
 
     def resolve_group_args(self, stacked_args, check_latch: bool = True):
         """Resolve K stacked batches via the GROUP kernel (ops/group.py):
@@ -1014,7 +857,9 @@ class TpuConflictSet:
     def _maybe_check_overflow(self) -> None:
         self._batches_since_check += 1
         if self._batches_since_check >= OVERFLOW_CHECK_INTERVAL:
-            self.check_overflow()
+            with stage(self.metrics.overflow_check,
+                       STAGE_NAMES["overflow_check"]):
+                self.check_overflow()
 
     def check_overflow(self) -> None:
         """Device sync: raise if a merge ever exceeded history_capacity
@@ -1067,14 +912,28 @@ class TpuConflictSet:
         as history hits are ever touched.
         """
         n = batch.n_txns
-        verdict = np.asarray(out.verdict)[:n]
+        with stage(self.metrics.readback, STAGE_NAMES["readback"]):
+            verdict, overflow, hist_read, intra_first = (
+                np.asarray(c) for c in (
+                    out.verdict, out.overflow, out.hist_conflict_read,
+                    out.intra_first_range,
+                )
+            )
+            verdict, intra_first = verdict[:n], intra_first[:n]
         # Same device sync the verdict read just paid: refuse to externalize
         # decisions computed against a truncated history (ADVICE r1 — the
         # interval-based check is only for the async packed path).
-        if bool(np.asarray(out.overflow)):
+        if bool(overflow):
             self._raise_overflow()
-        hist_read = np.asarray(out.hist_conflict_read)
-        intra_first = np.asarray(out.intra_first_range)[:n]
+        with stage(self.metrics.assemble, STAGE_NAMES["assemble"]):
+            return self._assemble(batch, verdict, hist_read, intra_first,
+                                  report, begin_key_of_row)
+
+    @staticmethod
+    def _assemble(batch, verdict, hist_read, intra_first, report,
+                  begin_key_of_row) -> BatchResult:
+        """The reply from the read-back result columns."""
+        n = batch.n_txns
         verdicts = [TransactionResult(int(v)) for v in verdict]
 
         conflicting: dict[int, list[int]] = {}
@@ -1198,97 +1057,6 @@ def stage_ledger(config: KernelConfig, batches, *, fuse: int,
         ledger["delta_live_boundaries"] = d_live
         ledger["main_live_boundaries"] = m_live
     return ledger
-
-
-class CpuConflictSet:
-    """CPU fallback behind the resolver_backend knob: the same
-    ConflictBatch interface served by the exact host-side semantic model
-    (testing.oracle.ConflictOracle — the reference's SkipList semantics
-    without a device). Mirrors BASELINE.json's contract that the CPU
-    path stays available (`resolver_backend=cpu`), e.g. for
-    deterministic simulation without device calls."""
-
-    def __init__(self, config: KernelConfig, base_version: int = 0):
-        from foundationdb_tpu.testing.oracle import ConflictOracle, OracleTxn
-
-        self.config = config
-        self._oracle_txn = OracleTxn
-        self._oracle = ConflictOracle(window=config.window_versions)
-        # same metrics surface as TpuConflictSet so status readers never
-        # special-case the backend (stage samples stay empty: the CPU
-        # path has no pack/transfer/kernel split)
-        self.metrics = KernelStageMetrics()
-
-    def resolve(
-        self, transactions: list[CommitTransaction], version: int
-    ) -> BatchResult:
-        self.metrics.counters.add("resolveBatches")
-        res = self._oracle.resolve(
-            [
-                self._oracle_txn(
-                    t.read_conflict_ranges,
-                    t.write_conflict_ranges,
-                    t.read_snapshot,
-                    t.report_conflicting_keys,
-                )
-                for t in transactions
-            ],
-            version,
-        )
-        verdicts = [TransactionResult(v) for v in res.verdicts]
-        conflicting = {
-            t: idxs
-            for t, idxs in res.conflicting_ranges.items()
-            if transactions[t].report_conflicting_keys
-            and verdicts[t] == TransactionResult.CONFLICT
-        }
-        return BatchResult(verdicts=verdicts, conflicting_key_ranges=conflicting)
-
-    def check_overflow(self) -> None:
-        pass  # unbounded host memory
-
-
-def make_conflict_set(config: KernelConfig, backend: str = None):
-    """The resolver_backend knob gate (BASELINE.json: the TPU path sits
-    behind a knob; the CPU path remains selectable).
-
-    With backend "tpu", configs whose batch capacity sits under
-    SERVER_KNOBS.RESOLVER_TPU_MIN_BATCH auto-route to the CPU backend:
-    at small batches the device dispatch alone exceeds the CPU's whole
-    resolve (measured — bench.py BENCH_SMALL=1), so the TPU serves the
-    loaded/batched regime and the CPU the latency regime. Explicit
-    backend="tpu-force" bypasses the threshold (benches, tests)."""
-    if backend is None:
-        from foundationdb_tpu.utils.knobs import SERVER_KNOBS
-
-        backend = SERVER_KNOBS.RESOLVER_BACKEND
-    if backend == "tpu":
-        from foundationdb_tpu.utils.knobs import SERVER_KNOBS
-
-        if config.max_txns < SERVER_KNOBS.RESOLVER_TPU_MIN_BATCH:
-            # Loud reroute (ADVICE r4): the default KernelConfig sizes
-            # max_txns at 1024, well under the measured device/CPU
-            # crossover, so backend="tpu" quietly serving CPU would be
-            # a silent surprise. The gate is on the config's static
-            # batch CAPACITY — the kernel is compiled for max_txns, so
-            # capacity bounds the largest batch this instance could
-            # ever route and is the honest static proxy for load.
-            from foundationdb_tpu.utils.trace import SEV_WARN, TraceEvent
-
-            TraceEvent(
-                "ResolverBackendAutoRouted", severity=SEV_WARN
-            ).detail("Requested", "tpu").detail("Chosen", "cpu").detail(
-                "MaxTxns", config.max_txns
-            ).detail(
-                "MinBatch", SERVER_KNOBS.RESOLVER_TPU_MIN_BATCH
-            ).log()
-            return CpuConflictSet(config)
-        return TpuConflictSet(config)
-    if backend == "tpu-force":
-        return TpuConflictSet(config)
-    if backend == "cpu":
-        return CpuConflictSet(config)
-    raise ValueError(f"unknown resolver_backend {backend!r}")
 
 
 # ---------------------------------------------------------------------------

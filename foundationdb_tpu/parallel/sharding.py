@@ -556,7 +556,7 @@ class ShardedConflictSet:
         return out
 
     def _raise_overflow(self) -> None:
-        from foundationdb_tpu.models.conflict_set import HistoryOverflowError
+        from foundationdb_tpu.models.conflict_base import HistoryOverflowError
 
         raise HistoryOverflowError(
             f"a shard's history_capacity={self.config.history_capacity} "

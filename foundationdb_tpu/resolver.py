@@ -141,7 +141,7 @@ class Resolver:
         backend: str = None,  # resolver_backend knob: "tpu" | "cpu"
         num_logs: int = 1,  # tlog count for the version-vector tpcv path
     ):
-        from foundationdb_tpu.models.conflict_set import make_conflict_set
+        from foundationdb_tpu.models.conflict_base import make_conflict_set
         from foundationdb_tpu.utils.knobs import SERVER_KNOBS
 
         self.sched = sched
@@ -172,7 +172,7 @@ class Resolver:
         # kernel-panel fallback (the wire ResolverRole owns the same
         # shape): an unrouted or metrics-less conflict set still
         # reports a zeroed qos.kernel block — REQUIRED_SENSORS pins it
-        from foundationdb_tpu.models.conflict_set import KernelStageMetrics
+        from foundationdb_tpu.models.conflict_base import KernelStageMetrics
 
         self._fallback_kernel_metrics = KernelStageMetrics()
         self.version = Notified(init_version)
@@ -258,9 +258,9 @@ class Resolver:
     def _route_backend(self, transactions) -> None:
         from foundationdb_tpu.models.conflict_set import (
             backend_for_profile,
-            make_conflict_set,
             profile_transactions,
         )
+        from foundationdb_tpu.models.conflict_base import make_conflict_set
         from foundationdb_tpu.utils.trace import TraceEvent
 
         self._profile = profile_transactions(transactions)

@@ -12,6 +12,7 @@ reporting without the reference's mergeability machinery.
 from __future__ import annotations
 
 import math
+import sys
 import time as _time
 from typing import Callable, Optional
 
@@ -260,6 +261,36 @@ class LatencySample:
             "p99": self.quantile(0.99),
             "max": self.max or 0.0,
         }
+
+
+class stage:
+    """`with stage(sample, "Resolver.resolveBatch.Pack"):` times the
+    block's wall seconds into `sample` and, once JAX is loaded in this
+    process, marks it as a host event of that name for the profiler
+    (`jax.profiler.TraceAnnotation`): a profiled run then shows each
+    stage on the device trace's clock. It never imports JAX itself, so
+    JAX-free roles and the simulation stay JAX-free; with no profiler
+    session running the annotation is one cheap check."""
+
+    __slots__ = ("sample", "name", "_t0", "_event")
+
+    def __init__(self, sample: LatencySample, name: str):
+        self.sample = sample
+        self.name = name
+
+    def __enter__(self) -> "stage":
+        profiler = sys.modules.get("jax.profiler")
+        self._event = None
+        if profiler is not None and profiler.TraceAnnotation.is_enabled():
+            self._event = profiler.TraceAnnotation(self.name)
+            self._event.__enter__()
+        self._t0 = _time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.sample.sample(_time.perf_counter() - self._t0)
+        if self._event is not None:
+            self._event.__exit__(*exc)
 
 
 #: the reference's default commit/GRV/read latency band thresholds

@@ -32,6 +32,7 @@ sim tests never touch this module.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import ssl as _ssl
 import struct
 import sys
@@ -51,6 +52,9 @@ KIND_REPLY = 1
 KIND_ERROR = 2
 
 MAX_FRAME = 256 * 1024 * 1024
+
+#: the (decode, encode) stage timers of an endpoint that times neither
+_UNTIMED = (contextlib.nullcontext, contextlib.nullcontext)
 
 
 class TransportError(ConnectionError):
@@ -157,15 +161,22 @@ class RpcServer:
         self.tls = tls
         self.protocol_version = protocol_version  # None = current
         self._handlers: dict[int, Callable] = {}
+        self._stages: dict[int, tuple] = {}
         self._server: asyncio.AbstractServer | None = None
         self._conns: set = set()  # live connection writers
         self._census_live = False  # tracked in census.SERVERS
 
-    def register(self, token: int, handler: Callable) -> None:
-        """handler: async (msg) -> reply msg (codec-registered types)."""
+    def register(self, token: int, handler: Callable, *,
+                 stages: tuple | None = None) -> None:
+        """handler: async (msg) -> reply msg (codec-registered types).
+        `stages`, where given, is a (decode, encode) pair of zero-argument
+        context-manager factories (utils.metrics.stage) that time the
+        request's decode and its reply's encode."""
         if token in self._handlers:
             raise ValueError(f"token {token:#x} already registered")
         self._handlers[token] = handler
+        if stages is not None:
+            self._stages[token] = stages
 
     async def start(self) -> None:
         ssl_ctx = self.tls.server_context() if self.tls else None
@@ -278,11 +289,15 @@ class RpcServer:
                 handler = self._handlers.get(token)
                 if handler is None:
                     raise UnknownEndpointError(f"no endpoint {token:#x}")
-                reply = await handler(codec.decode(payload))
+                decode_stage, encode_stage = self._stages.get(token, _UNTIMED)
+                with decode_stage():
+                    msg = codec.decode(payload)
+                reply = await handler(msg)
                 # build+write share the connection's frame buffer: no
                 # await between fb.send entry and writer.write (see
                 # _FrameBuffer)
-                fb.send(writer, _REP.pack(KIND_REPLY, reqid), msg=reply)
+                with encode_stage():
+                    fb.send(writer, _REP.pack(KIND_REPLY, reqid), msg=reply)
             except Exception as e:  # travels back as an error frame
                 fb.send(
                     writer, _REP.pack(KIND_ERROR, reqid),

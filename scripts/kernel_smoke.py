@@ -35,10 +35,8 @@ def main() -> int:
     args = ap.parse_args()
     t_start = time.perf_counter()
     from foundationdb_tpu.config import KernelConfig
-    from foundationdb_tpu.models.conflict_set import (
-        CpuConflictSet,
-        TpuConflictSet,
-    )
+    from foundationdb_tpu.models.conflict_set import TpuConflictSet
+    from foundationdb_tpu.models.conflict_base import CpuConflictSet
     from foundationdb_tpu.models.types import CommitTransaction
 
     base_cfg = dict(

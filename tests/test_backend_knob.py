@@ -5,9 +5,9 @@ import pytest
 
 from foundationdb_tpu.cluster.database import ClusterConfig, open_cluster
 from foundationdb_tpu.config import TEST_CONFIG
-from foundationdb_tpu.models.conflict_set import (
+from foundationdb_tpu.models.conflict_set import TpuConflictSet
+from foundationdb_tpu.models.conflict_base import (
     CpuConflictSet,
-    TpuConflictSet,
     make_conflict_set,
 )
 from foundationdb_tpu.testing import workloads
@@ -81,7 +81,7 @@ def test_cluster_runs_on_cpu_backend():
     v1, outcome = sched.run_until(sched.spawn(body()).done)
     assert v1 == b"backend"
     assert outcome == "conflict"
-    from foundationdb_tpu.models.conflict_set import CpuConflictSet as C
+    from foundationdb_tpu.models.conflict_base import CpuConflictSet as C
 
     assert all(isinstance(r.conflict_set, C) for r in cluster.resolvers)
     cluster.stop()

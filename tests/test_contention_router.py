@@ -229,10 +229,8 @@ def test_resolver_routes_on_first_batch():
     """The wiring: a Resolver with the tpu knob chooses its backend from
     the FIRST batch's contention profile (one-shot — switching later
     would discard MVCC history; drift only warns)."""
-    from foundationdb_tpu.models.conflict_set import (
-        CpuConflictSet,
-        TpuConflictSet,
-    )
+    from foundationdb_tpu.models.conflict_set import TpuConflictSet
+    from foundationdb_tpu.models.conflict_base import CpuConflictSet
     from foundationdb_tpu.models.types import (
         CommitTransaction,
         ResolveTransactionBatchRequest,

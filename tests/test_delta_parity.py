@@ -27,9 +27,11 @@ import pytest
 from foundationdb_tpu.config import KernelConfig
 from foundationdb_tpu.models.conflict_set import (
     OVERFLOW_CHECK_INTERVAL,
+    TpuConflictSet,
+)
+from foundationdb_tpu.models.conflict_base import (
     CpuConflictSet,
     HistoryOverflowError,
-    TpuConflictSet,
 )
 from foundationdb_tpu.models.types import CommitTransaction
 from foundationdb_tpu.ops import delta as D

@@ -195,7 +195,7 @@ def test_default_band_edges_are_sorted_and_stable():
 
 
 def test_kernel_stage_metrics_shape():
-    from foundationdb_tpu.models.conflict_set import KernelStageMetrics
+    from foundationdb_tpu.models.conflict_base import KernelStageMetrics
 
     m = KernelStageMetrics()
     d = m.as_dict()
@@ -210,7 +210,7 @@ def test_kernel_stage_metrics_shape():
 
 def test_cpu_conflict_set_counts_batches():
     from foundationdb_tpu.config import TEST_CONFIG
-    from foundationdb_tpu.models.conflict_set import CpuConflictSet
+    from foundationdb_tpu.models.conflict_base import CpuConflictSet
     from foundationdb_tpu.models.types import CommitTransaction
 
     cs = CpuConflictSet(TEST_CONFIG)

@@ -12,10 +12,8 @@ import numpy as np
 import pytest
 
 from foundationdb_tpu.config import KernelConfig
-from foundationdb_tpu.models.conflict_set import (
-    HistoryOverflowError,
-    TpuConflictSet,
-)
+from foundationdb_tpu.models.conflict_set import TpuConflictSet
+from foundationdb_tpu.models.conflict_base import HistoryOverflowError
 from foundationdb_tpu.models.types import CommitTransaction
 
 # compile-heavy kernel tests: run with -m kernel (fast lane: -m 'not kernel')

@@ -490,7 +490,7 @@ def test_cost_analysis_of_jitted():
 
 
 def test_compile_cache_stats_surface(tmp_path, monkeypatch):
-    from foundationdb_tpu.models.conflict_set import KernelStageMetrics
+    from foundationdb_tpu.models.conflict_base import KernelStageMetrics
     from foundationdb_tpu.utils import compile_cache
 
     compile_cache.record_compile("sig/test", 1.25)

@@ -26,10 +26,10 @@ import numpy as np
 import pytest
 
 from foundationdb_tpu.config import KernelConfig
-from foundationdb_tpu.models.conflict_set import (
+from foundationdb_tpu.models.conflict_set import TpuConflictSet
+from foundationdb_tpu.models.conflict_base import (
     CpuConflictSet,
     HistoryOverflowError,
-    TpuConflictSet,
 )
 from foundationdb_tpu.models.types import CommitTransaction
 from foundationdb_tpu.utils import packing

@@ -11,9 +11,9 @@ make_conflict_set routing decision on both sides of it.
 from __future__ import annotations
 
 from foundationdb_tpu.config import KernelConfig
-from foundationdb_tpu.models.conflict_set import (
+from foundationdb_tpu.models.conflict_set import TpuConflictSet
+from foundationdb_tpu.models.conflict_base import (
     CpuConflictSet,
-    TpuConflictSet,
     make_conflict_set,
 )
 from foundationdb_tpu.utils.knobs import SERVER_KNOBS
