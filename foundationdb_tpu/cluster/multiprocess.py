@@ -30,11 +30,13 @@ import dataclasses
 import os
 import subprocess
 import sys
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 from foundationdb_tpu.cluster.grv_proxy import GrvThrottledError  # noqa: F401
 from foundationdb_tpu.models.conflict_base import (
     STAGE_NAMES,
+    BatchResult,
+    HistoryOverflowError,
     KernelStageMetrics,
 )
 from foundationdb_tpu.utils.metrics import stage
@@ -625,6 +627,43 @@ def _decode_alloc_count(txns) -> int:
     return n
 
 
+#: batches a ResolverRole keeps issued on the device and not yet
+#: completed: one running, and the next one issued behind it. A deeper
+#: queue adds latency and no throughput.
+OVERLAP_DEPTH = 2
+
+
+#: the OS name of the threads a ResolverRole waits for the device on
+WAIT_THREAD = "resolver-wait"
+
+
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread an OS-level name (Linux; elsewhere it
+    keeps its own), which a profiler trace names its host line by."""
+    import threading
+
+    try:
+        with open(f"/proc/self/task/{threading.get_native_id()}/comm",
+                  "w") as f:
+            f.write(name[:15])
+    except OSError:
+        pass
+
+
+class _Issued(NamedTuple):
+    """A batch of the overlapped order, issued and not yet completed:
+    its conflict set's pending handle, the completion futures of the
+    batch issued before it and of itself (completions run in issue
+    order), and when its compute began."""
+
+    version: int
+    pending: Any
+    prev: Optional[asyncio.Future]
+    done: asyncio.Future
+    #: when its compute began (perf_counter)
+    t0: float
+
+
 class ResolverRole:
     """Wire-served resolver: version-chained conflict resolution.
 
@@ -634,11 +673,35 @@ class ResolverRole:
     req.version — so out-of-order arrivals from concurrent proxies are
     serialized into the global commit order. Duplicate requests (same
     version) replay the recorded reply (:515-530).
+
+    On a conflict set that splits a resolve into `issue` and `complete`
+    (the jitted ones), batches overlap on the device: a batch passes the
+    version chain once it is issued, so the event loop decodes, packs
+    and dispatches the next batch while the device runs this one
+    (`_resolve_overlapped`). The native skip list, the CPU oracle and
+    the modeled-compute path resolve one batch at a time.
     """
 
     def __init__(self, backend: str = "native", window: int = 5_000_000,
                  epoch: int = 0, compute_cost_per_txn: float = 0.0):
         self.version = -1
+        #: the overlapped order's chain: the last version issued to the
+        #: device (>= version, the last replied), the batches issued and
+        #: not yet completed, the completion of the last one issued, the
+        #: reply futures of claimed, unreplied versions, and the first
+        #: version refused for a history overflow (every later batch is
+        #: refused too)
+        self._issued = -1
+        self._in_flight = 0
+        self._last_done: asyncio.Future | None = None
+        self._unreplied: dict[int, asyncio.Future] = {}
+        self._overflow_from: int | None = None
+        self._loop: asyncio.AbstractEventLoop | None = None
+        self._waiters = None  # the wait threads (_wait_pool)
+        #: batches past their orderer wait and not yet replied, and when
+        #: occupancy last accrued (the union of their busy intervals)
+        self._busy_count = 0
+        self._busy_mark = 0.0
         self.window = window
         #: modeled per-transaction compute seconds (the wire twin of the
         #: sim Resolver.sim_compute_cost_per_txn, PR 8): awaited per
@@ -696,9 +759,13 @@ class ResolverRole:
         self.decode_time = LatencySample("decodeSeconds")
         self.key_sample_time = LatencySample("keySampleSeconds")
         self.encode_time = LatencySample("encodeSeconds")
+        #: per batch, 1.0 when another batch was still on the device as
+        #: it was issued, else 0.0 (always 0.0 in the one-at-a-time order)
+        self.overlap = LatencySample("overlap")
         # busy-fraction smoother (the Ratekeeper's resolver-occupancy
         # input): compute seconds accumulate as a rate — a resolver
-        # spending ~every wall second inside _resolve_now reads ~1.0.
+        # spending ~every wall second inside _resolve_now reads ~1.0,
+        # however many of its batches overlap (see _busy).
         # This is the signal that catches few-huge-batch saturation,
         # where queue DEPTH stays deceptively small because the
         # blocking compute keeps arrivals out of the parked count.
@@ -774,6 +841,13 @@ class ResolverRole:
             self._warm_compile(kcfg, backend)
         else:
             raise ValueError(f"unknown resolver backend {backend!r}")
+        #: overlap batches on the device where the conflict set can
+        #: issue a batch and complete it later; modeled compute keeps
+        #: the one-at-a-time order its drills measure
+        self._overlap = (
+            self.compute_cost_per_txn == 0.0
+            and hasattr(self._cs, "issue") and hasattr(self._cs, "complete")
+        )
 
     def _warm_compile(self, kcfg, backend: str) -> None:
         """Compile the resolver kernels at ROLE STARTUP, not on the
@@ -880,6 +954,8 @@ class ResolverRole:
                 span.finish()
 
     async def _resolve_ordered(self, req: ResolveTransactionBatchRequest):
+        if self._overlap:
+            return await self._resolve_overlapped(req)
         import time as _time
 
         t_arrive = _time.perf_counter()
@@ -895,49 +971,230 @@ class ResolverRole:
                 self._waiting -= 1
             self.queue_wait_latency.sample(_time.perf_counter() - t_arrive)
             if req.version <= self.version:
-                # duplicate (proxy retry): replay the recorded reply
-                reply = self._replies.get(req.version)
-                if reply is None:
-                    raise transport.RemoteError(
-                        f"version {req.version} already resolved and expired"
-                    )
-                return reply
-            if req.debug_id is not None:
-                from foundationdb_tpu.utils import commit_debug as _cdbg
-                from foundationdb_tpu.utils import trace as _tr
-
-                # past the version-chain wait (the reference's orderer):
-                # the next mark is ColumnarDecode, so the waterfall's
-                # columnar_decode stage brackets exactly the frame ->
-                # kernel-tensor work
-                _tr.g_trace_batch.add_event(
-                    "CommitDebug", req.debug_id, _cdbg.RESOLVER_AFTER_ORDERER
-                )
+                return self._replayed(req)
+            self._trace_after_orderer(req)
             t_compute = _time.perf_counter()
-            reply = self._resolve_now(req)
-            if self.compute_cost_per_txn > 0.0:
-                # modeled compute rides the version chain exactly like
-                # real compute (successors wait on the condition), but
-                # as an await so the role process keeps serving status
-                # polls — occupancy and compute_time absorb it below,
-                # which is what makes the Ratekeeper's resolver_busy
-                # attribution (and the elasticity drill's plateau) real
-                await asyncio.sleep(
-                    self.compute_cost_per_txn * self._local_txns(req)
-                )
-            dt_compute = _time.perf_counter() - t_compute
-            self.compute_time.sample(dt_compute)
-            self.occupancy.add_delta(dt_compute)
-            self.resolver_latency.sample(_time.perf_counter() - t_arrive)
-            self._replies[req.version] = reply
-            # retain a bounded replay window
-            floor = req.version - self.window
-            self._replies = {
-                v: r for v, r in self._replies.items() if v >= floor
-            }
-            self.version = req.version
+            self._busy(1)
+            try:
+                reply = self._resolve_now(req)
+                if self.compute_cost_per_txn > 0.0:
+                    # modeled compute rides the version chain exactly
+                    # like real compute (successors wait on the
+                    # condition), but as an await so the role process
+                    # keeps serving status polls — occupancy and
+                    # compute_time absorb it below, which is what makes
+                    # the Ratekeeper's resolver_busy attribution (and
+                    # the elasticity drill's plateau) real
+                    await asyncio.sleep(
+                        self.compute_cost_per_txn * self._local_txns(req)
+                    )
+            finally:
+                self._busy(-1)
+            self.compute_time.sample(_time.perf_counter() - t_compute)
+            self._record_reply(req, reply, t_arrive)
             cond.notify_all()
             return reply
+
+    async def _resolve_overlapped(self, req):
+        """The overlapped order. A batch waits for its predecessor to be
+        ISSUED (not replied) and for room on the device: at most
+        OVERLAP_DEPTH batches issued and not yet completed. Then
+        `_resolve_now` runs on a worker thread, handing its host stages
+        to this loop and waiting for the device there, so the loop
+        serves the next batch meanwhile. A retry of a version that is
+        claimed and not yet replied awaits that reply."""
+        import time as _time
+
+        t_arrive = _time.perf_counter()
+        cond = self._cond_lazy()
+        async with cond:
+            self._waiting += 1
+            self.queue_depth.sample(self._waiting)
+            try:
+                await cond.wait_for(
+                    lambda: self._issued >= req.prev_version
+                    and self._in_flight < OVERLAP_DEPTH
+                )
+            finally:
+                self._waiting -= 1
+            self.queue_wait_latency.sample(_time.perf_counter() - t_arrive)
+            claimed = self._unreplied.get(req.version)
+            if claimed is None:
+                if req.version <= self._issued:
+                    return self._replayed(req)
+                self._trace_after_orderer(req)
+                self._loop = asyncio.get_running_loop()
+                fut = self._unreplied[req.version] = self._loop.create_future()
+        if claimed is not None:
+            return await asyncio.shield(claimed)
+        try:
+            reply = await self._loop.run_in_executor(
+                self._wait_pool(), self._resolve_now, req
+            )
+        except asyncio.CancelledError:
+            fut.cancel()
+            raise
+        except Exception as e:
+            fut.set_exception(e)
+            fut.exception()  # retrieved: a retry may never come
+            if self._issued < req.version:
+                # failed before its issue: its successors go on, as
+                # they do past a batch that fails on the device
+                await self._pass_chain(req.version)
+            raise
+        finally:
+            self._unreplied.pop(req.version, None)
+        fut.set_result(reply)
+        self._record_reply(req, reply, t_arrive)
+        return reply
+
+    def _replayed(self, req) -> ResolveTransactionBatchReply:
+        """A duplicate (proxy retry) of a resolved version: the recorded
+        reply."""
+        reply = self._replies.get(req.version)
+        if reply is None:
+            self._refuse_after_overflow(req.version)
+            raise transport.RemoteError(
+                f"version {req.version} already resolved and expired"
+            )
+        return reply
+
+    def _trace_after_orderer(self, req) -> None:
+        if req.debug_id is None:
+            return
+        from foundationdb_tpu.utils import commit_debug as _cdbg
+        from foundationdb_tpu.utils import trace as _tr
+
+        # past the version-chain wait (the reference's orderer): the
+        # next mark is ColumnarDecode, so the waterfall's columnar_decode
+        # stage brackets exactly the frame -> kernel-tensor work
+        _tr.g_trace_batch.add_event(
+            "CommitDebug", req.debug_id, _cdbg.RESOLVER_AFTER_ORDERER
+        )
+
+    def _busy(self, step: int) -> None:
+        """Occupancy is the union of the batches' busy intervals (compute
+        start to reply ready): time accrues while at least one batch is
+        busy, once however many overlap, so it stays <= 1."""
+        import time as _time
+
+        now = _time.perf_counter()
+        if self._busy_count:
+            self.occupancy.add_delta(now - self._busy_mark)
+        self._busy_mark = now
+        self._busy_count += step
+
+    def _record_reply(self, req, reply, t_arrive: float) -> None:
+        import time as _time
+
+        self.resolver_latency.sample(_time.perf_counter() - t_arrive)
+        self._replies[req.version] = reply
+        # retain a bounded replay window
+        floor = req.version - self.window
+        self._replies = {
+            v: r for v, r in self._replies.items() if v >= floor
+        }
+        self.version = max(self.version, req.version)
+
+    async def _pass_chain(self, version: int) -> None:
+        """Let the batches waiting on `version` go on."""
+        self._issued = max(self._issued, version)
+        cond = self._cond_lazy()
+        async with cond:
+            cond.notify_all()
+
+    def _refuse_after_overflow(self, version: int) -> None:
+        """A batch at or after one whose history overflowed never gets
+        verdicts: its own output need not show a delta tier's latch
+        before the next compaction folds it into the main tier."""
+        if self._overflow_from is not None and version >= self._overflow_from:
+            raise HistoryOverflowError(
+                f"version {version} refused: the conflict history "
+                f"overflowed at version {self._overflow_from}"
+            )
+
+    def _note_overflow(self, version: int) -> None:
+        if self._overflow_from is None or version < self._overflow_from:
+            self._overflow_from = version
+
+    def _wait_pool(self):
+        """The worker threads the overlapped order waits on, named for
+        the profiler: each OS thread gets a trace line of its own, apart
+        from the event loop's."""
+        if self._waiters is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            # one more than the depth: a completed batch's thread is
+            # still returning as the next batch is let in
+            self._waiters = ThreadPoolExecutor(
+                OVERLAP_DEPTH + 1, thread_name_prefix=WAIT_THREAD,
+                initializer=_name_os_thread, initargs=(WAIT_THREAD,),
+            )
+        return self._waiters
+
+    def close(self) -> None:
+        """Release the wait threads (a retired or stopping role)."""
+        if self._waiters is not None:
+            self._waiters.shutdown(wait=False)
+            self._waiters = None
+
+    def _on_loop(self, coro):
+        """Run `coro` on the role's event loop from the worker thread
+        `_resolve_now` runs on in the overlapped order, and wait for it."""
+        return asyncio.run_coroutine_threadsafe(coro, self._loop).result()
+
+    async def _issue(self, req) -> _Issued:
+        """On the loop: a batch's host stages up to its dispatch, then
+        the version chain passes (also past a batch refused here). Its
+        compute time and busy interval start here."""
+        import time as _time
+
+        t0 = _time.perf_counter()
+        self._busy(1)
+        try:
+            self._refuse_after_overflow(req.version)
+            self._note_batch(req)
+            self.overlap.sample(1.0 if self._in_flight else 0.0)
+            pending = self._dispatch(req)
+            self._in_flight += 1
+            prev = self._last_done
+            if prev is not None and prev.get_loop() is not self._loop:
+                prev = None  # a loop that ended: nothing left to order
+            done = self._last_done = self._loop.create_future()
+        except BaseException as e:
+            self._busy(-1)
+            if isinstance(e, HistoryOverflowError):
+                # the refusal, or the periodic overflow check inside
+                # the dispatch
+                self._note_overflow(req.version)
+            raise
+        finally:
+            await self._pass_chain(req.version)
+        return _Issued(req.version, pending, prev, done, t0)
+
+    async def _complete(self, issued: _Issued) -> BatchResult:
+        """On the loop, in issue order, once the batch's columns are on
+        the host: the overflow refusal and the reply's assembly."""
+        import time as _time
+
+        try:
+            if issued.prev is not None:
+                await asyncio.shield(issued.prev)
+            self._refuse_after_overflow(issued.version)
+            try:
+                res = self._cs.complete(issued.pending)
+            except HistoryOverflowError:
+                self._note_overflow(issued.version)
+                raise
+            self.compute_time.sample(_time.perf_counter() - issued.t0)
+            return res
+        finally:
+            self._busy(-1)
+            self._in_flight -= 1
+            issued.done.set_result(None)
+            cond = self._cond_lazy()
+            async with cond:
+                cond.notify_all()
 
     def _local_txns(self, req) -> int:
         """Transactions in this batch carrying LOCAL conflict work —
@@ -1015,11 +1272,31 @@ class ResolverRole:
             _sampling.decay_key_sample(sample)
 
     def _resolve_now(self, req) -> ResolveTransactionBatchReply:
-        columnar = isinstance(req, codec.ResolveBatchColumnar)
+        """One batch, from its key sample to its reply. In the
+        overlapped order it runs on a worker thread and keeps only the
+        wait for the device there: the host stages up to the dispatch
+        and the assembly after the wait run on the event loop."""
+        if self._overlap:
+            issued = self._on_loop(self._issue(req))
+            self._cs.read_back(issued.pending)
+            res = self._on_loop(self._complete(issued))
+        else:
+            self._note_batch(req)
+            self.overlap.sample(0.0)
+            res = self._resolve_one(req)
+        return ResolveTransactionBatchReply(
+            committed=res.verdicts,
+            conflicting_key_range_map=res.conflicting_key_ranges,
+            state_mutations=[],
+            debug_id=req.debug_id,
+        )
+
+    def _note_batch(self, req) -> None:
+        """The key sample and the frame accounting of one batch."""
         stats = self.path_stats
         with stage(self.key_sample_time, STAGE_NAMES["key_sample"]):
             self._note_key_sample(req)
-        if columnar:
+        if isinstance(req, codec.ResolveBatchColumnar):
             stats["columnar_batches"] += 1
             stats["txns"] += req.cols.n_txns
         else:
@@ -1030,6 +1307,10 @@ class ResolverRole:
             # payload -> objects copy plus the per-txn allocations
             stats["copies"] += 1
             stats["decode_allocs"] += _decode_alloc_count(req.transactions)
+
+    def _resolve_one(self, req) -> BatchResult:
+        """One batch in the one-at-a-time order."""
+        columnar = isinstance(req, codec.ResolveBatchColumnar)
         if self._backend == "native":
             import time as _time
 
@@ -1041,39 +1322,34 @@ class ResolverRole:
             verdicts = self._cs.resolve(txns, req.version)
             self._kernel_metrics.kernel.sample(_time.perf_counter() - t0)
             self._kernel_metrics.counters.add("resolveBatches")
-            committed = [TransactionResult(int(v)) for v in verdicts]
-            ckr: dict[int, list[int]] = {}
-        else:
-            jitted = hasattr(self._cs, "pack_columnar_batch")
-            if columnar and jitted:
-                # THE columnar win: wire bytes -> device tensors with
-                # TWO copies total — the blob -> padded-tensor scatter
-                # (pack_columnar_batch) and the host -> device transfer
-                # inside the dispatch. No per-txn objects ever exist.
-                batch = self._cs.pack_columnar_batch(req.cols, req.version)
-                self._trace_columnar_decode(req)
-                stats["copies"] += 2
-                res = self._cs.resolve_columnar_packed(req.cols, batch)
-            elif columnar:
-                # CPU-oracle backend: object-consuming fallback
-                res = self._cs.resolve(
-                    self._columnar_to_objects(req), req.version
-                )
-            else:
-                if jitted:
-                    # object path on a jitted backend: pack_batch
-                    # re-flattens the decoded objects (+1) and the
-                    # dispatch transfers (+1) on top of the decode copy
-                    stats["copies"] += 2
-                res = self._cs.resolve(req.transactions, req.version)
-            committed = res.verdicts
-            ckr = res.conflicting_key_ranges
-        return ResolveTransactionBatchReply(
-            committed=committed,
-            conflicting_key_range_map=ckr,
-            state_mutations=[],
-            debug_id=req.debug_id,
+            return BatchResult(
+                verdicts=[TransactionResult(int(v)) for v in verdicts],
+                conflicting_key_ranges={},
+            )
+        if hasattr(self._cs, "complete"):
+            return self._cs.complete(self._dispatch(req))
+        # CPU-oracle backend: object-consuming fallback
+        return self._cs.resolve(
+            self._columnar_to_objects(req) if columnar else req.transactions,
+            req.version,
         )
+
+    def _dispatch(self, req):
+        """Pack and issue one batch on a jitted conflict set."""
+        stats = self.path_stats
+        if isinstance(req, codec.ResolveBatchColumnar):
+            # THE columnar win: wire bytes -> device tensors with TWO
+            # copies total — the blob -> padded-tensor scatter
+            # (pack_columnar_batch) and the host -> device transfer
+            # inside the dispatch. No per-txn objects ever exist.
+            batch = self._cs.pack_columnar_batch(req.cols, req.version)
+            self._trace_columnar_decode(req)
+            stats["copies"] += 2
+            return self._cs.issue_columnar_packed(req.cols, batch)
+        # object path: pack_batch re-flattens the decoded objects (+1)
+        # and the dispatch transfers (+1) on top of the decode copy
+        stats["copies"] += 2
+        return self._cs.issue(req.transactions, req.version)
 
     def wire_stages(self) -> tuple:
         """The (decode, encode) stage timers RpcServer.register takes
@@ -1110,10 +1386,12 @@ class ResolverRole:
             "key_sample": self.key_sample_time.as_dict(),
             "encode": self.encode_time.as_dict(),
             **self._kernel_metrics.stages(),
+            "overlap": self.overlap.as_dict(),
         }
         qos["stages"] = {
             k: stages[k]
-            for k in (*STAGE_NAMES, "arg_bytes", "device_arg_bytes")
+            for k in (*STAGE_NAMES, "arg_bytes", "device_arg_bytes",
+                      "overlap")
         }
         # columnar-vs-object frame accounting (r12): bench_pipeline
         # reads this to land the structural copy/alloc metrics
@@ -2984,6 +3262,8 @@ class WorkerRole:
         self.role_epochs.pop(kind, None)
         if isinstance(old, (ProxyRole, RatekeeperRole)):
             await old.stop()
+        elif isinstance(old, ResolverRole):
+            old.close()
         elif isinstance(old, StorageRole):
             # storage WAL writes run on executor threads: close under
             # the log lock (use-after-free in the native queue
@@ -4527,6 +4807,8 @@ async def _serve_role(
         # the whole process down) — but a clean close here means the
         # in-process drills' census sees the listener go away
         await server.close()
+        if isinstance(role, ResolverRole):
+            role.close()
 
 
 # ---------------------------------------------------------------------------
@@ -4896,6 +5178,7 @@ class ProxyPipeline:
         )
         self.failed: Optional[BaseException] = None
         self._loop: asyncio.AbstractEventLoop | None = None
+        self._waiters = None  # the wait threads (_wait_pool)
         # ordered apply queue: (version, mutations, prev_version)
         # appended in commit order at reply time, drained by ONE
         # applier task in batched StorageApplyBatch RPCs — replies never wait on storage, and

@@ -47,11 +47,12 @@ class KernelStageMetrics:
     Timing semantics: stage samples are host wall-clock seconds.
     "transfer" covers placing the kernel's arguments on the device,
     "kernel" the jitted dispatch call (on asynchronous backends that is
-    issue time); "fence" covers what follows the dispatch on the served
-    path, the wait for and copy of the results ("readback") and the
-    reply assembly ("assemble"). "compact" and "overflow_check" time
-    the compaction dispatch and the periodic overflow sync, which fall
-    between the dispatch and the fence. `arg_bytes` and
+    issue time); "fence" covers what follows the issue on the served
+    path, the wait for and copy of the results ("readback", from the
+    issue's end until the completion takes them up) and the reply
+    assembly ("assemble"). "compact" and "overflow_check" time the
+    compaction dispatch and the periodic overflow sync, which fall
+    between the dispatch and the end of the issue. `arg_bytes` and
     `device_arg_bytes` sample, per transfer, the host bytes handed to
     the device and the bytes they take there. Counters are event counts and
     deterministic per run; the periodic trace_counters flush ships only

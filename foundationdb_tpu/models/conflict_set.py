@@ -12,9 +12,10 @@ TPU-motivated:
 * Versions are rebased to int32 offsets of `base_version`; the rebase
   shifts every stored offset on device when the window drifts too far.
 * Capacity overflow is latched on device and surfaced in every
-  BatchVerdict; `resolve()` checks it on the same sync that reads the
-  verdicts, so no decision computed against a truncated history is ever
-  externalized. The async `resolve_packed` path (bench) checks every
+  BatchVerdict; `complete()` (the second half of `resolve()`, after
+  `issue()`) checks it on the same sync that reads the verdicts, so no
+  decision computed against a truncated history is ever externalized.
+  The async `resolve_packed` path (bench) checks every
   OVERFLOW_CHECK_INTERVAL batches to preserve pipelining.
 
 The conflicting-key report follows the reference's recording order:
@@ -26,7 +27,9 @@ for txns the history phase didn't already condemn (:880-899).
 
 from __future__ import annotations
 
+import dataclasses
 import time
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -185,6 +188,27 @@ def _stack_one(args: dict) -> dict:
     return out
 
 
+def _result_columns(out: C.BatchVerdict) -> tuple:
+    """The result columns a reply is assembled from."""
+    return (out.verdict, out.overflow, out.hist_conflict_read,
+            out.intra_first_range)
+
+
+@dataclasses.dataclass
+class PendingBatch:
+    """One issued batch: the packed host batch, its BatchVerdict still
+    on the device, and what assembly needs; `host` holds the read-back
+    result columns once `TpuConflictSet.read_back` has waited for them."""
+
+    batch: packing.PackedBatch
+    out: C.BatchVerdict
+    report: list
+    begin_key_of_row: Callable[[int], bytes]
+    #: when the issue ended (perf_counter): the readback runs from here
+    issued_at: float
+    host: tuple | None = None
+
+
 class TpuConflictSet:
     """Batch MVCC conflict detection with device-resident history.
 
@@ -283,12 +307,21 @@ class TpuConflictSet:
         and the conflicting-key-range report, and merges committed writes
         into history at `version`.
         """
+        return self.complete(self.issue(transactions, version))
+
+    def issue(
+        self, transactions: list[CommitTransaction], version: int
+    ) -> PendingBatch:
+        """The first half of resolve(): rebase, pack, place and dispatch
+        the batch (with any compaction or overflow check it falls due
+        for), and return without waiting for the device. Batches issue
+        in version order; `complete` gives each its BatchResult."""
         self._maybe_rebase(version)
         with stage(self.metrics.pack, STAGE_NAMES["pack"]):
             batch = packing.pack_batch(
                 transactions, version, self.base_version, self.config
             )
-        return self._dispatch_and_assemble(
+        return self._issue(
             batch,
             report=[t.report_conflicting_keys for t in transactions],
             begin_key_of_row=lambda r: transactions[
@@ -318,10 +351,16 @@ class TpuConflictSet:
     def resolve_columnar_packed(
         self, cols: packing.ColumnarBatch, batch: packing.PackedBatch
     ) -> BatchResult:
-        """Dispatch + reply assembly for a pack_columnar_batch result.
-        The conflicting-key report's begin keys slice out of the blob
+        """Dispatch + reply assembly for a pack_columnar_batch result."""
+        return self.complete(self.issue_columnar_packed(cols, batch))
+
+    def issue_columnar_packed(
+        self, cols: packing.ColumnarBatch, batch: packing.PackedBatch
+    ) -> PendingBatch:
+        """The dispatch half of resolve_columnar_packed. The
+        conflicting-key report's begin keys slice out of the blob
         lazily — only the (rare) rows the kernel flagged are touched."""
-        return self._dispatch_and_assemble(
+        return self._issue(
             batch,
             report=[
                 bool(int(f) & packing.COLUMNAR_FLAG_REPORT)
@@ -348,12 +387,13 @@ class TpuConflictSet:
             self.base_version += delta
             self.metrics.counters.add("rebases")
 
-    def _dispatch_and_assemble(
+    def _issue(
         self, batch: packing.PackedBatch, report, begin_key_of_row
-    ) -> BatchResult:
-        """The shared tail of resolve()/resolve_columnar(): place the
-        packed batch on the device, dispatch it (tiered or classic) and
-        assemble the BatchResult."""
+    ) -> PendingBatch:
+        """The shared dispatch of the object and columnar paths: place
+        the packed batch on the device, dispatch it (tiered or classic)
+        and start the copy of its result columns to the host, which
+        then runs as soon as the program ends."""
         self.metrics.counters.add("resolveBatches")
         if self.tiered:
             out = self._resolve_args_tiered(batch.device_args())
@@ -361,9 +401,44 @@ class TpuConflictSet:
             args = self._transfer(batch.device_args())
             with stage(self.metrics.kernel, STAGE_NAMES["dispatch"]):
                 self.state, out = self._resolve(self.state, args)
-        t2 = time.perf_counter()
-        result = self._assemble_result(batch, out, report, begin_key_of_row)
-        self.metrics.fence.sample(time.perf_counter() - t2)
+        for col in _result_columns(out):
+            col.copy_to_host_async()
+        return PendingBatch(batch, out, report, begin_key_of_row,
+                            time.perf_counter())
+
+    def read_back(self, pending: PendingBatch) -> None:
+        """Wait for an issued batch's result columns and hold them on
+        the host. It touches nothing but `pending`, so the wait may run
+        on another thread than the one that issues and completes; the
+        span marks it there, and `complete` samples the readback."""
+        with stage(None, STAGE_NAMES["readback"]):
+            pending.host = tuple(
+                np.asarray(c) for c in _result_columns(pending.out)
+            )
+
+    def complete(self, pending: PendingBatch) -> BatchResult:
+        """The second half of a resolve: the readback (unless read_back
+        already waited), the overflow refusal and the reply's assembly.
+        The readback is sampled from the issue's end to here: the wait
+        for the device and the copy, and where another thread waited,
+        the hand-back. Refuses to externalize decisions computed against
+        a truncated history (ADVICE r1 — the interval-based check is
+        only for the async packed path)."""
+        if pending.host is None:
+            self.read_back(pending)
+        t0 = time.perf_counter()
+        readback_s = t0 - pending.issued_at
+        self.metrics.readback.sample(readback_s)
+        verdict, overflow, hist_read, intra_first = pending.host
+        if bool(overflow):
+            self._raise_overflow()
+        n = pending.batch.n_txns
+        with stage(self.metrics.assemble, STAGE_NAMES["assemble"]):
+            result = self._assemble(
+                pending.batch, verdict[:n], hist_read, intra_first[:n],
+                pending.report, pending.begin_key_of_row,
+            )
+        self.metrics.fence.sample(readback_s + time.perf_counter() - t0)
         return result
 
     def _transfer(self, args: dict) -> dict:
@@ -900,10 +975,11 @@ class TpuConflictSet:
 
     # -- reply assembly --------------------------------------------------
 
-    def _assemble_result(
-        self, batch, out: C.BatchVerdict, report, begin_key_of_row
-    ) -> BatchResult:
-        """Shared reply assembly for the object and columnar paths.
+    @staticmethod
+    def _assemble(batch, verdict, hist_read, intra_first, report,
+                  begin_key_of_row) -> BatchResult:
+        """The reply from the read-back result columns, shared by the
+        object and columnar paths.
 
         `report[t]` = the txn asked for the conflicting-key report;
         `begin_key_of_row(r)` = flat read row r's range BEGIN key bytes
@@ -911,28 +987,6 @@ class TpuConflictSet:
         from the frame's key blob) — only the rows the kernel flagged
         as history hits are ever touched.
         """
-        n = batch.n_txns
-        with stage(self.metrics.readback, STAGE_NAMES["readback"]):
-            verdict, overflow, hist_read, intra_first = (
-                np.asarray(c) for c in (
-                    out.verdict, out.overflow, out.hist_conflict_read,
-                    out.intra_first_range,
-                )
-            )
-            verdict, intra_first = verdict[:n], intra_first[:n]
-        # Same device sync the verdict read just paid: refuse to externalize
-        # decisions computed against a truncated history (ADVICE r1 — the
-        # interval-based check is only for the async packed path).
-        if bool(overflow):
-            self._raise_overflow()
-        with stage(self.metrics.assemble, STAGE_NAMES["assemble"]):
-            return self._assemble(batch, verdict, hist_read, intra_first,
-                                  report, begin_key_of_row)
-
-    @staticmethod
-    def _assemble(batch, verdict, hist_read, intra_first, report,
-                  begin_key_of_row) -> BatchResult:
-        """The reply from the read-back result columns."""
         n = batch.n_txns
         verdicts = [TransactionResult(int(v)) for v in verdict]
 

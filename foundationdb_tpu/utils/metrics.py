@@ -270,11 +270,12 @@ class stage:
     (`jax.profiler.TraceAnnotation`): a profiled run then shows each
     stage on the device trace's clock. It never imports JAX itself, so
     JAX-free roles and the simulation stay JAX-free; with no profiler
-    session running the annotation is one cheap check."""
+    session running the annotation is one cheap check. With `sample`
+    None it only marks the block."""
 
     __slots__ = ("sample", "name", "_t0", "_event")
 
-    def __init__(self, sample: LatencySample, name: str):
+    def __init__(self, sample: Optional[LatencySample], name: str):
         self.sample = sample
         self.name = name
 
@@ -288,7 +289,8 @@ class stage:
         return self
 
     def __exit__(self, *exc) -> None:
-        self.sample.sample(_time.perf_counter() - self._t0)
+        if self.sample is not None:
+            self.sample.sample(_time.perf_counter() - self._t0)
         if self._event is not None:
             self._event.__exit__(*exc)
 

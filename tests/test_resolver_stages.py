@@ -114,7 +114,7 @@ def served():
 def test_status_lists_the_stages_in_batch_order(served):
     _role, qos, _reqs = served
     assert list(qos["stages"]) == [*STAGE_NAMES, "arg_bytes",
-                                   "device_arg_bytes"]
+                                   "device_arg_bytes", "overlap"]
 
 
 @pytest.mark.parametrize("key", PER_BATCH)
