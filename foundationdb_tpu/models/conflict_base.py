@@ -115,6 +115,9 @@ class KernelStageMetrics:
         # syncs — the fdbtop kernel panel's per-shard columns
         self.shard_count = 1
         self.collective = LatencySample("collectiveSeconds")
+        # mesh-sharded kernel: per batch, the most conflict ranges one
+        # partition got over the mean, in % (sampled in the pack stage)
+        self.shard_skew = LatencySample("shardSkewPct")
         # device-memory gauges: live-buffer + peak bytes on
         # the dispatch device, sampled on the same overflow-check syncs
         # (no extra fences); zero on backends that don't report (CPU)
@@ -151,8 +154,9 @@ class KernelStageMetrics:
 
     def stages(self) -> dict:
         """The conflict set's share of the role's `qos.stages`: each
-        stage sample and the two byte samples, as_dict() by status key."""
-        return {
+        stage sample and the two byte samples, as_dict() by status key,
+        and on a sharded instance the partition skew."""
+        out = {
             "pack": self.pack.as_dict(),
             "transfer": self.transfer.as_dict(),
             "dispatch": self.kernel.as_dict(),
@@ -163,6 +167,9 @@ class KernelStageMetrics:
             "arg_bytes": self.arg_bytes.as_dict(),
             "device_arg_bytes": self.device_arg_bytes.as_dict(),
         }
+        if self.shard_count > 1:
+            out["shard_skew"] = self.shard_skew.as_dict()
+        return out
 
     def qos(self) -> dict:
         """The compressed occupancy view the saturation layer reads

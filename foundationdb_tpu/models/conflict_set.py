@@ -275,6 +275,9 @@ class TpuConflictSet:
                 else _sh.default_boundaries(config.n_shards)
             )
             self.shard_boundaries = boundaries
+            #: the packed interior split keys, for the host's count of
+            #: each partition's ranges (_sample_shard_skew)
+            self._split_keys = _sh.make_partition(boundaries, config)[0][1:]
             self.state, self._part_lo, self._part_hi = (
                 _sh.init_sharded_tiered(config, self._mesh, boundaries)
             )
@@ -321,6 +324,7 @@ class TpuConflictSet:
             batch = packing.pack_batch(
                 transactions, version, self.base_version, self.config
             )
+            self._sample_shard_skew(batch)
         return self._issue(
             batch,
             report=[t.report_conflicting_keys for t in transactions],
@@ -345,6 +349,7 @@ class TpuConflictSet:
             batch = packing.pack_batch_columnar(
                 cols, version, self.base_version, self.config
             )
+            self._sample_shard_skew(batch)
         self.metrics.counters.add("columnarBatches")
         return batch
 
@@ -376,6 +381,19 @@ class TpuConflictSet:
         out, never materializing per-transaction objects."""
         batch = self.pack_columnar_batch(cols, version)
         return self.resolve_columnar_packed(cols, batch)
+
+    def _sample_shard_skew(self, batch: packing.PackedBatch) -> None:
+        """On a sharded instance, the packed batch's balance over the
+        partitions: the most conflict ranges one partition got, over the
+        mean, in % (100 is even; n_shards x 100 is all in one). The load
+        FoundationDB's ResolutionBalancer watches across resolvers."""
+        if not self.sharded:
+            return
+        from foundationdb_tpu.parallel import sharding as _sh
+
+        loads = _sh.partition_loads(batch, self._split_keys)
+        if loads.any():
+            self.metrics.shard_skew.sample(loads.max() / loads.mean() * 100)
 
     def _maybe_rebase(self, version: int) -> None:
         if version - self.base_version > REBASE_THRESHOLD:

@@ -181,6 +181,48 @@ def make_partition(
     return lo, hi
 
 
+def _keys_below(bound: np.ndarray, keys: np.ndarray, first: np.ndarray,
+                or_equal: bool) -> int:
+    """How many packed `keys` lie below the packed `bound` (or at it,
+    with `or_equal`). `first` is their first words, which decide almost
+    every key; keys whose first word ties the bound's are compared
+    whole."""
+    n = int(np.count_nonzero(first < bound[0]))
+    tied = np.flatnonzero(first == bound[0])
+    if len(tied):
+        b = tuple(bound)
+        n += sum(1 for k in map(tuple, keys[tied])
+                 if k < b or (or_equal and k == b))
+    return n
+
+
+def partition_loads(batch: packing.PackedBatch,
+                    bounds: np.ndarray) -> np.ndarray:
+    """Read and write conflict ranges of a packed batch that land in each
+    partition of the interior split keys `bounds` ([n_shards - 1, W]
+    packed, ascending): ResolutionRequestBuilder's split, so a range
+    that straddles a boundary counts in every partition it touches.
+    Host numpy on the batch's live rows; [n_shards] int64."""
+    loads = np.zeros(len(bounds) + 1, np.int64)
+    for begin, end, valid, count in (
+        (batch.read_begin, batch.read_end, batch.read_valid, batch.n_reads),
+        (batch.write_begin, batch.write_end, batch.write_valid,
+         batch.n_writes),
+    ):
+        live = valid[:count]
+        rows = slice(0, count) if live.all() else np.flatnonzero(live)
+        begin, end = begin[rows], end[rows]
+        b0 = np.ascontiguousarray(begin[:, 0])
+        e0 = np.ascontiguousarray(end[:, 0])
+        # [begin, end) touches partition s iff begin < its upper bound
+        # and end > its lower bound; as begin < end, every range with
+        # end <= the lower bound also has begin below the upper one
+        below = [_keys_below(b, begin, b0, False) for b in bounds]
+        ended = [_keys_below(b, end, e0, True) for b in bounds]
+        loads += np.array(below + [len(b0)]) - np.array([0] + ended)
+    return loads
+
+
 # ---------------------------------------------------------------------------
 # The MESH-SHARDED DELTA-TIERED kernel (ISSUE 11): the production tiered
 # path (ops/delta.py — the kernel TpuConflictSet._dispatch_tiered runs)
