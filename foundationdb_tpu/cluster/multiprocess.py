@@ -627,6 +627,28 @@ def _decode_alloc_count(txns) -> int:
     return n
 
 
+def _rung_warm_batches(rungs) -> list[list]:
+    """One dummy batch per row rung, each taken by its own rung and no
+    smaller one: for each coordinate (txns, reads, writes) one more than
+    the rung below holds where this rung holds more, else one. Without
+    rungs (a conflict set that packs no rows), the one-txn batch. The
+    reads and writes ride the first txn; the rest carry none."""
+    key = (b"\x00warm", b"\x00warm\x00")
+    batches, below = [], (0, 0, 0)
+    for rung in rungs or ((1, 1, 1),):
+        n, nr, nw = (lo + 1 if hi > lo else 1 for lo, hi in zip(below, rung))
+        batches.append(
+            [CommitTransaction(read_conflict_ranges=[key] * nr,
+                               write_conflict_ranges=[key] * nw,
+                               read_snapshot=0)]
+            + [CommitTransaction(read_conflict_ranges=[],
+                                 write_conflict_ranges=[], read_snapshot=0)
+               for _ in range(n - 1)]
+        )
+        below = rung
+    return batches
+
+
 #: batches a ResolverRole keeps issued on the device and not yet
 #: completed: one running, and the next one issued behind it. A deeper
 #: queue adds latency and no throughput.
@@ -854,12 +876,15 @@ class ResolverRole:
         first commit batch: a cold jit compile (seconds) landing inside
         the first resolve request was the wire-mode tpu-force p50
         pathology (PIPELINE_r06: 18.9s) — the stall hid in commit
-        latency where no ledger attributed it. A throwaway conflict set
-        with the same config drives every padded-shape kernel through
-        the shared module-level jit cache (shapes are G-independent, so
-        one dummy resolve covers all batch sizes), and the measured
-        seconds land in KernelStageMetrics.compile where cluster_status
-        and commit_debug can see them."""
+        latency where no ledger attributed it. A device conflict set
+        dispatches each batch at the smallest of its row rungs
+        (`TpuConflictSet.rungs`) that holds it, one compiled program per
+        rung, so one dummy resolve per rung, each on a throwaway
+        conflict set with the same config, drives every program a batch
+        can take through the shared module-level jit cache, beside the
+        compaction program; the measured seconds land in
+        KernelStageMetrics.compile where cluster_status and commit_debug
+        can see them."""
         import time as _time
         from concurrent.futures import ThreadPoolExecutor
 
@@ -868,29 +893,33 @@ class ResolverRole:
         from foundationdb_tpu.models.conflict_base import make_conflict_set
 
         t0 = _time.perf_counter()
-        scratch = make_conflict_set(kcfg, backend)
-        # the compaction program too, compiled beside the kernel: a
+
+        def resolve_one(txns) -> None:
+            scratch = make_conflict_set(kcfg, backend)
+            scratch.resolve(txns, 1)
+            jax.block_until_ready(getattr(scratch, "state", None))
+
+        # the compaction program too, compiled beside the kernels: a
         # tiered resolver runs it every compact_interval batches, its
-        # first compile must not land inside a commit, and each of the
-        # two takes minutes at 64K-txn shapes on a few host cores
+        # first compile must not land inside a commit, and each takes
+        # minutes at 64K-txn shapes on a few host cores
         compact = getattr(
             make_conflict_set(kcfg, backend), "compact_history", None
         )
-        with ThreadPoolExecutor(max_workers=1) as pool:
-            fut = pool.submit(compact) if compact is not None else None
-            scratch.resolve(
-                [
-                    CommitTransaction(
-                        read_conflict_ranges=[(b"\x00warm", b"\x00warm\x00")],
-                        write_conflict_ranges=[(b"\x00warm", b"\x00warm\x00")],
-                        read_snapshot=0,
-                    )
-                ],
-                1,
-            )
-            if fut is not None:
+        # two programs at a time, as the kernel beside the compaction
+        # program compiled before the rungs: four compiling at once on a
+        # v5e host overflowed a stack inside the TPU compiler (SIGSEGV in
+        # a compiler pass, in one of two cold starts at the 64K-txn
+        # resolver's shapes), and one rung at a time took 36 s to load
+        # the 64K-txn resolver's programs from a warm cache there,
+        # against 9 s for the one kernel before the rungs
+        jobs = [(resolve_one, txns) for txns in
+                _rung_warm_batches(getattr(self._cs, "rungs", ()))]
+        if compact is not None:
+            jobs.insert(1, (compact,))
+        with ThreadPoolExecutor(max_workers=2) as pool:
+            for fut in [pool.submit(*job) for job in jobs]:
                 fut.result()
-        jax.block_until_ready(getattr(scratch, "state", None))
         dt = _time.perf_counter() - t0
         metrics = getattr(self._cs, "metrics", None)
         if metrics is not None:
@@ -1380,8 +1409,9 @@ class ResolverRole:
         # compile-cache counters)
         qos["kernel"] = {**self._kernel_metrics.qos(), **self._device_info}
         # per-batch stage samples in STAGE_NAMES order, then the bytes
-        # each batch hands the device and, on a sharded conflict set, its
-        # partition skew (the benchmark's per-layer readers)
+        # each batch hands the device, the rows it dispatches at and, on
+        # a sharded conflict set, its partition skew (the benchmark's
+        # per-layer readers)
         stages = {
             "decode": self.decode_time.as_dict(),
             "key_sample": self.key_sample_time.as_dict(),
@@ -1392,7 +1422,7 @@ class ResolverRole:
         qos["stages"] = {
             k: stages[k]
             for k in (*STAGE_NAMES, "arg_bytes", "device_arg_bytes",
-                      "overlap", "shard_skew")
+                      "dispatch_rows", "overlap", "shard_skew")
             if k in stages
         }
         # columnar-vs-object frame accounting (r12): bench_pipeline

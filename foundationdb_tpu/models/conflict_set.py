@@ -286,10 +286,14 @@ class TpuConflictSet:
             self._collective_probe_warm = False
         else:
             self.state = _D.init(config) if self.tiered else H.init(config)
+        #: the compiled row capacities a served batch is packed at
+        #: (packing.row_rungs): each batch dispatches at the smallest
+        #: that holds it, one compiled program per rung
+        self.rungs = packing.row_rungs(config)
         self._batches_since_check = 0
         self._batches_since_compact = 0
         #: conservative live-boundary bound of the delta tier since the
-        #: last compaction (2*max_writes per dispatched batch): the
+        #: last compaction (2 x write rows per dispatched batch): the
         #: delta_spill pressure signal — host arithmetic only, so spill
         #: decisions never cost a device sync (and are therefore
         #: invariant across pipelined/sharded/compact_interval paths)
@@ -322,7 +326,8 @@ class TpuConflictSet:
         self._maybe_rebase(version)
         with stage(self.metrics.pack, STAGE_NAMES["pack"]):
             batch = packing.pack_batch(
-                transactions, version, self.base_version, self.config
+                transactions, version, self.base_version, self.config,
+                self.rungs,
             )
             self._sample_shard_skew(batch)
         return self._issue(
@@ -341,13 +346,14 @@ class TpuConflictSet:
         """Rebase + decode a columnar wire batch straight into kernel
         tensors (packing.pack_batch_columnar — byte-identical to
         pack_batch on the equivalent transaction list, so decisions are
-        identical by construction). No per-txn Python objects. Split
-        from resolve_columnar so the wire ResolverRole can bracket
-        exactly this stage with its ColumnarDecode trace event."""
+        identical by construction), at the smallest rung that holds
+        them. No per-txn Python objects. Split from resolve_columnar so
+        the wire ResolverRole can bracket exactly this stage with its
+        ColumnarDecode trace event."""
         self._maybe_rebase(version)
         with stage(self.metrics.pack, STAGE_NAMES["pack"]):
             batch = packing.pack_batch_columnar(
-                cols, version, self.base_version, self.config
+                cols, version, self.base_version, self.config, self.rungs
             )
             self._sample_shard_skew(batch)
         self.metrics.counters.add("columnarBatches")
@@ -465,10 +471,14 @@ class TpuConflictSet:
         device, or replicated over the mesh on a sharded instance). The
         transfer stage times the copy's issue; the copy completes on a
         runtime thread, and the device's wait for it lands in the
-        readback. Samples the host bytes handed over and the bytes they
-        take on the device."""
+        readback. Samples the host bytes handed over, the bytes they
+        take on the device, and the read + write rows of the rung
+        dispatched."""
         m = self.metrics
         m.arg_bytes.sample(sum(v.nbytes for v in args.values()))
+        m.dispatch_rows.sample(
+            args["read_valid"].size + args["write_valid"].size
+        )
         with stage(m.transfer, STAGE_NAMES["transfer"]):
             placed = jax.device_put(args, self._batch_sharding)
         m.device_arg_bytes.sample(
@@ -574,8 +584,9 @@ class TpuConflictSet:
         if getattr(cfg, "delta_spill", False):
             # SPILL-AND-COMPACT (ISSUE 14): before a dispatch whose
             # conservative boundary bound could overflow the delta tier
-            # (each batch adds at most 2*max_writes boundary rows; the
-            # host tracks the bound so no device sync is ever paid),
+            # (each batch adds at most 2 boundary rows per write row of
+            # the rung it dispatches at; the host tracks the bound so no
+            # device sync is ever paid),
             # fold delta into MAIN with the compaction program — an
             # asynchronous device dispatch like any batch — instead of
             # letting the in-kernel latch trip and raise. A stream
@@ -584,7 +595,7 @@ class TpuConflictSet:
             # whose own bound exceeds delta_capacity still reaches the
             # latch+raise backstop (a configuration error spill cannot
             # paper over).
-            add = 2 * cfg.max_writes * kb
+            add = 2 * int(stacked_args["write_valid"].shape[-1]) * kb
             if self._spill_bound_rows + add > cfg.delta_capacity:
                 self.compact_history()
                 self.metrics.counters.add("spills")
@@ -930,11 +941,12 @@ class TpuConflictSet:
         pressure bound to the REAL delta occupancy, piggybacked on the
         sync the overflow check already paid — zero extra fences.
 
-        The host bound accrues 2*max_writes per dispatched batch
-        (duplicate keys and merged ranges make the true boundary count
-        far smaller on most streams); at this sync every dispatched
-        batch has completed, so the measured live boundary count IS the
-        exact occupancy the bound conservatively over-estimates.
+        The host bound accrues 2 x the write rows of the rung each batch
+        dispatched at (duplicate keys and merged ranges make the true
+        boundary count far smaller on most streams); at this sync every
+        dispatched batch has completed, so the measured live boundary
+        count IS the exact occupancy the bound conservatively
+        over-estimates.
         Re-anchoring to min(bound, live) keeps the bound conservative
         (batches dispatched after the sync keep accruing the worst
         case) while shedding the accumulated over-estimate — ~2x fewer

@@ -151,7 +151,8 @@ def unpack_key(row: np.ndarray) -> bytes:
 class PackedBatch:
     """One batch of transactions in kernel form (all numpy, host-side).
 
-    Shapes are exactly the KernelConfig caps; `n_txns`/`n_reads`/`n_writes`
+    Shapes are one rung of `row_rungs(config)` (the KernelConfig caps
+    unless the packer was given the ladder); `n_txns`/`n_reads`/`n_writes`
     give the live prefix sizes (rows past them are masked invalid).
     """
 
@@ -197,6 +198,36 @@ class PackedBatch:
         }
 
 
+def row_rungs(config: KernelConfig) -> tuple[tuple[int, int, int], ...]:
+    """The row capacities (txns, reads, writes) a batch may be packed
+    at, smallest first: (r, r, r) for each `r` of max(max_txns // 64,
+    64) and max_txns, capped by the config's own caps, then the
+    config's full shape, duplicates dropped. Every rung is a power of
+    two in each coordinate (min of powers of two), and the rungs grow
+    in every coordinate, so the first that holds a batch is the
+    smallest."""
+    full = (config.max_txns, config.max_reads, config.max_writes)
+    rungs: list[tuple[int, int, int]] = []
+    for r in sorted({max(config.max_txns // 64, 64), config.max_txns}):
+        rung = tuple(min(cap, r) for cap in full)
+        if rung not in rungs:
+            rungs.append(rung)
+    if full not in rungs:
+        rungs.append(full)
+    return tuple(rungs)
+
+
+def _rung(config: KernelConfig, rungs, n: int, nread: int,
+          nwrite: int) -> tuple[int, int, int]:
+    """The first of `rungs` that holds n txns, nread reads and nwrite
+    writes; the config's full shape when `rungs` is None. The caller
+    has already refused a batch past the config's caps."""
+    for rung in rungs or ():
+        if n <= rung[0] and nread <= rung[1] and nwrite <= rung[2]:
+            return rung
+    return (config.max_txns, config.max_reads, config.max_writes)
+
+
 def _clamp_version(v: int, base: int) -> np.int32:
     off = v - base
     if off <= int(VERSION_NEG):
@@ -206,11 +237,43 @@ def _clamp_version(v: int, base: int) -> np.int32:
     return np.int32(off)
 
 
+def _txn_columns(cfg: KernelConfig, rungs, off, r_counts, nread: int,
+                 nwrite: int):
+    """What both packers share ahead of the range rows: refuse a
+    snapshot offset past int32 or a batch past the config's caps, choose
+    the rung (`_rung`), and build the per-txn columns at its txn
+    capacity. `off` holds each txn's snapshot offset from the base
+    version and `r_counts` its read count. Returns (b, nr, nw,
+    txn_valid, snapshot, has_reads)."""
+    high = off >= 2**31
+    if high.any():
+        bad = int(off[high][0])
+        raise OverflowError(f"version offset {bad} overflows int32; rebase")
+    if nread > cfg.max_reads:
+        raise ValueError(f"{nread} read ranges > max_reads {cfg.max_reads}")
+    if nwrite > cfg.max_writes:
+        raise ValueError(
+            f"{nwrite} write ranges > max_writes {cfg.max_writes}"
+        )
+    n = len(off)
+    b, nr, nw = _rung(cfg, rungs, n, nread, nwrite)
+    txn_valid = np.zeros((b,), bool)
+    txn_valid[:n] = True
+    snapshot = np.full((b,), VERSION_NEG, np.int32)
+    snapshot[:n] = np.where(
+        off <= int(VERSION_NEG), int(VERSION_NEG), off
+    ).astype(np.int32)
+    has_reads = np.zeros((b,), bool)
+    has_reads[:n] = r_counts > 0
+    return b, nr, nw, txn_valid, snapshot, has_reads
+
+
 def pack_batch(
     transactions,
     version: int,
     base_version: int,
     config: KernelConfig,
+    rungs=None,
 ) -> PackedBatch:
     """Pack a list of CommitTransaction into kernel tensors.
 
@@ -224,42 +287,33 @@ def pack_batch(
     append loops, so host packing stops dominating the pipelined stream
     (the pack stage of TpuConflictSet.resolve_stream_pipelined).
     Byte-identical to pack_batch_reference (tests/test_packing.py).
+
+    With `rungs` (`row_rungs(config)`) the tensors are padded to the
+    first rung that holds the batch, not to the config's caps: the same
+    bytes as the full-size pack cut to the rung, with the padding rows'
+    txn id the rung's txn capacity.
     """
     cfg = config
-    b, nr, nw, w = cfg.max_txns, cfg.max_reads, cfg.max_writes, cfg.key_words
     n = len(transactions)
-    if n > b:
-        raise ValueError(f"{n} txns > max_txns {b}")
+    if n > cfg.max_txns:
+        raise ValueError(f"{n} txns > max_txns {cfg.max_txns}")
 
-    txn_valid = np.zeros((b,), bool)
-    snapshot = np.full((b,), VERSION_NEG, np.int32)
-    has_reads = np.zeros((b,), bool)
     r_lists = [tr.read_conflict_ranges for tr in transactions]
     w_lists = [tr.write_conflict_ranges for tr in transactions]
     if n:
-        txn_valid[:n] = True
         off = np.fromiter(
             (tr.read_snapshot for tr in transactions), np.int64, count=n
         ) - base_version
-        high = off >= 2**31
-        if high.any():
-            bad = int(off[high][0])
-            raise OverflowError(f"version offset {bad} overflows int32; rebase")
-        snapshot[:n] = np.where(
-            off <= int(VERSION_NEG), int(VERSION_NEG), off
-        ).astype(np.int32)
         r_counts = np.fromiter((len(x) for x in r_lists), np.int64, count=n)
         w_counts = np.fromiter((len(x) for x in w_lists), np.int64, count=n)
-        has_reads[:n] = r_counts > 0
     else:
-        r_counts = w_counts = np.zeros((0,), np.int64)
-
+        off = r_counts = w_counts = np.zeros((0,), np.int64)
     nread = int(r_counts.sum())
     nwrite = int(w_counts.sum())
-    if nread > nr:
-        raise ValueError(f"{nread} read ranges > max_reads {nr}")
-    if nwrite > nw:
-        raise ValueError(f"{nwrite} write ranges > max_writes {nw}")
+    b, nr, nw, txn_valid, snapshot, has_reads = _txn_columns(
+        cfg, rungs, off, r_counts, nread, nwrite
+    )
+    w = cfg.key_words
 
     r_flat = [rg for lst in r_lists for rg in lst]
     w_flat = [rg for lst in w_lists for rg in lst]
@@ -300,10 +354,10 @@ def pack_batch(
         read_end=re,
         # KERNEL LAYOUT CONTRACT (ops/group.py per-txn windows): rows are
         # grouped by txn in nondecreasing txn order with ranges in
-        # declaration order, and PADDING rows carry txn id == max_txns —
-        # the flat (batch, txn) segment id is then monotone, which lets
-        # the kernel do per-txn reductions with cumsum windows instead
-        # of scatters.
+        # declaration order, and PADDING rows carry txn id == the
+        # rung's txn capacity (its B) — the flat (batch, txn) segment
+        # id is then monotone, which lets the kernel do per-txn
+        # reductions with cumsum windows instead of scatters.
         read_txn=_col(r_txn, nr, fill=b),
         read_index=_col(r_idx, nr),
         read_valid=_col([True] * nread, nr, bool),
@@ -525,6 +579,7 @@ def pack_batch_columnar(
     version: int,
     base_version: int,
     config: KernelConfig,
+    rungs=None,
 ) -> PackedBatch:
     """Columnar twin of pack_batch: flat columns -> kernel tensors.
 
@@ -534,38 +589,26 @@ def pack_batch_columnar(
     the key matrices from the same pack_keys_from_blob scatter, so the
     columnar wire path and the object path cannot diverge in what the
     kernel sees. No per-transaction Python objects are materialized.
+    `rungs` pads to the first rung that holds the batch, as in
+    pack_batch.
     """
     cfg = config
-    b, nr, nw, w = cfg.max_txns, cfg.max_reads, cfg.max_writes, cfg.key_words
     n = cols.n_txns
-    if n > b:
-        raise ValueError(f"{n} txns > max_txns {b}")
+    if n > cfg.max_txns:
+        raise ValueError(f"{n} txns > max_txns {cfg.max_txns}")
 
-    txn_valid = np.zeros((b,), bool)
-    snapshot = np.full((b,), VERSION_NEG, np.int32)
-    has_reads = np.zeros((b,), bool)
     if n:
-        txn_valid[:n] = True
         off = cols.snapshots.astype(np.int64) - base_version
-        high = off >= 2**31
-        if high.any():
-            bad = int(off[high][0])
-            raise OverflowError(f"version offset {bad} overflows int32; rebase")
-        snapshot[:n] = np.where(
-            off <= int(VERSION_NEG), int(VERSION_NEG), off
-        ).astype(np.int32)
         r_counts = cols.read_counts.astype(np.int64)
         w_counts = cols.write_counts.astype(np.int64)
-        has_reads[:n] = r_counts > 0
     else:
-        r_counts = w_counts = np.zeros((0,), np.int64)
-
+        off = r_counts = w_counts = np.zeros((0,), np.int64)
     nread = int(r_counts.sum())
     nwrite = int(w_counts.sum())
-    if nread > nr:
-        raise ValueError(f"{nread} read ranges > max_reads {nr}")
-    if nwrite > nw:
-        raise ValueError(f"{nwrite} write ranges > max_writes {nw}")
+    b, nr, nw, txn_valid, snapshot, has_reads = _txn_columns(
+        cfg, rungs, off, r_counts, nread, nwrite
+    )
+    w = cfg.key_words
 
     ids = np.arange(n, dtype=np.int32)
     r_txn = np.repeat(ids, r_counts)

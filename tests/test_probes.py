@@ -544,7 +544,8 @@ def test_every_declared_probe_fires():
 
     # -- range-path probes (ISSUE 14) -------------------------------------
     # the sorted-endpoint sweep dispatching and the pressure spill
-    # folding delta into MAIN (delta sized so the conservative bound
+    # folding delta into MAIN (delta sized so the conservative bound,
+    # 2 x 8 write rows of the (8, 8, 8) rung these batches take,
     # trips on the second batch)
     import dataclasses as _dc
 
@@ -557,7 +558,7 @@ def test_every_declared_probe_fires():
             max_key_bytes=8, max_txns=8, max_reads=16, max_writes=16,
             history_capacity=256, window_versions=1000,
         ),
-        delta_capacity=48, compact_interval=0,
+        delta_capacity=24, compact_interval=0,
         range_sweep=True, delta_spill=True,
     )
     cs = TpuConflictSet(sweep_cfg)

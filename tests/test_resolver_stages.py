@@ -39,7 +39,7 @@ TINY_KERNEL = (
 BATCHES = 34
 PER_BATCH = ("decode", "key_sample", "pack", "transfer", "dispatch",
              "readback", "assemble", "encode", "arg_bytes",
-             "device_arg_bytes")
+             "device_arg_bytes", "dispatch_rows")
 #: the stages inside ResolverRole._resolve_now (computeTime)
 IN_COMPUTE = ("key_sample", "pack", "transfer", "dispatch", "compact",
               "overflow_check", "readback", "assemble")
@@ -114,7 +114,8 @@ def served():
 def test_status_lists_the_stages_in_batch_order(served):
     _role, qos, _reqs = served
     assert list(qos["stages"]) == [*STAGE_NAMES, "arg_bytes",
-                                   "device_arg_bytes", "overlap"]
+                                   "device_arg_bytes", "dispatch_rows",
+                                   "overlap"]
 
 
 @pytest.mark.parametrize("key", PER_BATCH)
@@ -155,11 +156,15 @@ def test_conflict_set_total_keeps_its_stages(served):
 def test_arg_bytes_are_the_kernel_arguments(served):
     role, qos, reqs = served
     batch = packing.pack_batch_columnar(reqs[0].cols, reqs[0].version, 0,
-                                        role._cs.config)
+                                        role._cs.config, role._cs.rungs)
     want = sum(v.nbytes for v in batch.device_args().values())
     assert qos["stages"]["arg_bytes"]["mean"] == want
     # the CPU backend keeps an array in its host bytes, unpadded
     assert qos["stages"]["device_arg_bytes"]["mean"] == want
+    # 60 txns of one read and one write take the (64, 64, 64) rung of
+    # the (64, 256, 256) config
+    assert batch.read_valid.shape == batch.write_valid.shape == (64,)
+    assert qos["stages"]["dispatch_rows"]["mean"] == 64 + 64
 
 
 def test_profiler_records_the_stages_as_sibling_host_events(tmp_path):
