@@ -5,11 +5,10 @@ The TPU conflict kernel versus the measured CPU baseline
 (foundationdb_tpu/native — the stand-in for the reference's
 `fdbserver -r skiplisttest` microbench, fdbserver/SkipList.cpp:1082-1177:
 uniform 1M keyspace, one read + one write range per txn; snapshots lag up
-to two batch-versions so reads really contend with history). Since r6
-the default device path is the DELTA-TIERED kernel
-(foundationdb_tpu.ops.delta — G-independent compile, delta-tier merges,
-periodic compaction, optional read dedup); BENCH_KERNEL=classic runs the
-r3-r5 single-tier mega-sort group kernel.
+to two batch-versions so reads really contend with history). The device
+path is the DELTA-TIERED kernel (foundationdb_tpu.ops.delta —
+G-independent compile, delta-tier merges, periodic compaction, optional
+read dedup).
 
 Prints ONE JSON line whose PRIMARY `value` is the TRANSFER-INCLUSIVE
 pipelined rate (pack -> host->device copy -> kernel, overlapped by
@@ -27,8 +26,7 @@ blocking calls, device-resident and transfer-inclusive.
 
 Env overrides: BENCH_TXNS (default 65536), BENCH_BATCHES (default 32),
 BENCH_CPU_BATCHES (default 4), BENCH_MODE (uniform | zipf | range —
-BASELINE.json configs 1-3), BENCH_KERNEL (tiered | classic),
-BENCH_FUSE (group size; tiered compiles ONCE for any value),
+BASELINE.json configs 1-3), BENCH_FUSE (group size; tiered compiles ONCE for any value),
 BENCH_DELTA_CAP, BENCH_COMPACT_INTERVAL, BENCH_REPS.
 
 Flags: --profile-dir DIR captures a jax.profiler device/compile trace
@@ -58,7 +56,7 @@ SNAPSHOT_LAG = 2 * VERSION_STEP  # spans ~2 batches: history conflicts real
 
 
 def build_stream(mode: str, n_txns: int, n_batches: int, *,
-                 kernel: str = "tiered", fuse: int = 8,
+                 fuse: int = 8,
                  compact_interval: int = 0, delta_cap: int = 0,
                  sweep_allowed: bool = True):
     """The bench stream for `mode` (BASELINE configs 1-3 and the YCSB
@@ -107,7 +105,7 @@ def build_stream(mode: str, n_txns: int, n_batches: int, *,
     # ycsb_e arms the ISSUE-14 device-native range path: the
     # sorted-endpoint sweep probe + spill-and-compact pressure handling
     # (both tiered-only; BENCH_SWEEP=0 ablates back to the probe path)
-    sweep = mode == "ycsb_e" and kernel == "tiered" and sweep_allowed
+    sweep = mode == "ycsb_e" and sweep_allowed
 
     from foundationdb_tpu.config import KernelConfig
     from foundationdb_tpu.testing.benchgen import skiplist_style_batch
@@ -142,7 +140,7 @@ def build_stream(mode: str, n_txns: int, n_batches: int, *,
         window_versions=window,
         fixpoint_unroll=unroll,
         fixpoint_latch=latch,
-        delta_capacity=delta_cap if kernel == "tiered" else 0,
+        delta_capacity=delta_cap,
         compact_interval=compact_interval,
         range_sweep=sweep,
         delta_spill=sweep,
@@ -197,7 +195,7 @@ def build_stream(mode: str, n_txns: int, n_batches: int, *,
     # common (zipf); a uniform stream's distinct count ~= its point
     # count, so dedup would add sorts for nothing and stays off.
     dedup = 0
-    if kernel == "tiered" and not sweep:
+    if not sweep:
         # (sweep-configured streams skip dedup: the endpoint sweep has
         # no per-range searches to dedup and the knobs are exclusive)
         max_uniq = 0
@@ -235,7 +233,6 @@ def main():
     cpu_batches = int(os.environ.get("BENCH_CPU_BATCHES", 4))
     mode = os.environ.get("BENCH_MODE", "uniform")
     fuse = max(1, int(os.environ.get("BENCH_FUSE", 8)))
-    kernel = os.environ.get("BENCH_KERNEL", "tiered")
     import jax
 
     from foundationdb_tpu.utils import compile_cache, perf
@@ -251,7 +248,7 @@ def main():
     from foundationdb_tpu.models.conflict_set import TpuConflictSet
 
     config, batches, stream_profile, routed_backend = build_stream(
-        mode, n_txns, n_batches, kernel=kernel, fuse=fuse,
+        mode, n_txns, n_batches, fuse=fuse,
         compact_interval=int(os.environ.get("BENCH_COMPACT_INTERVAL", 0)),
         delta_cap=int(os.environ.get("BENCH_DELTA_CAP", 0)),
         sweep_allowed=os.environ.get("BENCH_SWEEP", "1") != "0",
@@ -340,17 +337,13 @@ def main():
     # Phase 4 reports the transfer-inclusive latency separately so the
     # staging effect is visible, and the JSON marks the methodology.
     # Batches are dispatched in groups of BENCH_FUSE (default 8) through
-    # the GROUP kernel (ops/group.py): one mega-sort program resolves the
-    # whole group — identical decisions (tests/test_group_parity.py), one
-    # dispatch per group, and
-    # the history merge amortized across the group. A loaded resolver
-    # coalescing its queue is exactly how the reference behaves under
-    # backpressure (fdbserver/Resolver.actor.cpp resolveBatch queueing).
-    # Per-batch latency is still reported un-fused (phase 4). Classic
-    # kernel: 8 batches per group — G=16 amortizes fixed costs further
-    # but its XLA compile exceeds 35 minutes on a single-core host. The
-    # tiered kernel has no such wall (G-independent body; BENCH_FUSE up
-    # to MAX_GROUP_TIERED=64, compile probe logs the flat curve).
+    # the tiered kernel (ops/delta.py): one program resolves the whole
+    # group, one dispatch per group. A loaded resolver coalescing its
+    # queue is exactly how the reference behaves under backpressure
+    # (fdbserver/Resolver.actor.cpp resolveBatch queueing). Per-batch
+    # latency is still reported un-fused (phase 4). The body is
+    # G-independent: BENCH_FUSE up to MAX_GROUP_TIERED=64, and the
+    # compile probe logs the flat curve.
     from foundationdb_tpu.utils.packing import stack_device_args
 
     dev_groups = [
@@ -383,12 +376,9 @@ def main():
     # G-independence probe (opt-in: BENCH_COMPILE_PROBE=1): compile the
     # SAME kernel at extra group sizes and log the wall time per G. The
     # tiered kernel's scan body is G-independent, so the curve is ~flat
-    # where the classic skeleton's grew with G to a >35min wall at G=16
-    # (ops/group.py MAX_GROUP note).
-    if os.environ.get("BENCH_COMPILE_PROBE") and kernel == "tiered":
-        # tiered only: probing the classic kernel at 2*fuse would pay
-        # the exact >35-minute G-scaling compile wall the probe exists
-        # to show is gone. Sizes clamp to the kernel's group cap.
+    # (ops/group.py MAX_GROUP note: a G-sized skeleton's compile grew to
+    # a >35min wall at G=16). Sizes clamp to the kernel's group cap.
+    if os.environ.get("BENCH_COMPILE_PROBE"):
         from foundationdb_tpu.ops.delta import MAX_GROUP_TIERED
 
         probe_cap = min(n_batches, MAX_GROUP_TIERED)
@@ -403,7 +393,7 @@ def main():
             warm_p.resolve_group_args(probe_args, check_latch=False)
             jax.block_until_ready(warm_p.state)
             log(f"compile probe G={g_probe}: "
-                f"{time.perf_counter() - t0:.1f}s wall (kernel={kernel})")
+                f"{time.perf_counter() - t0:.1f}s wall")
             del warm_p, probe_args
 
     def device_pass(check_parity=False, cfg_=None):
@@ -695,7 +685,6 @@ def main():
         "device": fingerprint,
         "compile_cache": cc_stats,
         "hlo_cost": hlo_cost,
-        "kernel": kernel,
         "delta_capacity": config.delta_capacity,
         "dedup_reads": config.dedup_reads,
         "range_sweep": config.range_sweep,

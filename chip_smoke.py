@@ -71,7 +71,7 @@ def phase_a(kernel_txns, clients, ops, records, batch) -> dict:
     import bench_pipeline
 
     args = types.SimpleNamespace(
-        kernel_txns=kernel_txns, classic_kernel=False, clients=clients,
+        kernel_txns=kernel_txns, clients=clients,
         ops=ops, records=records, batch=batch,
     )
     t0 = time.perf_counter()

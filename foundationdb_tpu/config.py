@@ -66,17 +66,17 @@ class KernelConfig:
     #: state returns unchanged, and the caller re-dispatches on the
     #: exact kernel. Loud refusal, never a silent wrong answer.
     fixpoint_latch: bool = False
-    #: >0 enables the DELTA-TIERED history path (ops/delta.py): new
-    #: per-group writes land in a delta tier of this boundary capacity,
-    #: queried alongside the immutable main tier and folded into main by
-    #: a periodic device-side compaction. Every per-batch shape in the
-    #: tiered kernel is independent of the group size G (one lax.scan
-    #: body), so XLA compiles once regardless of G — the r6 answer to
-    #: the MAX_GROUP=16 compile wall. Sizing: must hold the boundaries
-    #: written between compactions (<= 2*max_writes per batch, window-
-    #: trimmed); overflow raises, never truncates. 0 = classic
-    #: single-tier kernel (ops/group.py mega-sort over main).
-    delta_capacity: int = 0
+    #: Boundary capacity of the DELTA tier (ops/delta.py): new
+    #: per-group writes land here, queried alongside the immutable main
+    #: tier and folded into main by a periodic device-side compaction.
+    #: Every per-batch shape in the tiered kernel is independent of the
+    #: group size G (one lax.scan body), so XLA compiles once regardless
+    #: of G. Sizing: must hold the boundaries written between
+    #: compactions (<= 2*max_writes per batch, window-trimmed); overflow
+    #: raises, never truncates. Unset (None), it is sized by that rule:
+    #: 2 * max_writes * max(compact_interval, 1) (`dataclasses.replace`
+    #: keeps the size so made). It must be positive.
+    delta_capacity: int | None = None
     #: >0 compiles device-side HOT-KEY DEDUP of read conflict ranges
     #: before the main-tier probe: identical (begin, end) ranges are
     #: sort+unique'd and only this many DISTINCT ranges are binary-
@@ -84,7 +84,7 @@ class KernelConfig:
     #: not points (the kernel-side attack on zipf contention). A batch
     #: with more distinct live read ranges than this trips the
     #: unconverged latch — state unchanged, host re-dispatches the exact
-    #: kernel — never a silent wrong answer. Tiered path only.
+    #: kernel — never a silent wrong answer.
     dedup_reads: int = 0
     #: True compiles the tiered kernel's main-tier probe as a SORTED-
     #: ENDPOINT SWEEP (ops/delta.sweep_read_ranks): the whole group's
@@ -96,8 +96,8 @@ class KernelConfig:
     #: probe window, no dedup latch. Wide scans (range_heavy streams)
     #: cost O((M + G*R) log) streaming sorted work per GROUP instead of
     #: per-covered-block probes per batch, which is what lets
-    #: backend_for_profile keep range_heavy on the device. Tiered path
-    #: only; mutually exclusive with dedup_reads (they compile the same
+    #: backend_for_profile keep range_heavy on the device. Mutually
+    #: exclusive with dedup_reads (they compile the same
     #: probe differently — pick per contention profile).
     range_sweep: bool = False
     #: True raises delta-capacity pressure handling from latch-and-raise
@@ -111,7 +111,7 @@ class KernelConfig:
     #: remains only as the misconfiguration backstop (a SINGLE group's
     #: bound exceeding delta_capacity cannot be spilled around).
     delta_spill: bool = False
-    #: Tiered path: host folds delta into main after at least this many
+    #: The host folds delta into main after at least this many
     #: BATCHES have resolved since the last compaction (TpuConflictSet
     #: auto-compaction; a fused group of G batches counts G). Counting
     #: batches — not dispatches — keeps the per-batch resolve() hot
@@ -131,8 +131,8 @@ class KernelConfig:
     #: Per-shard history semantics are EXACTLY the reference's
     #: multi-resolver deployment (each shard merges its locally
     #: committed writes — phantom commits included), so decisions match
-    #: the multi-resolver CPU path bit-for-bit. Requires the tiered
-    #: path (delta_capacity > 0). 0/1 = single-device kernel.
+    #: the multi-resolver CPU path bit-for-bit. 0/1 = single-device
+    #: kernel.
     n_shards: int = 0
     #: Mesh axis name the sharded kernel partitions over (must match
     #: the Mesh handed to TpuConflictSet; parallel.mesh.AXIS default).
@@ -148,30 +148,25 @@ class KernelConfig:
             v = getattr(self, name)
             if v & (v - 1):
                 raise ValueError(f"{name} must be a power of two, got {v}")
+        if self.delta_capacity is None:
+            object.__setattr__(
+                self, "delta_capacity",
+                2 * self.max_writes * max(self.compact_interval, 1),
+            )
+        elif self.delta_capacity <= 0:
+            raise ValueError(
+                f"delta_capacity must be positive, got {self.delta_capacity}"
+            )
         if self.dedup_reads > self.max_reads:
             raise ValueError("dedup_reads cannot exceed max_reads")
-        if self.dedup_reads and not self.delta_capacity:
-            raise ValueError("dedup_reads requires the tiered path "
-                             "(delta_capacity > 0)")
-        if self.range_sweep and not self.delta_capacity:
-            raise ValueError("range_sweep requires the tiered path "
-                             "(delta_capacity > 0)")
         if self.range_sweep and self.dedup_reads:
             raise ValueError(
                 "range_sweep and dedup_reads compile the same main-tier "
                 "probe differently (sweep ranks vs dedup'd binary "
                 "searches) — configure one per contention profile"
             )
-        if self.delta_spill and not self.delta_capacity:
-            raise ValueError("delta_spill requires the tiered path "
-                             "(delta_capacity > 0)")
         if self.n_shards < 0:
             raise ValueError("n_shards must be >= 0")
-        if self.n_shards > 1 and not self.delta_capacity:
-            raise ValueError("the mesh-sharded kernel is tiered-only: "
-                             "n_shards > 1 requires delta_capacity > 0 "
-                             "(the classic sharded path is "
-                             "parallel.sharding.ShardedConflictSet)")
 
     # ---- derived shapes -------------------------------------------------
 

@@ -5,10 +5,11 @@ Plays the role of the reference's ConflictSet + ConflictBatch pair
 history plus a batch-at-a-time detect API. Differences are all
 TPU-motivated:
 
-* State lives on device as `ops.history.VersionHistory`; each batch is one
-  jitted call (`ops.conflict.resolve_batch`) with donated state buffers —
-  committed writes merge into the single-tier history inside the same
-  call (no separate compaction step).
+* State lives on device as `ops.delta.TieredState`: an immutable main
+  tier plus a small delta tier. Each batch is one jitted call of the
+  tiered kernel (`ops.delta.resolve_group_tiered` at G=1), which merges
+  the batch's committed writes into delta; the host folds delta into
+  main every `compact_interval` batches (`compact_history`).
 * Versions are rebased to int32 offsets of `base_version`; the rebase
   shifts every stored offset on device when the window drifts too far.
 * Capacity overflow is latched on device and surfaced in every
@@ -43,7 +44,7 @@ from foundationdb_tpu.models.conflict_base import (
     KernelStageMetrics,
 )
 from foundationdb_tpu.models.types import CommitTransaction, TransactionResult
-from foundationdb_tpu.ops import conflict as C
+from foundationdb_tpu.ops import delta as _D
 from foundationdb_tpu.ops import history as H
 from foundationdb_tpu.utils import packing
 from foundationdb_tpu.utils.metrics import stage
@@ -72,36 +73,12 @@ def _rebase(state: H.VersionHistory, delta):
     )
 
 
-def _resolve_scan(state, stacked):
-    """Resolve K stacked batches in ONE device program (lax.scan).
-
-    Semantically identical to K sequential resolve_batch calls — the
-    scan carry is the history state, so batch i+1 sees batch i's merged
-    writes. One dispatch instead of K: every dispatch pays a fixed host
-    round trip (scripts/profile_serialized.py), and a loaded resolver coalescing
-    its queue is exactly how the reference behaves under backpressure
-    (fdbserver/Resolver.actor.cpp resolveBatch queueing).
-    """
-
-    def body(st, batch):
-        st2, out = C.resolve_batch(st, batch)
-        return st2, out
-
-    return jax.lax.scan(body, state, stacked)
-
-
 # Module-level jitted kernels: shared across all TpuConflictSet instances
 # so N resolvers with the same KernelConfig compile once, not N times.
-# State is deliberately NOT donated to the group kernel: the mega-sort
+# State is deliberately NOT donated to the resolve kernel: the mega-sort
 # gathers against the history buffers, and gathers from donated/carried
 # buffers measure ~2x slower than from plain arguments on v5e
-# (scripts/price_primitives.py); the un-donated copy is 2 x ~12MB.
-from foundationdb_tpu.ops import delta as _D
-from foundationdb_tpu.ops import group as _G
-
-_RESOLVE = jax.jit(C.resolve_batch)
-_RESOLVE_SCAN = jax.jit(_resolve_scan, donate_argnums=0)
-_REBASE = jax.jit(_rebase, donate_argnums=0)
+# (scripts/price_primitives.py).
 
 
 def _rebase_tiered(state: _D.TieredState, delta):
@@ -117,7 +94,6 @@ _REBASE_TIERED = jax.jit(_rebase_tiered, donate_argnums=0)
 # carried buffers — the price_primitives donated-gather penalty).
 _COMPACT = jax.jit(_D.compact)
 
-_GROUP_JITS: dict = {}
 _TIERED_JITS: dict = {}
 
 
@@ -131,23 +107,6 @@ def _named_partial(fn, **static):
     bound = functools.partial(fn, **static)
     bound.__name__ = fn.__name__
     return bound
-
-
-def _resolve_group_jit(short_span_limit: int, fixpoint_unroll: int = 3,
-                       fixpoint_latch: bool = False):
-    """One compiled group kernel per (short_span_limit, fixpoint_unroll,
-    fixpoint_latch) triple (static compile-time switches — see
-    ops/group.resolve_group)."""
-    key = (short_span_limit, fixpoint_unroll, fixpoint_latch)
-    fn = _GROUP_JITS.get(key)
-    if fn is None:
-        fn = jax.jit(_named_partial(
-            _G.resolve_group, short_span_limit=short_span_limit,
-            fixpoint_unroll=fixpoint_unroll,
-            fixpoint_latch=fixpoint_latch,
-        ))
-        _GROUP_JITS[key] = fn
-    return fn
 
 
 def _resolve_tiered_jit(short_span_limit: int, fixpoint_unroll: int = 3,
@@ -188,7 +147,7 @@ def _stack_one(args: dict) -> dict:
     return out
 
 
-def _result_columns(out: C.BatchVerdict) -> tuple:
+def _result_columns(out: _D.BatchVerdict) -> tuple:
     """The result columns a reply is assembled from."""
     return (out.verdict, out.overflow, out.hist_conflict_read,
             out.intra_first_range)
@@ -201,7 +160,7 @@ class PendingBatch:
     result columns once `TpuConflictSet.read_back` has waited for them."""
 
     batch: packing.PackedBatch
-    out: C.BatchVerdict
+    out: _D.BatchVerdict
     report: list
     begin_key_of_row: Callable[[int], bytes]
     #: when the issue ended (perf_counter): the readback runs from here
@@ -212,12 +171,10 @@ class PendingBatch:
 class TpuConflictSet:
     """Batch MVCC conflict detection with device-resident history.
 
-    With `config.delta_capacity > 0` the instance runs the TIERED path
-    (ops/delta.py): state is a TieredState (main + delta tier), every
+    State is a TieredState (main + delta tier, ops/delta.py), every
     resolve dispatches the G-independent tiered kernel, and the host
     folds delta into main every `config.compact_interval` batches (a
-    fused group counts its G). The classic single-tier mega-sort path
-    (ops/group.py) serves delta_capacity == 0 unchanged.
+    fused group counts its G).
 
     With `config.n_shards > 1` the tiered path runs MESH-SHARDED
     (parallel/sharding.py, ISSUE 11): both tiers are partitioned by key
@@ -246,7 +203,6 @@ class TpuConflictSet:
 
         if jax.default_backend() != "cpu":
             _rm.flat_gather_selftest(config.history_capacity)
-        self.tiered = getattr(config, "delta_capacity", 0) > 0
         self.sharded = getattr(config, "n_shards", 0) > 1
         #: set on sharded instances (the staging thread replicates
         #: against it; None = plain single-device device_put)
@@ -255,7 +211,6 @@ class TpuConflictSet:
         #: always-on stage telemetry (see KernelStageMetrics)
         self.metrics = KernelStageMetrics()
         if self.sharded:
-            # config validation already pinned tiered-only
             from jax.sharding import NamedSharding, PartitionSpec as _P
 
             from foundationdb_tpu.parallel import mesh as _mesh_mod
@@ -285,7 +240,7 @@ class TpuConflictSet:
             self.metrics.shard_count = config.n_shards
             self._collective_probe_warm = False
         else:
-            self.state = _D.init(config) if self.tiered else H.init(config)
+            self.state = _D.init(config)
         #: the compiled row capacities a served batch is packed at
         #: (packing.row_rungs): each batch dispatches at the smallest
         #: that holds it, one compiled program per rung
@@ -299,8 +254,6 @@ class TpuConflictSet:
         #: invariant across pipelined/sharded/compact_interval paths)
         self._spill_bound_rows = 0
         self._prewarmed_exact: set = set()
-        self._resolve = _RESOLVE
-        self._rebase = _REBASE
 
     # -- ConflictBatch-equivalent API -----------------------------------
 
@@ -404,10 +357,7 @@ class TpuConflictSet:
     def _maybe_rebase(self, version: int) -> None:
         if version - self.base_version > REBASE_THRESHOLD:
             delta = version - self.base_version - (1 << 20)
-            if self.tiered:
-                self.state = _REBASE_TIERED(self.state, np.int32(delta))
-            else:
-                self.state = self._rebase(self.state, np.int32(delta))
+            self.state = _REBASE_TIERED(self.state, np.int32(delta))
             self.base_version += delta
             self.metrics.counters.add("rebases")
 
@@ -415,16 +365,11 @@ class TpuConflictSet:
         self, batch: packing.PackedBatch, report, begin_key_of_row
     ) -> PendingBatch:
         """The shared dispatch of the object and columnar paths: place
-        the packed batch on the device, dispatch it (tiered or classic)
-        and start the copy of its result columns to the host, which
-        then runs as soon as the program ends."""
+        the packed batch on the device, dispatch it and start the copy
+        of its result columns to the host, which then runs as soon as
+        the program ends."""
         self.metrics.counters.add("resolveBatches")
-        if self.tiered:
-            out = self._resolve_args_tiered(batch.device_args())
-        else:
-            args = self._transfer(batch.device_args())
-            with stage(self.metrics.kernel, STAGE_NAMES["dispatch"]):
-                self.state, out = self._resolve(self.state, args)
+        out = self.resolve_args(batch.device_args())
         for col in _result_columns(out):
             col.copy_to_host_async()
         return PendingBatch(batch, out, report, begin_key_of_row,
@@ -489,15 +434,14 @@ class TpuConflictSet:
     def _raise_overflow(self) -> None:
         self._batches_since_check = 0
         self.metrics.counters.add("overflowRaised")
-        cap = f"history_capacity={self.config.history_capacity}"
-        if self.tiered:
-            cap += f" / delta_capacity={self.config.delta_capacity}"
         raise HistoryOverflowError(
-            f"{cap} exceeded; increase it (or lower the MVCC window / "
-            "write rate, or compact the delta tier more often)"
+            f"history_capacity={self.config.history_capacity} / "
+            f"delta_capacity={self.config.delta_capacity} exceeded; "
+            "increase it (or lower the MVCC window / write rate, or "
+            "compact the delta tier more often)"
         )
 
-    def resolve_packed(self, batch: packing.PackedBatch) -> C.BatchVerdict:
+    def resolve_packed(self, batch: packing.PackedBatch) -> _D.BatchVerdict:
         """Kernel-only path for pre-packed batches (bench / perf tests).
 
         Skips the Python packer and reply assembly; the caller owns
@@ -505,45 +449,12 @@ class TpuConflictSet:
         """
         return self.resolve_args(batch.device_args())
 
-    def resolve_args(self, args) -> C.BatchVerdict:
-        """Kernel-only path for an already-materialized device_args tree
-        (host numpy or device-resident arrays alike)."""
-        if self.tiered:
-            out = self._resolve_args_tiered(args)
-            # _dispatch_tiered already advanced the overflow interval
-            return out
-        t0 = time.perf_counter()
-        self.state, out = self._resolve(self.state, args)
-        self.metrics.kernel.sample(time.perf_counter() - t0)
-        self.metrics.counters.add("resolveBatches")
-        self._maybe_check_overflow()
-        return out
-
-    def resolve_args_scan(self, stacked_args) -> C.BatchVerdict:
-        """Resolve K batches stacked on a leading axis in one dispatch.
-
-        stacked_args: a device_args tree whose leaves carry a leading
-        [K] axis. Returns a BatchVerdict with [K, ...] leaves, in batch
-        order. State chains across the K batches inside the program.
-        (Tiered instances serve this through the tiered group kernel —
-        same per-batch decisions, GroupVerdict-shaped result.)
-        """
-        if self.tiered:
-            return self._dispatch_tiered(stacked_args)
-        t0 = time.perf_counter()
-        self.state, outs = _RESOLVE_SCAN(self.state, stacked_args)
-        self.metrics.kernel.sample(time.perf_counter() - t0)
-        self.metrics.counters.add("groupDispatches")
-        self._batches_since_check += int(
-            outs.verdict.shape[0]) - 1
-        self._maybe_check_overflow()
-        return outs
-
-    def _resolve_args_tiered(self, args, check_latch: bool = True):
-        """One batch through the tiered kernel (G=1): BatchVerdict."""
+    def resolve_args(self, args) -> _D.BatchVerdict:
+        """One batch through the tiered kernel (G=1) from an
+        already-materialized device_args tree (host numpy or
+        device-resident arrays alike): its BatchVerdict."""
         return self._dispatch_tiered(
-            self._transfer(_stack_one(args)), check_latch=check_latch,
-            first_row=True,
+            self._transfer(_stack_one(args)), first_row=True
         )
 
     def _tiered_jit(self, ssl, unroll, latch, dedup, sweep=False):
@@ -636,7 +547,7 @@ class TpuConflictSet:
                     self.state, stacked_args
                 )
             if first_row:
-                outs = C.BatchVerdict(
+                outs = _D.BatchVerdict(
                     verdict=outs.verdict[0],
                     hist_conflict_read=outs.hist_conflict_read[0],
                     intra_first_range=outs.intra_first_range[0],
@@ -662,8 +573,6 @@ class TpuConflictSet:
         device program, dispatched asynchronously like any batch — the
         only main-sized pass in the tiered design, off the per-batch
         path."""
-        if not self.tiered:
-            return
         self._batches_since_compact = 0
         self._spill_bound_rows = 0
         self.metrics.counters.add("compactions")
@@ -679,45 +588,24 @@ class TpuConflictSet:
                 self.state = _COMPACT(self.state)
 
     def resolve_group_args(self, stacked_args, check_latch: bool = True):
-        """Resolve K stacked batches via the GROUP kernel (ops/group.py):
-        one mega-sort program instead of a lax.scan of per-batch
-        kernels — same decisions (tests/test_group_parity.py), one
-        dispatch, and the per-batch history merge amortized across the
-        group. Versions must ascend across the stack (sequencer
-        contract); a stale host-side check guards the bench path.
+        """Resolve K batches stacked on a leading axis (a device_args
+        tree whose leaves carry a leading [K] axis) in ONE dispatch of
+        the tiered kernel: a GroupVerdict with [K, ...] leaves, in batch
+        order, state chained across the K batches inside the program.
+        Versions must ascend across the stack (sequencer contract).
 
-        With `config.fixpoint_latch` the latched kernel may REFUSE a
-        group whose conflict chains run deeper than `fixpoint_unroll`
-        (GroupVerdict.unconverged; the returned state is the unchanged
-        input state). By default this method honors the kernel contract
-        itself: it host-checks the latch and re-dispatches the same args
-        on the exact while-loop kernel (ADVICE r4 — callers must never
+        With `config.fixpoint_latch` or `config.dedup_reads` the kernel
+        may REFUSE a group (GroupVerdict.unconverged; the returned state
+        is the unchanged input state). By default this method honors the
+        kernel contract itself: it host-checks the latch and re-dispatches
+        the same args on the exact kernel (ADVICE r4 — callers must never
         see untrustworthy verdicts). The check costs one device sync per
         group; pipelined callers that fence once per stream (bench.py)
         pass check_latch=False and fall back themselves. Call
         `prewarm_exact` up front so the fallback swaps programs in
         milliseconds instead of paying an XLA compile mid-stream.
-
-        Tiered instances serve this through the G-independent tiered
-        kernel (ops/delta.py) — same stacked-args contract, and the
-        dedup latch shares the unconverged/fallback discipline.
         """
-        if self.tiered:
-            return self._dispatch_tiered(stacked_args, check_latch=check_latch)
-        ssl = getattr(self.config, "short_span_limit", 0)
-        unroll = getattr(self.config, "fixpoint_unroll", 3)
-        latch = getattr(self.config, "fixpoint_latch", False)
-        state2, outs = _resolve_group_jit(ssl, unroll, latch)(
-            self.state, stacked_args
-        )
-        if latch and check_latch and bool(np.asarray(outs.unconverged).any()):
-            state2, outs = _resolve_group_jit(ssl, unroll, False)(
-                self.state, stacked_args
-            )
-        self.state = state2
-        self._batches_since_check += int(outs.verdict.shape[0]) - 1
-        self._maybe_check_overflow()
-        return outs
+        return self._dispatch_tiered(stacked_args, check_latch=check_latch)
 
     def resolve_group_stream(self, host_groups: list,
                              check_latch: bool = True) -> list:
@@ -838,32 +726,21 @@ class TpuConflictSet:
         return outs
 
     def prewarm_exact(self, stacked_args) -> None:
-        """Warm the exact while-loop group kernel for this args shape so
-        a fixpoint-latch trip swaps programs in milliseconds instead of
-        stalling the version chain behind an XLA compile — the reference
-        resolver never stalls its chain (fdbserver/Resolver.actor.cpp:
-        283-296). The group kernel does not donate state, so executing
-        it once and discarding the results is side-effect-free; the
-        compile lands in both the jit call cache and the persistent
-        compile cache. No-op when neither the fixpoint latch nor the
-        dedup latch can trip."""
-        ssl = getattr(self.config, "short_span_limit", 0)
-        unroll = getattr(self.config, "fixpoint_unroll", 3)
-        if self.tiered:
-            if not (getattr(self.config, "fixpoint_latch", False)
-                    or getattr(self.config, "dedup_reads", 0)):
-                return
-            _, outs = self._tiered_jit(
-                ssl, unroll, False, 0,
-                getattr(self.config, "range_sweep", False),
-            )(self.state, stacked_args)
-            jax.block_until_ready(outs.verdict)
+        """Warm the exact kernel for this args shape so a latch trip
+        swaps programs in milliseconds instead of stalling the version
+        chain behind an XLA compile — the reference resolver never
+        stalls its chain (fdbserver/Resolver.actor.cpp:283-296). The
+        kernel does not donate state, so executing it once and
+        discarding the results is side-effect-free; the compile lands in
+        both the jit call cache and the persistent compile cache. No-op
+        when neither the fixpoint latch nor the dedup latch can trip."""
+        cfg = self.config
+        if not (cfg.fixpoint_latch or cfg.dedup_reads):
             return
-        if not getattr(self.config, "fixpoint_latch", False):
-            return
-        _, outs = _resolve_group_jit(ssl, unroll, False)(
-            self.state, stacked_args
-        )
+        _, outs = self._tiered_jit(
+            cfg.short_span_limit, cfg.fixpoint_unroll, False, 0,
+            cfg.range_sweep,
+        )(self.state, stacked_args)
         jax.block_until_ready(outs.verdict)
 
     def _sample_collective(self) -> None:
@@ -892,11 +769,8 @@ class TpuConflictSet:
         """The device holding the history state (= the dispatch
         device); None when it can't be read (host numpy state, exotic
         shardings) — device_memory_stats then falls back to device 0."""
-        leaf = (
-            self.state.main.overflow if self.tiered else self.state.overflow
-        )
         try:
-            devices = leaf.devices()
+            devices = self.state.main.overflow.devices()
             return next(iter(devices)) if len(devices) == 1 else None
         except Exception:
             return None
@@ -927,13 +801,10 @@ class TpuConflictSet:
             return _perf.cost_analysis_of(
                 fn, self.state, stacked_args, self._part_lo, self._part_hi
             )
-        if self.tiered:
-            fn = _resolve_tiered_jit(
-                ssl, unroll, latch, getattr(cfg, "dedup_reads", 0),
-                getattr(cfg, "range_sweep", False),
-            )
-        else:
-            fn = _resolve_group_jit(ssl, unroll, latch)
+        fn = _resolve_tiered_jit(
+            ssl, unroll, latch, getattr(cfg, "dedup_reads", 0),
+            getattr(cfg, "range_sweep", False),
+        )
         return _perf.cost_analysis_of(fn, self.state, stacked_args)
 
     def _re_anchor_spill_bound(self, d_live: float) -> None:
@@ -967,9 +838,9 @@ class TpuConflictSet:
                 self.check_overflow()
 
     def check_overflow(self) -> None:
-        """Device sync: raise if a merge ever exceeded history_capacity
-        (either tier's, on the tiered path — a latched delta overflow
-        survives compaction by folding into main.overflow)."""
+        """Device sync: raise if a merge ever exceeded either tier's
+        capacity (a latched delta overflow survives compaction by
+        folding into main.overflow)."""
         self._batches_since_check = 0
         if self.sharded:
             # any-shard overflow; occupancy samples take the WORST
@@ -983,7 +854,7 @@ class TpuConflictSet:
             self.metrics.delta_occupancy.sample(d_live)
             self._re_anchor_spill_bound(d_live)
             self._sample_collective()
-        elif self.tiered:
+        else:
             tripped = bool(np.asarray(self.state.main.overflow)) or bool(
                 np.asarray(self.state.delta.overflow)
             )
@@ -994,8 +865,6 @@ class TpuConflictSet:
             self.metrics.main_occupancy.sample(float(np.asarray(m_cnt)))
             self.metrics.delta_occupancy.sample(d_live)
             self._re_anchor_spill_bound(d_live)
-        else:
-            tripped = bool(np.asarray(self.state.overflow))
         # device-memory gauges ride the same sync (allocator stats are
         # a host call, no fence; CPU backends report nothing and skip),
         # sampled on the device holding the history state
@@ -1060,10 +929,10 @@ def stage_ledger(config: KernelConfig, batches, *, fuse: int,
       the whole stream (the phase-3 number of record).
     * fence: a fenced pass of the same program mix minus `kernel_s` —
       the per-group sync penalty and nothing else.
-    * merge rows: what one group's history machinery touches; on the
-      tiered kernel the delta tier's true end-of-stream occupancy comes
-      from a separate compaction-disabled pass read via
-      `KernelStageMetrics` occupancy samples.
+    * merge rows: what one group's history machinery touches; the
+      delta tier's true end-of-stream occupancy comes from a separate
+      compaction-disabled pass read via `KernelStageMetrics` occupancy
+      samples.
     """
     import dataclasses as _dc
 
@@ -1072,7 +941,6 @@ def stage_ledger(config: KernelConfig, batches, *, fuse: int,
     n_batches = len(batches)
     groups = [batches[g: g + fuse] for g in range(0, n_batches, fuse)]
     n_groups = len(groups)
-    tiered = getattr(config, "delta_capacity", 0) > 0
 
     # pack + fenced transfer, through the instrumented stages
     cs = TpuConflictSet(config)
@@ -1110,36 +978,30 @@ def stage_ledger(config: KernelConfig, batches, *, fuse: int,
             max(0.0, fenced_s - kernel_s) / n_groups * 1e3, 1
         ),
         "pipelined_ms_per_group": round(pipelined_s / n_groups * 1e3, 1),
-        "merge_rows_classic_per_group": (
-            config.history_capacity + 2 * fuse * nrw
-        ),
     }
-    if tiered:
-        # separate UNTIMED pass with compaction disabled: the delta
-        # tier's true end-of-stream occupancy (what a batch's skeleton
-        # actually co-sorts when compaction is deferred). Delta sized
-        # for the window worst case — a capacity sized for the
-        # compaction cadence would overflow with compaction off.
-        occ_cap = occupancy_delta_capacity or config.history_capacity
-        # delta_spill off too: a pressure fold mid-pass would reset the
-        # very occupancy this pass exists to measure
-        cs_occ = TpuConflictSet(
-            _dc.replace(config, compact_interval=0, delta_capacity=occ_cap,
-                        delta_spill=False)
-        )
-        for dg in staged:
-            cs_occ.resolve_group_args(dg, check_latch=False)
-        m_cnt, d_cnt = _D.boundary_counts(cs_occ.state)
-        d_live = int(np.asarray(d_cnt))
-        m_live = int(np.asarray(m_cnt))
-        cs_occ.metrics.delta_occupancy.sample(float(d_live))
-        cs_occ.metrics.main_occupancy.sample(float(m_live))
-        ledger["merge_rows_tiered_per_batch_cap"] = (
-            config.delta_capacity + 2 * nrw
-        )
-        ledger["merge_rows_tiered_per_batch_live"] = d_live + 2 * nrw
-        ledger["delta_live_boundaries"] = d_live
-        ledger["main_live_boundaries"] = m_live
+    # separate UNTIMED pass with compaction disabled: the delta tier's
+    # true end-of-stream occupancy (what a batch's skeleton actually
+    # co-sorts when compaction is deferred). Delta sized for the window
+    # worst case — a capacity sized for the compaction cadence would
+    # overflow with compaction off.
+    occ_cap = occupancy_delta_capacity or config.history_capacity
+    # delta_spill off too: a pressure fold mid-pass would reset the
+    # very occupancy this pass exists to measure
+    cs_occ = TpuConflictSet(
+        _dc.replace(config, compact_interval=0, delta_capacity=occ_cap,
+                    delta_spill=False)
+    )
+    for dg in staged:
+        cs_occ.resolve_group_args(dg, check_latch=False)
+    m_cnt, d_cnt = _D.boundary_counts(cs_occ.state)
+    d_live = int(np.asarray(d_cnt))
+    m_live = int(np.asarray(m_cnt))
+    cs_occ.metrics.delta_occupancy.sample(float(d_live))
+    cs_occ.metrics.main_occupancy.sample(float(m_live))
+    ledger["merge_rows_tiered_per_batch_cap"] = config.delta_capacity + 2 * nrw
+    ledger["merge_rows_tiered_per_batch_live"] = d_live + 2 * nrw
+    ledger["delta_live_boundaries"] = d_live
+    ledger["main_live_boundaries"] = m_live
     return ledger
 
 
@@ -1321,14 +1183,12 @@ def backend_for_profile(profile: str, config=None) -> str:
     if (
         profile == "hot_key"
         and config is not None
-        and getattr(config, "delta_capacity", 0) > 0
         and getattr(config, "dedup_reads", 0) > 0
     ):
         return "tpu"
     if (
         profile == "range_heavy"
         and config is not None
-        and getattr(config, "delta_capacity", 0) > 0
         and getattr(config, "range_sweep", False)
     ):
         return "tpu"
@@ -1349,7 +1209,6 @@ def fallback_free(config) -> bool:
     for the profile it routed)."""
     return bool(
         config is not None
-        and getattr(config, "delta_capacity", 0) > 0
         and getattr(config, "delta_spill", False)
         and (
             getattr(config, "dedup_reads", 0) > 0

@@ -66,10 +66,10 @@ O((M + G*R) log) streaming sorted work per group — the lax.sort
 per batch, and there is NO dedup latch on this path (nothing is probed
 per distinct range), so a range-heavy stream never escapes to the host.
 
-Decisions are bit-identical to the classic sequential pipeline
-(tests/test_delta_parity.py drives tiered vs per-batch resolve_batch vs
-the Python oracle on adversarial shapes; the sweep path is pinned
-against the probe path and the oracle on range-heavy streams).
+Decisions are bit-identical to the reference's sequential pipeline
+(tests/test_delta_parity.py drives the tiered kernel against the Python
+oracle on adversarial shapes; the sweep path is pinned against the
+probe path and the oracle on range-heavy streams).
 """
 
 from __future__ import annotations
@@ -99,10 +99,25 @@ class TieredState(NamedTuple):
     delta: H.VersionHistory  # small tier: boundaries since last compaction
 
 
+class BatchVerdict(NamedTuple):
+    """One batch's row of a GroupVerdict (ConflictBatch's per-batch
+    result, fdbserver/include/fdbserver/ConflictSet.h:41-46)."""
+
+    verdict: jnp.ndarray          # [B] int32 (CONFLICT/TOO_OLD/COMMITTED)
+    hist_conflict_read: jnp.ndarray  # [NR] bool — per read range, history hit
+    intra_first_range: jnp.ndarray   # [B] int32 — first intra-batch
+    #                                  conflicting read-range index, else -1
+    committed_count: jnp.ndarray  # [] int32
+    conflict_count: jnp.ndarray   # [] int32
+    too_old_count: jnp.ndarray    # [] int32
+    overflow: jnp.ndarray         # [] bool — history capacity exceeded by
+    #   this batch's merge (or latched earlier). Surfaced in the verdict so
+    #   the sync the host already pays to read verdicts also proves the
+    #   history they were computed against didn't truncate (ADVICE r1).
+
+
 def init(config: KernelConfig) -> TieredState:
     d = config.delta_capacity
-    if d <= 0:
-        raise ValueError("tiered state requires config.delta_capacity > 0")
     delta = H.VersionHistory(
         main_keys=K.sentinel_like(d, config.key_words),
         main_ver=jnp.full((d,), VERSION_NEG, jnp.int32),

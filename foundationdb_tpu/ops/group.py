@@ -41,15 +41,14 @@ verdicts, the batch's committed writes fold into `seg_ver` via a
 parity-delta cumsum. Chains therefore stay within one batch (2-3
 fixpoint iterations); cross-batch ordering is exact by construction.
 
-The alternating fixpoint recurrence (see ops/conflict.py's original
-derivation) is unchanged, per batch: committed[t] = ok[t] and no
+The alternating fixpoint recurrence, per batch: committed[t] = ok[t] and no
 committed earlier writer in the same batch intersects t's reads. F is
 antitone and the dependency order is a DAG by txn index, so iteration
 from the all-ok start converges to the unique sequential answer in
 (max conflict-chain length + 1) rounds.
 
 Decisions are bit-identical to resolving the G batches sequentially
-(tests/test_group_parity.py drives both paths plus the Python oracle).
+(tests/test_group_parity.py drives groups against the Python oracle).
 """
 
 from __future__ import annotations
@@ -479,8 +478,7 @@ def resolve_group(state: H.VersionHistory, g: dict, *,
             # into seg_ver, and with a single batch seg_ver is still the
             # all-NEG initial carry — the query is statically dead, so
             # skip its table build entirely (the biggest in-kernel cost
-            # of the per-batch tiered path, and a free win for the
-            # classic resolve_batch G=1 specialization).
+            # of the per-batch tiered path).
             cross_g = jnp.zeros((nr,), bool)
         elif short_span_limit:
             gmax = direct_range_op(

@@ -44,7 +44,7 @@ class VersionHistory(NamedTuple):
     oldest: jnp.ndarray      # [] int32 current oldestVersion offset
     overflow: jnp.ndarray    # [] bool — merge exceeded main capacity
     # NOTE deliberately NOT carried: the [L, M] range-max table. It is
-    # derived from main_ver at the start of each batch (resolve_batch) —
+    # derived from main_ver at the start of each group —
     # carrying 66MB of derived data made lax.scan fusion copy it per
     # iteration (measured: fused dispatch SLOWER than sequential) and
     # tripled donation traffic.

@@ -12,19 +12,19 @@ another resolver aborts it globally — there is no cross-resolver
 consensus inside a batch.
 
 That independence is exactly what makes the TPU mapping clean: resolver
-shards become a `Mesh` axis. Each device holds one shard's
-`VersionHistory`, the packed batch is replicated, every device clips the
+shards become a `Mesh` axis. Each device holds one shard's history
+tiers, the packed batch is replicated, every device clips the
 batch's ranges to its own key partition (the device-side equivalent of
 ResolutionRequestBuilder's splitting), runs the identical conflict
 kernel, and the per-shard verdicts merge with one `lax.pmin` over the ICI
 ring — the reference's min() combine as a collective. One jitted
-`shard_map` call per batch; no host round-trip between shards.
+`shard_map` call per group; no host round-trip between shards.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +32,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from foundationdb_tpu.config import KernelConfig
-from foundationdb_tpu.ops import conflict as C
 from foundationdb_tpu.ops import history as H
 from foundationdb_tpu.ops import keys as K
 from foundationdb_tpu.ops.rangemax import INT32_POS
@@ -47,13 +46,6 @@ def _shard_map(f, *, mesh, in_specs, out_specs):
     pmin/psum combines."""
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-
-class ShardedVerdict(NamedTuple):
-    verdict: jnp.ndarray            # [B] int32 — min-combined across shards
-    hist_conflict_read: jnp.ndarray  # [NR] bool — OR across shards
-    intra_first_range: jnp.ndarray   # [B] int32 — min non-negative, else -1
-    overflow: jnp.ndarray            # [] bool — any shard's history overflowed
 
 
 def lex_max(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -96,68 +88,6 @@ def clip_batch(batch: dict, lo: jnp.ndarray, hi: jnp.ndarray) -> dict:
         has_reads=has_reads,
     )
     return out
-
-
-def _shard_resolve_group(state: H.VersionHistory, g: dict, lo, hi):
-    """Per-device body for a G-batch GROUP resolve under shard_map.
-
-    The round-3 gap (VERDICT r3 weak #3): the sharded path dispatched
-    the G=1 kernel per batch, paying per-batch dispatch the single-chip
-    path had already amortized away. Here the whole stacked group ships
-    to the mesh once: each device clips every batch in the stack to its
-    partition (vmapped ResolutionRequestBuilder), runs ONE group-kernel
-    program (ops/group.py — mega-sort + seg_ver scan), and the [G, ...]
-    verdicts min-combine across shards with a single pmin
-    (determineCommittedTransactions' min(), once per group instead of
-    once per batch)."""
-    state = jax.tree.map(lambda x: x[0], state)
-    lo = lo[0]
-    hi = hi[0]
-    from foundationdb_tpu.ops import group as G
-
-    local = jax.vmap(lambda b: clip_batch(b, lo, hi))(g)
-    state, out = G.resolve_group(state, local)
-
-    verdict = jax.lax.pmin(out.verdict, AXIS)                 # [G, B]
-    hist_read = (
-        jax.lax.pmax(out.hist_conflict_read.astype(jnp.int32), AXIS) > 0
-    )
-    first = jnp.where(
-        out.intra_first_range < 0, INT32_POS, out.intra_first_range
-    )
-    first = jax.lax.pmin(first, AXIS)
-    first = jnp.where(first == INT32_POS, -1, first)
-    overflow = jax.lax.pmax(out.overflow.astype(jnp.int32), AXIS) > 0
-
-    state = jax.tree.map(lambda x: x[None], state)
-    return state, GroupShardedVerdict(verdict, hist_read, first, overflow)
-
-
-class GroupShardedVerdict(NamedTuple):
-    verdict: jnp.ndarray             # [G, B] min-combined across shards
-    hist_conflict_read: jnp.ndarray  # [G, NR] OR across shards
-    intra_first_range: jnp.ndarray   # [G, B]
-    overflow: jnp.ndarray            # [G] bool
-
-
-def _shard_resolve(state: H.VersionHistory, batch: dict, lo, hi):
-    """Body run per device under shard_map (leading shard axis squeezed)."""
-    state = jax.tree.map(lambda x: x[0], state)
-    lo = lo[0]
-    hi = hi[0]
-    local = clip_batch(batch, lo, hi)
-    state, out = C.resolve_batch(state, local)
-
-    # min() verdict combine (CommitProxyServer.actor.cpp:1559-1565) on ICI.
-    verdict = jax.lax.pmin(out.verdict, AXIS)
-    hist_read = jax.lax.pmax(out.hist_conflict_read.astype(jnp.int32), AXIS) > 0
-    first = jnp.where(out.intra_first_range < 0, INT32_POS, out.intra_first_range)
-    first = jax.lax.pmin(first, AXIS)
-    first = jnp.where(first == INT32_POS, -1, first)
-    overflow = jax.lax.pmax(out.overflow.astype(jnp.int32), AXIS) > 0
-
-    state = jax.tree.map(lambda x: x[None], state)
-    return state, ShardedVerdict(verdict, hist_read, first, overflow)
 
 
 def make_partition(
@@ -237,8 +167,8 @@ def partition_loads(batch: packing.PackedBatch,
 # the SAME compiled shard_map program. One dispatch per group; no host
 # round-trip between shards.
 #
-# Semantics are the reference's multi-resolver deployment, exactly like
-# ShardedConflictSet above: each shard merges its LOCALLY committed
+# Semantics are the reference's multi-resolver deployment: each shard
+# merges its LOCALLY committed
 # writes into its delta tier (phantom commits included), verdicts
 # min-combine (determineCommittedTransactions). Decisions are therefore
 # bit-identical to N independent tiered resolvers over the same
@@ -497,115 +427,3 @@ def collective_probe_jit(mesh: Mesh, n: int, axis: str = AXIS):
         )
         _COLLECTIVE_PROBE_JITS[key] = fn
     return fn
-
-
-class ShardedConflictSet:
-    """TpuConflictSet over an n-shard resolver mesh axis.
-
-    Equivalent of running n reference resolvers: same per-shard history
-    semantics, same min() verdict combine, but one SPMD program — the
-    batch ships to the mesh once and verdicts come back combined.
-    """
-
-    def __init__(
-        self,
-        config: KernelConfig,
-        mesh: Mesh,
-        boundaries: Sequence[bytes],
-        base_version: int = 0,
-    ):
-        if AXIS not in mesh.axis_names:
-            raise ValueError(f"mesh must have a {AXIS!r} axis")
-        n_shards = mesh.shape[AXIS]
-        if len(boundaries) != n_shards - 1:
-            raise ValueError(
-                f"{n_shards} shards need {n_shards - 1} interior boundaries"
-            )
-        self.config = config
-        self.mesh = mesh
-        self.n_shards = n_shards
-        self.base_version = base_version
-
-        lo, hi = make_partition(boundaries, config)
-        shard = NamedSharding(mesh, P(AXIS))
-        self.part_lo = jax.device_put(lo, shard)
-        self.part_hi = jax.device_put(hi, shard)
-
-        # Replicate one empty history per shard (stacked leading axis).
-        single = H.init(config)
-        stacked = jax.tree.map(
-            lambda x: np.broadcast_to(np.asarray(x), (n_shards,) + np.asarray(x).shape).copy(),
-            single,
-        )
-        self.state = jax.tree.map(lambda x: jax.device_put(x, shard), stacked)
-
-        spec_state = jax.tree.map(lambda _: P(AXIS), single)
-        self._resolve = jax.jit(
-            _shard_map(
-                _shard_resolve,
-                mesh=mesh,
-                in_specs=(spec_state, P(), P(AXIS), P(AXIS)),
-                out_specs=(spec_state, P()),
-            ),
-            donate_argnums=0,
-        )
-        self._resolve_group = jax.jit(
-            _shard_map(
-                _shard_resolve_group,
-                mesh=mesh,
-                in_specs=(spec_state, P(), P(AXIS), P(AXIS)),
-                out_specs=(spec_state, P()),
-            ),
-            donate_argnums=0,
-        )
-
-    def resolve(self, transactions, version: int) -> ShardedVerdict:
-        """Resolve one batch across all shards; returns combined verdicts.
-
-        Like TpuConflictSet.resolve, refuses to externalize verdicts
-        computed against any truncated shard history — the overflow latch
-        rides the same ShardedVerdict the caller is about to sync anyway.
-        """
-        batch = packing.pack_batch(
-            transactions, version, self.base_version, self.config
-        )
-        self.state, out = self._resolve(
-            self.state, batch.device_args(), self.part_lo, self.part_hi
-        )
-        if bool(np.asarray(out.overflow)):
-            self._raise_overflow()
-        return out
-
-    def resolve_group_args(self, stacked_args) -> GroupShardedVerdict:
-        """Resolve a G-batch stacked device_args tree across all shards
-        in ONE SPMD program (the group kernel under shard_map). Versions
-        must ascend across the stack — the sequencer contract the
-        single-chip group path already enforces."""
-        self.state, out = self._resolve_group(
-            self.state, stacked_args, self.part_lo, self.part_hi
-        )
-        return out
-
-    def resolve_group(self, batches, versions) -> GroupShardedVerdict:
-        """Pack + resolve a list of transaction batches as one group."""
-        packed = [
-            packing.pack_batch(txns, v, self.base_version, self.config)
-            for txns, v in zip(batches, versions)
-        ]
-        out = self.resolve_group_args(packing.stack_device_args(packed))
-        if bool(np.any(np.asarray(out.overflow))):
-            self._raise_overflow()
-        return out
-
-    def _raise_overflow(self) -> None:
-        from foundationdb_tpu.models.conflict_base import HistoryOverflowError
-
-        raise HistoryOverflowError(
-            f"a shard's history_capacity={self.config.history_capacity} "
-            "overflowed; increase it (or lower the MVCC window / write rate)"
-        )
-
-    def check_overflow(self) -> None:
-        """Device sync: raise if any shard's history merge overflowed."""
-        if bool(np.any(np.asarray(self.state.overflow))):
-            self._raise_overflow()

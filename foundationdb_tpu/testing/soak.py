@@ -336,15 +336,13 @@ def run_seed(seed: int, spec=None, collect_probes: bool = False,
         )
         if seed % 4 == 0 and _sharded_mesh_available(2):
             kernel_config = kernel_config.scaled(n_shards=2)
-    elif plan.resolver_backend == "tpu-force" and seed % 2 == 0:
-        # alternate the r6 TIERED kernel (ops/delta.py: delta tier +
-        # device-side read dedup + per-group compaction) through the
-        # fault ensemble on even tpu-force seeds — decisions are
-        # parity-identical to the classic kernel, so every model check
-        # applies unchanged while the new path (incl. the dedup-latch
-        # exact-kernel fallback) runs INSIDE the fault mix. Odd seeds
-        # keep the classic kernel covered. Deterministic per seed; the
-        # spec draw order is untouched.
+    elif plan.resolver_backend == "tpu-force":
+        # the TIERED kernel (ops/delta.py: delta tier + device-side read
+        # dedup + per-group compaction) through the fault ensemble on
+        # every tpu-force seed — decisions equal the reference's, so
+        # every model check applies unchanged while the dedup-latch
+        # exact-kernel fallback runs INSIDE the fault mix. Deterministic
+        # per seed; the spec draw order is untouched.
         kernel_config = kernel_config.scaled(
             delta_capacity=4 * kernel_config.max_writes,
             dedup_reads=kernel_config.max_reads // 4,
@@ -354,7 +352,7 @@ def run_seed(seed: int, spec=None, collect_probes: bool = False,
             compact_interval=2,
         )
         if seed % 4 == 0 and _sharded_mesh_available(2):
-            # every other tiered seed runs the MESH-SHARDED tiered
+            # every fourth seed runs the MESH-SHARDED tiered
             # kernel (ISSUE 11: parallel/sharding.py — keyspace
             # partition over a 2-virtual-device mesh, on-device
             # pmin/psum combine) inside the fault ensemble. Per-shard

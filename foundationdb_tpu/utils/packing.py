@@ -714,7 +714,7 @@ def columnar_to_transactions(cols: ColumnarBatch) -> list:
 
 def stack_device_args(batches) -> dict:
     """Stack PackedBatch device_args along a new leading axis — the
-    input contract of TpuConflictSet.resolve_args_scan. Single place so
+    input contract of TpuConflictSet.resolve_group_args. Single place so
     a new device_args key can never be silently dropped by callers."""
     import numpy as _np
 

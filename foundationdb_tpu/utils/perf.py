@@ -603,7 +603,9 @@ def bench_row_to_record(row: dict, *, imported_from: str = None,
         "staging": row.get("staging", "device"),
     }
     knobs = {
-        "kernel": row.get("kernel"),
+        # every row since r6 ran the tiered kernel, the only one left:
+        # rows that no longer name it key with the rows that did
+        "kernel": row.get("kernel", "tiered"),
         "fused_dispatch": row.get("fused_dispatch"),
         "delta_capacity": row.get("delta_capacity"),
         "dedup_reads": row.get("dedup_reads"),
@@ -674,7 +676,7 @@ def pipeline_row_to_records(row: dict, *, imported_from: str = None,
         knobs = {
             "batch": row.get("batch"),
             "kernel_txns": row.get("kernel_txns"),
-            "kernel": row.get("kernel"),
+            "kernel": row.get("kernel", "tiered"),
         }
         if row.get("resolve_path"):
             # frame A/B knob: keys columnar and object rows apart in

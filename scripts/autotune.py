@@ -59,7 +59,6 @@ BENCH_ENV_KNOBS = {
     "fuse": "BENCH_FUSE",
     "delta_capacity": "BENCH_DELTA_CAP",
     "compact_interval": "BENCH_COMPACT_INTERVAL",
-    "kernel": "BENCH_KERNEL",
     "txns": "BENCH_TXNS",
     "batches": "BENCH_BATCHES",
 }

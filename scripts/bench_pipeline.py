@@ -50,7 +50,7 @@ def _pctl(samples, q):
     return s[min(len(s) - 1, int(len(s) * q))]
 
 
-def kernel_config(kernel_txns: int, tiered: bool):
+def kernel_config(kernel_txns: int):
     from foundationdb_tpu.config import KernelConfig
 
     kt = 1 << (kernel_txns - 1).bit_length()
@@ -61,7 +61,7 @@ def kernel_config(kernel_txns: int, tiered: bool):
         max_writes=4 * kt,
         history_capacity=1 << max(17, (12 * kt).bit_length()),
         window_versions=5_000_000,
-        delta_capacity=(1 << max(16, (4 * kt).bit_length())) if tiered else 0,
+        delta_capacity=1 << max(16, (4 * kt).bit_length()),
     )
 
 
@@ -71,7 +71,7 @@ def run_cluster(backend: str, args) -> dict:
     from foundationdb_tpu.cluster.database import ClusterConfig, open_cluster
     from foundationdb_tpu.runtime.flow import all_of
 
-    kcfg = kernel_config(args.kernel_txns, tiered=not args.classic_kernel)
+    kcfg = kernel_config(args.kernel_txns)
     sched, cluster, db = open_cluster(
         ClusterConfig(
             n_commit_proxies=2, n_resolvers=2, n_storage=2,
@@ -162,7 +162,7 @@ async def _run_wire(backend: str, args) -> dict:
         _spans.set_exporter(_spans.SpanExporter(trace_log=sink))
 
     if backend in ("cpu", "tpu", "tpu-force"):
-        kcfg = kernel_config(args.kernel_txns, tiered=not args.classic_kernel)
+        kcfg = kernel_config(args.kernel_txns)
         os.environ["RESOLVER_KERNEL"] = (
             "KernelConfig("
             f"max_key_bytes={kcfg.max_key_bytes}, max_txns={kcfg.max_txns}, "
@@ -473,7 +473,6 @@ def emit_row(args, results: dict) -> dict:
         "records": args.records,
         "batch": args.batch,
         "kernel_txns": args.kernel_txns,
-        "kernel": "classic" if args.classic_kernel else "tiered",
         "backends": results,
     }
     if getattr(args, "knob_overrides", None):
@@ -612,8 +611,6 @@ def main():
     ap.add_argument("--backends", default=None,
                     help="comma list; default cpu,tpu-force (cluster) / "
                          "native,tpu-force (wire)")
-    ap.add_argument("--classic-kernel", action="store_true",
-                    help="tpu backends use the classic (non-tiered) kernel")
     ap.add_argument("--resolve-path", choices=("columnar", "object"),
                     default=None,
                     help="wire mode: resolve-hop frame A/B — columnar "

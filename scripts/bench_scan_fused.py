@@ -58,11 +58,11 @@ groups = [
 ]
 jax.block_until_ready(groups)
 warm = TpuConflictSet(config)
-warm.resolve_args_scan(groups[0])
+warm.resolve_group_args(groups[0])
 jax.block_until_ready(warm.state)
 cs2 = TpuConflictSet(config)
 t0 = time.perf_counter()
-fouts = [cs2.resolve_args_scan(g) for g in groups]
+fouts = [cs2.resolve_group_args(g) for g in groups]
 jax.block_until_ready(fouts[-1].verdict)
 fus = time.perf_counter() - t0
 print(f"fused x4:   {fus*1e3:.0f}ms total, {fus/8*1e3:.0f}ms/batch, "

@@ -38,8 +38,8 @@ JAX_PLATFORMS=cpu python scripts/wire_fuzz.py --smoke
 t1=$(date +%s.%N)
 awk -v a="$t0" -v b="$t1" 'BEGIN {printf "wire-fuzz smoke wall time: %.1fs\n", b - a}'
 
-echo "== kernel-parity smoke (tiny shapes: classic + tiered + dedup    =="
-echo "== fallback vs the Python oracle — seconds, compile-bound)       =="
+echo "== kernel-parity smoke (tiny shapes: tiered + dedup fallback   =="
+echo "== vs the Python oracle — seconds, compile-bound)                =="
 t0=$(date +%s.%N)
 perf_row=$(mktemp /tmp/perfcheck_row.XXXXXX.jsonl)
 JAX_PLATFORMS=cpu python scripts/kernel_smoke.py --perf-out "$perf_row"

@@ -1,11 +1,10 @@
 #!/usr/bin/env python
 """Tiny-shape kernel-parity smoke for the fast CI lane (seconds).
 
-Drives BOTH device kernel paths — classic single-tier (ops/group.py via
-resolve_batch) and r6 tiered (ops/delta.py with dedup + per-group
-compaction) — against the Python oracle (CpuConflictSet) on a seeded
-random stream, plus one dedup-latch trip with the exact-kernel
-fallback. Shapes are tiny so the whole run is XLA-compile-bound at a
+Drives the device kernel (ops/delta.py, with dedup + per-group
+compaction) against the Python oracle (testing.oracle.ConflictOracle,
+served through CpuConflictSet) on a seeded random stream, plus one
+dedup-latch trip with the exact-kernel fallback. Shapes are tiny so the whole run is XLA-compile-bound at a
 few seconds on JAX_PLATFORMS=cpu: kernel refactors cannot silently
 change commit/abort decisions in the fast lane (scripts/check.sh);
 the deep adversarial coverage lives in the kernel parity lane
@@ -43,7 +42,7 @@ def main() -> int:
         max_key_bytes=8, max_txns=8, max_reads=16, max_writes=16,
         history_capacity=128, window_versions=500,
     )
-    classic = KernelConfig(**base_cfg)
+    oracle_cfg = KernelConfig(**base_cfg)
     tiered = KernelConfig(
         **base_cfg, delta_capacity=64, dedup_reads=8, compact_interval=1
     )
@@ -79,9 +78,8 @@ def main() -> int:
         v = base + (i + 1) * step
         stream.append(([txn(base - 150, v) for _ in range(6)], v))
 
-    oracle = CpuConflictSet(classic)
+    oracle = CpuConflictSet(oracle_cfg)
     sets = {
-        "classic": TpuConflictSet(classic),
         "tiered+dedup": TpuConflictSet(tiered),
         "tiered(dedup-latch-fallback)": TpuConflictSet(tripwire),
     }
@@ -132,7 +130,7 @@ def main() -> int:
     for i in range(6):
         v = base + (i + 1) * step
         rstream.append(([scan_txn(base - 150, v) for _ in range(6)], v))
-    r_oracle = CpuConflictSet(classic)
+    r_oracle = CpuConflictSet(oracle_cfg)
     r_want = [r_oracle.resolve(txns, v) for txns, v in rstream]
     range_sets = {
         "sweep+spill": TpuConflictSet(sweep_cfg),
@@ -209,12 +207,8 @@ def _emit_perf_row(path: str, sets: dict, want, tiered_cfg, *,
             tiered_cfg.delta_capacity + 2 * nrw, "rows", "lower",
             tier="structural",
         ),
-        "merge_rows_classic_cap": perf.metric(
-            tiered_cfg.history_capacity + 2 * nrw, "rows", "lower",
-            tier="structural",
-        ),
     }
-    tags = {"classic": "classic", "tiered+dedup": "tiered_dedup",
+    tags = {"tiered+dedup": "tiered_dedup",
             "tiered(dedup-latch-fallback)": "dedup_latch"}
     for name, cs in sets.items():
         tag = tags.get(name, name.split("(")[0].replace("+", "_"))

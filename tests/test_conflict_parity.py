@@ -133,33 +133,35 @@ def test_intra_batch_chain():
 
 
 def test_scan_fused_path_matches_sequential(rng):
-    """resolve_args_scan (K batches, one dispatch) must produce exactly
-    the sequential per-batch decisions — the state chains inside the
-    scan."""
-    import numpy as np
-
+    """resolve_group_args (K batches, one dispatch) must produce exactly
+    the oracle's per-batch decisions — the state chains inside the
+    scan, across groups."""
     from foundationdb_tpu.config import TEST_CONFIG
-    from foundationdb_tpu.models.conflict_set import TpuConflictSet
-    from foundationdb_tpu.testing.benchgen import skiplist_style_batch
+    from foundationdb_tpu.utils.packing import pack_batch, stack_device_args
 
     config = TEST_CONFIG
-    batches = [
-        skiplist_style_batch(
-            rng, config, 48, version=(i + 1) * 100, keyspace=300,
-            key_bytes=4, snapshot_lag=150,
-        )
-        for i in range(6)
+    w = workloads.WorkloadConfig(n_txns=48, keyspace=300)
+    stream = []
+    for i in range(6):
+        version = (i + 1) * 100
+        stream.append((
+            workloads.make_batch(rng, w, version, config.window_versions),
+            version,
+        ))
+    oracle = ConflictOracle(window=config.window_versions)
+    want = [
+        oracle.resolve([
+            OracleTxn(t.read_conflict_ranges, t.write_conflict_ranges,
+                      t.read_snapshot, t.report_conflicting_keys)
+            for t in txns
+        ], version).verdicts
+        for txns, version in stream
     ]
-    seq = TpuConflictSet(config)
-    seq_verdicts = [
-        np.asarray(seq.resolve_packed(b).verdict) for b in batches
-    ]
-    from foundationdb_tpu.utils.packing import stack_device_args
-
     fused = TpuConflictSet(config)
-    for gi, g in enumerate((batches[:3], batches[3:])):
-        outs = fused.resolve_args_scan(stack_device_args(g))
-        base = gi * 3
-        for j in range(3):
-            got = np.asarray(outs.verdict[j])
-            assert (got == seq_verdicts[base + j]).all(), (base + j)
+    for gi, g in enumerate((stream[:3], stream[3:])):
+        outs = fused.resolve_group_args(stack_device_args(
+            [pack_batch(txns, v, 0, config) for txns, v in g]
+        ))
+        for j, (txns, _) in enumerate(g):
+            got = np.asarray(outs.verdict[j])[: len(txns)].tolist()
+            assert got == want[gi * 3 + j], gi * 3 + j

@@ -1,10 +1,11 @@
-"""Tiered (delta + main) kernel parity: decisions identical to classic.
+"""Tiered (delta + main) kernel parity: decisions identical to the oracle.
 
-The r6 tiered path (ops/delta.py — G-independent scan body, delta-tier
+The tiered kernel (ops/delta.py — G-independent scan body, delta-tier
 merges, periodic compaction, optional device-side read dedup) must be
-decision-identical to the classic sequential pipeline (ops/conflict.
-resolve_batch per batch) and to the Python oracle, on the adversarial
-shapes the design introduces new machinery for:
+decision-identical to the Python oracle (testing/oracle.ConflictOracle,
+the reference's ConflictBatch semantics, served by CpuConflictSet), and
+its fused groups to its own batch-at-a-time dispatch, on the adversarial
+shapes the design introduces machinery for:
 
 * duplicate/overlapping conflict ranges (the dedup sort+unique path),
 * window-edge versions (snapshots at/beside the GC floor),
@@ -60,10 +61,10 @@ def tiered_config(**kw):
     return KernelConfig(**d)
 
 
-def classic_config(cfg):
-    return dataclasses.replace(
-        cfg, delta_capacity=0, dedup_reads=0, compact_interval=1
-    )
+def run_oracle(cfg, stream):
+    """The reference decisions: testing/oracle.ConflictOracle, through
+    the CPU backend that serves it (CpuConflictSet)."""
+    return run_resolve(CpuConflictSet(cfg), stream)
 
 
 def random_txn(rng, *, snap_lo, snap_hi, n_ranges=2, blind_prob=0.15,
@@ -138,12 +139,14 @@ def canonical_map(hist: H.VersionHistory):
 
 @pytest.mark.parametrize("seed", range(6))
 def test_tiered_matches_classic_random(seed):
+    """Random streams: verdicts and conflicting-key reports equal the
+    oracle's, batch for batch."""
     rng = np.random.default_rng(seed)
     cfg = tiered_config()
     stream = gen_stream(rng, 8)
     res_t = run_resolve(TpuConflictSet(cfg), stream)
-    res_c = run_resolve(TpuConflictSet(classic_config(cfg)), stream)
-    assert_results_match(res_t, res_c, "tiered vs classic")
+    res_o = run_oracle(cfg, stream)
+    assert_results_match(res_t, res_o, "tiered vs oracle")
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -162,21 +165,21 @@ def test_tiered_matches_cpu_oracle(seed):
 def test_duplicate_and_overlapping_ranges_dedup_parity(seed):
     """Hot-key adversarial: most ranges drawn from a small duplicate
     pool (plus overlapping random ones). The dedup path must be
-    decision-identical to dedup-off and to the classic kernel."""
+    decision-identical to dedup-off and to the oracle."""
     rng = np.random.default_rng(200 + seed)
     pool = [random_range(rng) for _ in range(4)]
     stream = gen_stream(rng, 6, dup_pool=pool)
     cfg_dedup = tiered_config(dedup_reads=16)
     res_d = run_resolve(TpuConflictSet(cfg_dedup), stream)
     res_p = run_resolve(TpuConflictSet(tiered_config()), stream)
-    res_c = run_resolve(TpuConflictSet(classic_config(cfg_dedup)), stream)
+    res_o = run_oracle(cfg_dedup, stream)
     assert_results_match(res_d, res_p, "dedup vs plain tiered")
-    assert_results_match(res_d, res_c, "dedup vs classic")
+    assert_results_match(res_d, res_o, "dedup vs oracle")
 
 
 def test_window_edge_versions():
     """Snapshots exactly at / one beside the MVCC floor: the too-old
-    boundary and the GC boundary must match the classic kernel."""
+    boundary and the GC boundary must match the oracle."""
     cfg = tiered_config(window_versions=100)
     k = lambda i: bytes([i])
     streams = []
@@ -190,44 +193,46 @@ def test_window_edge_versions():
             200 + len(streams),  # versions ascend; floor = version - 100
         ))
     res_t = run_resolve(TpuConflictSet(cfg), streams)
-    res_c = run_resolve(TpuConflictSet(classic_config(cfg)), streams)
-    assert_results_match(res_t, res_c, "window edge")
+    res_o = run_oracle(cfg, streams)
+    assert_results_match(res_t, res_o, "window edge")
 
 
 @pytest.mark.parametrize("interval", [1, 2, 4, 0])
 def test_compaction_cadence_invariance(interval):
     """Decisions must not depend on WHEN delta folds into main: every
-    compaction cadence (incl. never) gives identical verdicts, and the
-    combined key->version map after an explicit final compaction matches
-    the classic single-tier map."""
+    compaction cadence (incl. never) gives the oracle's verdicts, and
+    the combined key->version map after an explicit final compaction
+    matches that of a run that folds delta into main only at the end."""
     rng = np.random.default_rng(42)
     stream = gen_stream(rng, 8)
     cfg = tiered_config(compact_interval=interval, delta_capacity=512)
     cs = TpuConflictSet(cfg)
     res = run_resolve(cs, stream)
-    classic = TpuConflictSet(classic_config(cfg))
-    res_c = run_resolve(classic, stream)
-    assert_results_match(res, res_c, f"interval={interval}")
+    assert_results_match(res, run_oracle(cfg, stream), f"interval={interval}")
+    once = TpuConflictSet(dataclasses.replace(cfg, compact_interval=0))
+    run_resolve(once, stream)
 
-    cs.compact_history()
-    assert not bool(np.asarray(H.boundary_count(cs.state.delta)))
+    for c in (cs, once):
+        c.compact_history()
+        assert not bool(np.asarray(H.boundary_count(c.state.delta)))
     got = canonical_map(cs.state.main)
-    want = canonical_map(classic.state)
+    want = canonical_map(once.state.main)
     assert got == want, "post-compaction combined map diverges"
 
 
 def test_compaction_boundary_mid_group_stream():
     """Group-path compaction boundaries: groups resolved through
     resolve_group_args with auto-compaction between them must match the
-    classic sequential path batch-for-batch."""
+    batch-at-a-time path batch-for-batch, and the oracle's verdicts."""
     rng = np.random.default_rng(7)
     cfg = tiered_config(compact_interval=1)
     stream = gen_stream(rng, 9, n_txns=8)
     batches = [
         packing.pack_batch(txns, v, 0, cfg) for txns, v in stream
     ]
-    classic = TpuConflictSet(classic_config(cfg))
-    seq = [classic.resolve_args(b.device_args()) for b in batches]
+    single = TpuConflictSet(cfg)
+    seq = [single.resolve_args(b.device_args()) for b in batches]
+    want = run_oracle(cfg, stream)
 
     cs = TpuConflictSet(cfg)
     outs = [
@@ -250,6 +255,9 @@ def test_compaction_boundary_mid_group_stream():
             np.asarray(seq[i].intra_first_range),
             err_msg=f"intra_first_range batch {i}",
         )
+        assert np.asarray(outs[g].verdict[k])[:8].tolist() == [
+            int(v) for v in want[i].verdicts
+        ], f"oracle verdict batch {i}"
 
 
 def test_dedup_latch_trips_state_unchanged_and_fallback():
@@ -318,13 +326,15 @@ def test_compaction_overflow_folds_into_main():
 
 def test_pipelined_stream_matches_sequential():
     """resolve_stream_pipelined (staging-thread pack->copy->compute)
-    must produce the classic sequential decisions, chunk by chunk."""
+    must produce the batch-at-a-time decisions, chunk by chunk, and
+    the oracle's verdicts."""
     rng = np.random.default_rng(11)
     cfg = tiered_config()
     stream = gen_stream(rng, 8, n_txns=8)
     batches = [packing.pack_batch(t, v, 0, cfg) for t, v in stream]
-    classic = TpuConflictSet(classic_config(cfg))
-    seq = [classic.resolve_args(b.device_args()) for b in batches]
+    single = TpuConflictSet(cfg)
+    seq = [single.resolve_args(b.device_args()) for b in batches]
+    want = run_oracle(cfg, stream)
 
     cs = TpuConflictSet(cfg)
     outs = cs.resolve_stream_pipelined(batches, chunk=3)
@@ -339,6 +349,9 @@ def test_pipelined_stream_matches_sequential():
             np.asarray(outs[g].verdict[k]), np.asarray(seq[i].verdict),
             err_msg=f"pipelined batch {i}",
         )
+        assert np.asarray(outs[g].verdict[k])[:8].tolist() == [
+            int(v) for v in want[i].verdicts
+        ], f"oracle verdict batch {i}"
 
 
 def test_pipelined_stream_overflow_joins_staging_thread():
@@ -376,7 +389,6 @@ def test_tiered_rebase_matches_classic():
     # window wider than the rebase jump so the old-snapshot reader is
     # judged on staleness (CONFLICT), not the too-old floor
     cfg = tiered_config(window_versions=1 << 33, compact_interval=0)
-    ccfg = classic_config(cfg)
     k = lambda i: bytes([i])
     v0 = 1000
     w = CommitTransaction([], [(k(5), k(6))], read_snapshot=v0 - 1)
@@ -387,7 +399,7 @@ def test_tiered_rebase_matches_classic():
                            read_snapshot=far - 1)  # fresh: commits
     stream = [([w], v0), ([r, r2], far)]
     res_t = run_resolve(TpuConflictSet(cfg), stream)
-    res_c = run_resolve(TpuConflictSet(ccfg), stream)
-    assert_results_match(res_t, res_c, "rebase")
+    res_o = run_oracle(cfg, stream)
+    assert_results_match(res_t, res_o, "rebase")
     assert res_t[1].verdicts[0].name == "CONFLICT"
     assert res_t[1].verdicts[1].name == "COMMITTED"

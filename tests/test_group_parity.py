@@ -1,17 +1,24 @@
-"""Group kernel parity: resolve_group(G batches) == G x resolve_batch.
+"""Group kernel parity: resolve_group(G batches) == the reference.
 
 The group kernel (ops/group.py) must be decision-identical to resolving
-the same batches sequentially — including the hard part: a read's
-snapshot can land BETWEEN the group's commit versions, so its conflicts
-with earlier in-group batches are version-dependent, exactly as if
-those batches had already merged into history.
+the same batches one after another in the Python oracle
+(testing/oracle.ConflictOracle, the reference's ConflictBatch semantics)
+— including the hard part: a read's snapshot can land BETWEEN the
+group's commit versions, so its conflicts with earlier in-group batches
+are version-dependent, exactly as if those batches had already merged
+into history.
 
-Also asserts the final history STATE is semantically identical (same
-piecewise key->version map; boundary arrays may differ in redundant
-rows, so maps are compared by evaluation).
+Per batch, the verdicts, the decision counts and the conflicting-key
+report (assembled from the kernel's per-read history hits and first
+intra-batch hit, as the served path assembles it) must equal the
+oracle's. The final history STATE must be semantically identical too:
+both maps are evaluated at every boundary either holds, where a read
+could still conflict (boundary arrays may differ in redundant rows).
 """
 
 from __future__ import annotations
+
+import bisect
 
 import jax
 import jax.numpy as jnp
@@ -19,10 +26,11 @@ import numpy as np
 import pytest
 
 from foundationdb_tpu.config import KernelConfig
+from foundationdb_tpu.models.conflict_set import TpuConflictSet
 from foundationdb_tpu.models.types import CommitTransaction
-from foundationdb_tpu.ops import conflict as C
 from foundationdb_tpu.ops import group as G
 from foundationdb_tpu.ops import history as H
+from foundationdb_tpu.testing.oracle import ConflictOracle, OracleTxn
 from foundationdb_tpu.utils import packing
 
 from conftest import random_key, random_range
@@ -56,10 +64,10 @@ def random_txn(rng, *, n_ranges=2, snap_lo, snap_hi, blind_prob=0.15):
     )
 
 
-def gen_group(rng, config, g, base_version=1000, step=100, n_txns=12):
-    """G batches whose snapshots deliberately straddle the group's
-    commit versions (the cross-batch visibility trap)."""
-    batches = []
+def gen_group(rng, g, base_version=1000, step=100, n_txns=12):
+    """G (txns, version) batches whose snapshots deliberately straddle
+    the group's commit versions (the cross-batch visibility trap)."""
+    stream = []
     for i in range(g):
         version = base_version + (i + 1) * step
         txns = [
@@ -70,10 +78,12 @@ def gen_group(rng, config, g, base_version=1000, step=100, n_txns=12):
             )
             for _ in range(n_txns)
         ]
-        batches.append(
-            packing.pack_batch(txns, version, 0, config)
-        )
-    return batches
+        stream.append((txns, version))
+    return stream
+
+
+def pack(config, stream):
+    return [packing.pack_batch(t, v, 0, config) for t, v in stream]
 
 
 def canonical_map(state, config):
@@ -98,53 +108,82 @@ def canonical_map(state, config):
     return out
 
 
-def run_sequential(config, batches):
-    state = H.init(config)
-    step = jax.jit(C.resolve_batch)
-    outs = []
-    for pb in batches:
-        state, out = step(state, pb.device_args())
-        outs.append(jax.tree_util.tree_map(np.asarray, out))
-    return state, outs
+def live_maps(state, oracle, config):
+    """The device history and the oracle's, each evaluated at every
+    boundary either holds: the version in force there, or None where it
+    is at or below the oracle's MVCC floor (no read that is not too old
+    can conflict with it there; unwritten keys read 0 in the oracle and
+    VERSION_NEG on the device)."""
+    dev = canonical_map(state, config)
+    ref = [
+        (tuple(packing.pack_key(b, config.max_key_bytes).tolist()), v)
+        for b, v in zip(oracle.history.boundaries, oracle.history.values)
+    ]
+
+    def at(rows, key, background):
+        i = bisect.bisect_right([k for k, _ in rows], key) - 1
+        v = rows[i][1] if i >= 0 else background
+        return v if v > oracle.oldest else None
+
+    points = sorted({k for k, _ in dev} | {k for k, _ in ref})
+    return (
+        [at(dev, p, H.VERSION_NEG) for p in points],
+        [at(ref, p, oracle.history.background) for p in points],
+    )
 
 
-def run_group(config, batches):
-    state = H.init(config)
+def oracle_txns(txns):
+    return [
+        OracleTxn(t.read_conflict_ranges, t.write_conflict_ranges,
+                  t.read_snapshot, True)
+        for t in txns
+    ]
+
+
+def assert_batch_matches(out, i, batch, txns, want, label=""):
+    """Row i of a GroupVerdict against the oracle's result for the
+    same batch: verdicts, counts, and the conflicting-key report."""
+    n = len(txns)
+    verdict = np.asarray(out.verdict[i])[:n]
+    assert verdict.tolist() == want.verdicts, f"{label}verdict batch {i}"
+    assert int(out.committed_count[i]) == want.verdicts.count(G.COMMITTED)
+    assert int(out.too_old_count[i]) == want.verdicts.count(G.TOO_OLD)
+    report = TpuConflictSet._assemble(
+        batch, verdict, np.asarray(out.hist_conflict_read[i]),
+        np.asarray(out.intra_first_range[i])[:n], [True] * n,
+        lambda r: txns[int(batch.read_txn[r])].read_conflict_ranges[
+            int(batch.read_index[r])][0],
+    )
+    assert report.conflicting_key_ranges == want.conflicting_ranges, (
+        f"{label}conflicting-key report batch {i}"
+    )
+
+
+def run_group(config, batches, state=None):
+    state = H.init(config) if state is None else state
     stacked = packing.stack_device_args(batches)
     state, out = jax.jit(G.resolve_group)(state, stacked)
     return state, jax.tree_util.tree_map(np.asarray, out)
 
 
-def assert_group_matches(config, batches):
-    s_seq, seq_outs = run_sequential(config, batches)
-    s_grp, grp_out = run_group(config, batches)
-    for i, so in enumerate(seq_outs):
-        np.testing.assert_array_equal(
-            grp_out.verdict[i], so.verdict, err_msg=f"verdict batch {i}"
-        )
-        np.testing.assert_array_equal(
-            grp_out.hist_conflict_read[i],
-            so.hist_conflict_read,
-            err_msg=f"hist_conflict_read batch {i}",
-        )
-        np.testing.assert_array_equal(
-            grp_out.intra_first_range[i],
-            so.intra_first_range,
-            err_msg=f"intra_first_range batch {i}",
-        )
-        assert grp_out.committed_count[i] == so.committed_count
-        assert grp_out.too_old_count[i] == so.too_old_count
-    assert canonical_map(s_grp, config) == canonical_map(s_seq, config), (
-        "final history maps diverge"
-    )
+def assert_group_matches(config, stream, oracle=None, state=None):
+    """Resolve `stream` as one group (on `state`, else empty history)
+    and compare batch for batch with `oracle` (else a fresh one)."""
+    oracle = oracle or ConflictOracle(config.window_versions)
+    batches = pack(config, stream)
+    s_grp, grp_out = run_group(config, batches, state)
+    for i, (txns, version) in enumerate(stream):
+        want = oracle.resolve(oracle_txns(txns), version)
+        assert_batch_matches(grp_out, i, batches[i], txns, want)
+    got, ref = live_maps(s_grp, oracle, config)
+    assert got == ref, "final history maps diverge"
+    return s_grp, grp_out
 
 
 @pytest.mark.parametrize("seed", range(8))
 def test_group_matches_sequential_random(seed):
     rng = np.random.default_rng(seed)
-    config = small_config()
-    batches = gen_group(rng, config, g=4)
-    assert_group_matches(config, batches)
+    assert_group_matches(small_config(), gen_group(rng, g=4))
 
 
 def test_group_snapshot_straddles_versions():
@@ -169,12 +208,11 @@ def test_group_snapshot_straddles_versions():
         write_conflict_ranges=[(k(11), k(12))],
         read_snapshot=90,
     )
-    b0 = packing.pack_batch([t_writer], 100, 0, config)
-    b1 = packing.pack_batch([t_reader_new, t_reader_old], 200, 0, config)
-    assert_group_matches(config, [b0, b1])
-    _, out = run_group(config, [b0, b1])
-    assert out.verdict[1][0] == C.COMMITTED  # saw the write already
-    assert out.verdict[1][1] == C.CONFLICT   # stale snapshot
+    _, out = assert_group_matches(
+        config, [([t_writer], 100), ([t_reader_new, t_reader_old], 200)]
+    )
+    assert out.verdict[1][0] == G.COMMITTED  # saw the write already
+    assert out.verdict[1][1] == G.CONFLICT   # stale snapshot
 
 
 def test_group_too_old_and_blind_writes():
@@ -190,12 +228,11 @@ def test_group_too_old_and_blind_writes():
         write_conflict_ranges=[(k(3), k(4))],
         read_snapshot=5,  # stale snapshot but NO reads: never too old
     )
-    b0 = packing.pack_batch([stale, blind], 200, 0, config)
-    b1 = packing.pack_batch([stale], 300, 0, config)
-    assert_group_matches(config, [b0, b1])
-    _, out = run_group(config, [b0, b1])
-    assert out.verdict[0][0] == C.TOO_OLD
-    assert out.verdict[0][1] == C.COMMITTED
+    _, out = assert_group_matches(
+        config, [([stale, blind], 200), ([stale], 300)]
+    )
+    assert out.verdict[0][0] == G.TOO_OLD
+    assert out.verdict[0][1] == G.COMMITTED
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -205,7 +242,7 @@ def test_group_hot_key_contention(seed):
     rng = np.random.default_rng(100 + seed)
     config = small_config()
     hot = (b"\x10", b"\x11")
-    batches = []
+    stream = []
     base, step = 1000, 100
     for i in range(4):
         version = base + (i + 1) * step
@@ -216,8 +253,8 @@ def test_group_hot_key_contention(seed):
                 write_conflict_ranges=[hot] if rng.random() < 0.7 else [random_range(rng)],
                 read_snapshot=int(rng.integers(base - step, version)),
             ))
-        batches.append(packing.pack_batch(txns, version, 0, config))
-    assert_group_matches(config, batches)
+        stream.append((txns, version))
+    assert_group_matches(config, stream)
 
 
 def test_group_continuation_across_groups():
@@ -225,62 +262,26 @@ def test_group_continuation_across_groups():
     as ordinary history."""
     rng = np.random.default_rng(7)
     config = small_config()
-    all_batches = gen_group(rng, config, g=6, n_txns=10)
-    s_seq, seq_outs = run_sequential(config, all_batches)
-
-    state = H.init(config)
-    jg = jax.jit(G.resolve_group)
-    outs = []
-    for lo in (0, 3):
-        stacked = packing.stack_device_args(all_batches[lo : lo + 3])
-        state, out = jg(state, stacked)
-        outs.append(jax.tree_util.tree_map(np.asarray, out))
-    for i in range(6):
-        np.testing.assert_array_equal(
-            outs[i // 3].verdict[i % 3],
-            seq_outs[i].verdict,
-            err_msg=f"batch {i}",
-        )
-    assert canonical_map(state, config) == canonical_map(s_seq, config)
+    stream = gen_group(rng, g=6, n_txns=10)
+    oracle = ConflictOracle(config.window_versions)
+    state, _ = assert_group_matches(config, stream[:3], oracle)
+    assert_group_matches(config, stream[3:], oracle, state)
 
 
 @pytest.mark.parametrize("seed", range(4))
 def test_group_parity_with_prestate(seed):
-    """Parity — including the per-read hist_conflict_read report — when
+    """Parity — including the per-read conflicting-key report — when
     history is NON-empty before the group (a txn condemned by pre-group
     history must still report its cross-batch conflicting reads)."""
     rng = np.random.default_rng(200 + seed)
     config = small_config()
-    pre = gen_group(rng, config, g=2, base_version=500)
-    batches = gen_group(rng, config, g=4, base_version=1000)
-
-    state_a = H.init(config)
-    step = jax.jit(C.resolve_batch)
-    for pb in pre:
-        state_a, _ = step(state_a, pb.device_args())
-    seq_outs = []
-    state_s = state_a
-    for pb in batches:
-        state_s, out = step(state_s, pb.device_args())
-        seq_outs.append(jax.tree_util.tree_map(np.asarray, out))
-
-    state_b = H.init(config)
-    for pb in pre:
-        state_b, _ = step(state_b, pb.device_args())
-    stacked = packing.stack_device_args(batches)
-    state_g, grp = jax.jit(G.resolve_group)(state_b, stacked)
-    grp = jax.tree_util.tree_map(np.asarray, grp)
-
-    for i, so in enumerate(seq_outs):
-        np.testing.assert_array_equal(grp.verdict[i], so.verdict)
-        np.testing.assert_array_equal(
-            grp.hist_conflict_read[i], so.hist_conflict_read,
-            err_msg=f"hist_conflict_read batch {i}",
-        )
-        np.testing.assert_array_equal(
-            grp.intra_first_range[i], so.intra_first_range
-        )
-    assert canonical_map(state_g, config) == canonical_map(state_s, config)
+    pre = gen_group(rng, g=2, base_version=500)
+    stream = gen_group(rng, g=4, base_version=1000)
+    oracle = ConflictOracle(config.window_versions)
+    for txns, version in pre:
+        oracle.resolve(oracle_txns(txns), version)
+    state, _ = run_group(config, pack(config, pre))
+    assert_group_matches(config, stream, oracle, state)
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -343,10 +344,9 @@ def test_short_span_latch_trips_on_wide_ranges():
 
 
 def test_group_of_one_equals_resolve_batch():
+    """G=1, the shape the delta tier runs the group kernel at."""
     rng = np.random.default_rng(3)
-    config = small_config()
-    batches = gen_group(rng, config, g=1)
-    assert_group_matches(config, batches)
+    assert_group_matches(small_config(), gen_group(rng, g=1))
 
 
 def test_fixpoint_latch_refuses_deep_chains_and_preserves_state():

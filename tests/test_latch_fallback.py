@@ -1,13 +1,13 @@
 """fixpoint_latch contract: trips never externalize wrong verdicts.
 
-The latched group kernel (ops/group.py, fixpoint_latch=True) REFUSES a
-group whose intra-batch conflict chains run deeper than fixpoint_unroll:
-GroupVerdict.unconverged trips and the returned state is the unchanged
-input state. The host contract (ADVICE r4 + VERDICT r4 task 5):
+The latched tiered kernel (ops/delta.py over ops/group.py,
+fixpoint_latch=True) REFUSES a group whose intra-batch conflict chains
+run deeper than fixpoint_unroll: GroupVerdict.unconverged trips and the
+returned state (both tiers) is the unchanged input state. The host contract (ADVICE r4 + VERDICT r4 task 5):
 
 * TpuConflictSet.resolve_group_args (default check_latch=True) must
   detect the trip and auto-redispatch the SAME args on the exact
-  while-loop kernel — callers see correct verdicts, never the latched
+  kernel — callers see correct verdicts, never the latched
   garbage.
 * prewarm_exact compiles the exact program up front so the fallback is
   a program swap, not an XLA compile stall mid-version-chain (the
@@ -20,13 +20,14 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
 from foundationdb_tpu.config import KernelConfig
 from foundationdb_tpu.models.conflict_set import (
     TpuConflictSet,
-    _resolve_group_jit,
+    _resolve_tiered_jit,
 )
 from foundationdb_tpu.models.types import CommitTransaction
 from foundationdb_tpu.utils import packing
@@ -77,12 +78,12 @@ def test_latch_trips_and_autoredispatch_matches_exact():
 
     # raw latched kernel refuses: unconverged trips, state unchanged
     cs_raw = TpuConflictSet(config)
-    before = np.asarray(cs_raw.state.main_keys).copy()
+    before = jax.tree.map(lambda x: np.asarray(x).copy(), cs_raw.state)
     outs_raw = cs_raw.resolve_group_args(stacked, check_latch=False)
     assert bool(np.asarray(outs_raw.unconverged).any())
-    np.testing.assert_array_equal(
-        np.asarray(cs_raw.state.main_keys), before
-    )
+    for a, b in zip(jax.tree_util.tree_leaves(before),
+                    jax.tree_util.tree_leaves(cs_raw.state)):
+        np.testing.assert_array_equal(np.asarray(b), a)
 
     # default path: auto-redispatch serves the exact kernel's decisions
     cs = TpuConflictSet(config)
@@ -95,12 +96,9 @@ def test_latch_trips_and_autoredispatch_matches_exact():
         np.asarray(outs.verdict), np.asarray(ref.verdict)
     )
     # ... and the post-group history state matches the exact kernel's
-    np.testing.assert_array_equal(
-        np.asarray(cs.state.main_keys), np.asarray(cs_exact.state.main_keys)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(cs.state.main_ver), np.asarray(cs_exact.state.main_ver)
-    )
+    for a, b in zip(jax.tree_util.tree_leaves(cs.state),
+                    jax.tree_util.tree_leaves(cs_exact.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_prewarm_exact_avoids_fallback_compile():
@@ -110,7 +108,7 @@ def test_prewarm_exact_avoids_fallback_compile():
 
     cs = TpuConflictSet(config)
     cs.prewarm_exact(stacked)
-    fn = _resolve_group_jit(0, config.fixpoint_unroll, False)
+    fn = _resolve_tiered_jit(0, config.fixpoint_unroll, False, 0, False)
     warmed = fn._cache_size()
     assert warmed >= 1
 

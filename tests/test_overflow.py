@@ -32,6 +32,9 @@ def make_cfg(capacity: int) -> KernelConfig:
         max_writes=16,
         history_capacity=capacity,
         window_versions=10_000_000,  # no GC relief inside the test
+        # fold delta into main after every batch: main (the capacity
+        # under test) takes each batch's writes before the next resolve
+        compact_interval=1,
     )
 
 

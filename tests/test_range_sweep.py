@@ -5,8 +5,8 @@ Two escape hatches closed, each pinned both directions:
 * `range_sweep` — the tiered kernel's main-tier probe as ONE per-group
   sorted-endpoint sweep (ops/delta.sweep_read_ranks) instead of
   per-read binary searches with a bounded probe window. Decision
-  parity vs the probe path, the classic kernel, CpuConflictSet and the
-  multi-resolver oracle on range-heavy / mixed / window-edge streams,
+  parity vs the probe path, the oracle (testing/oracle.ConflictOracle,
+  served by CpuConflictSet) and the multi-resolver oracle on range-heavy / mixed / window-edge streams,
   single-device, sharded (n=2) and through the pipelined stream.
 * `delta_spill` — delta-capacity pressure folds delta into MAIN (the
   compaction program, dispatched asynchronously) instead of
@@ -58,11 +58,12 @@ def probe_config(cfg, **kw):
     return dataclasses.replace(cfg, range_sweep=False, **kw)
 
 
-def classic_config(cfg):
-    return dataclasses.replace(
-        cfg, delta_capacity=0, dedup_reads=0, range_sweep=False,
-        delta_spill=False, compact_interval=1,
-    )
+def oracle_verdicts(cfg, stream):
+    """The reference verdicts per batch: testing/oracle.ConflictOracle,
+    through the CPU backend that serves it (CpuConflictSet)."""
+    oracle = CpuConflictSet(cfg)
+    return [[int(v) for v in oracle.resolve(t, ver).verdicts]
+            for t, ver in stream]
 
 
 def ikey(v, width=4):
@@ -140,9 +141,9 @@ def test_sweep_matches_probe_and_classic_range_heavy(seed):
     stream = gen_stream(rng, 8)
     res_s = run_resolve(TpuConflictSet(cfg), stream)
     res_p = run_resolve(TpuConflictSet(probe_config(cfg)), stream)
-    res_c = run_resolve(TpuConflictSet(classic_config(cfg)), stream)
+    res_o = run_resolve(CpuConflictSet(cfg), stream)
     assert_results_match(res_s, res_p, "sweep vs probe")
-    assert_results_match(res_s, res_c, "sweep vs classic")
+    assert_results_match(res_s, res_o, "sweep vs oracle")
 
 
 @pytest.mark.parametrize("seed", range(3))
@@ -243,8 +244,7 @@ def test_sweep_pipelined_stream_matches_sequential():
     cfg = sweep_config()
     stream = gen_stream(rng, 8, n_txns=8)
     batches = [packing.pack_batch(t, v, 0, cfg) for t, v in stream]
-    classic = TpuConflictSet(classic_config(cfg))
-    seq = [classic.resolve_args(b.device_args()) for b in batches]
+    want = oracle_verdicts(cfg, stream)
 
     cs = TpuConflictSet(cfg)
     outs = cs.resolve_stream_pipelined(batches, chunk=3)
@@ -255,19 +255,19 @@ def test_sweep_pipelined_stream_matches_sequential():
     ]
     assert len(flat) == len(batches)
     for i, (g, k) in enumerate(flat):
-        np.testing.assert_array_equal(
-            np.asarray(outs[g].verdict[k]), np.asarray(seq[i].verdict),
-            err_msg=f"pipelined sweep batch {i}",
-        )
+        got = np.asarray(outs[g].verdict[k])[: len(want[i])].tolist()
+        assert got == want[i], f"pipelined sweep batch {i}"
 
 
 def test_sweep_excludes_dedup():
     with pytest.raises(ValueError, match="range_sweep and dedup_reads"):
         sweep_config(dedup_reads=8)
-    with pytest.raises(ValueError, match="range_sweep requires"):
-        KernelConfig(range_sweep=True)
-    with pytest.raises(ValueError, match="delta_spill requires"):
-        KernelConfig(delta_spill=True)
+    # every config is tiered: an unset delta tier is sized by the rule,
+    # an empty one is refused
+    for knob in ("range_sweep", "delta_spill"):
+        assert KernelConfig(**{knob: True}).delta_capacity == 2 * 4096 * 8
+        with pytest.raises(ValueError, match="delta_capacity"):
+            KernelConfig(**{knob: True}, delta_capacity=0)
 
 
 # ---------------------------------------------------------------------------
@@ -408,7 +408,7 @@ def test_spill_mid_stream_with_staging_thread():
     """Overflow pressure mid-stream with the pipelined staging thread
     active: the spill compaction dispatches between chunk dispatches on
     the compute thread, the staging thread keeps feeding, nothing
-    raises, and decisions match the sequential reference."""
+    raises, and decisions match the oracle's."""
     import threading
 
     rng = np.random.default_rng(41)
@@ -424,15 +424,13 @@ def test_spill_mid_stream_with_staging_thread():
     assert cs.metrics.counters.get("exactFallbacks") == 0
     cs.check_overflow()
 
-    classic = TpuConflictSet(classic_config(cfg))
-    seq = [classic.resolve_args(b.device_args()) for b in batches]
+    want = oracle_verdicts(cfg, stream)
     vs = np.concatenate(
         [np.asarray(o.verdict).reshape(-1, cfg.max_txns) for o in outs]
     )
     for i in range(len(batches)):
-        np.testing.assert_array_equal(
-            vs[i], np.asarray(seq[i].verdict),
-            err_msg=f"mid-stream spill batch {i}",
+        assert vs[i][: len(want[i])].tolist() == want[i], (
+            f"mid-stream spill batch {i}"
         )
 
 

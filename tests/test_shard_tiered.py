@@ -8,8 +8,6 @@ per shard (phantom commits included), verdicts min-combined on device
 (`pmin`; conflict-read bitmasks via `psum`). Oracles:
 
 * MultiResolverOracle — the reference semantics model (always exact);
-* the classic sharded kernel (ShardedConflictSet) — same semantics,
-  different machinery (always exact);
 * the SINGLE-DEVICE tiered kernel — exact whenever no transaction can
   phantom-commit across shards: a degenerate partition (one empty
   shard) and shard-local workloads pin that equivalence.
@@ -39,10 +37,7 @@ from foundationdb_tpu.models.conflict_base import (
 )
 from foundationdb_tpu.models.types import CommitTransaction
 from foundationdb_tpu.parallel.mesh import cpu_mesh
-from foundationdb_tpu.parallel.sharding import (
-    ShardedConflictSet,
-    default_boundaries,
-)
+from foundationdb_tpu.parallel.sharding import default_boundaries
 from foundationdb_tpu.testing.oracle import MultiResolverOracle, OracleTxn
 from foundationdb_tpu.utils import packing
 from foundationdb_tpu.utils.packing import stack_device_args
@@ -198,24 +193,15 @@ def test_sharded_columnar_matches_multi_resolver_oracle():
 
 @pytest.mark.parametrize("n_shards", [2, 4])
 def test_sharded_tiered_matches_classic_sharded(n_shards):
-    """Same reference multi-resolver semantics, different machinery:
-    the tiered shard_map kernel vs the classic single-tier shard_map
-    kernel must agree batch for batch."""
+    """The tiered shard_map kernel must agree batch for batch with the
+    reference multi-resolver semantics (MultiResolverOracle)."""
     rng = np.random.default_rng(40 + n_shards)
     boundaries = even_boundaries(n_shards)
     cfg = tiered_config(n_shards=n_shards)
     dev = make_sharded(cfg, boundaries)
-    classic = ShardedConflictSet(
-        dataclasses.replace(cfg, n_shards=0, delta_capacity=0),
-        cpu_mesh(n_shards), boundaries,
-    )
+    oracle = MultiResolverOracle(boundaries, window=cfg.window_versions)
     stream = gen_stream(rng, 6)
-    for txns, ver in stream:
-        got = [int(v) for v in dev.resolve(txns, ver).verdicts]
-        want = np.asarray(
-            classic.resolve(txns, ver).verdict
-        )[: len(txns)].tolist()
-        assert got == want
+    assert run_verdicts(dev, stream) == oracle_verdicts(oracle, stream)
 
 
 # ---------------------------------------------------------------------------
@@ -607,7 +593,9 @@ def test_sharded_metrics_surface():
 
 
 def test_config_validation():
-    with pytest.raises(ValueError, match="tiered-only"):
+    with pytest.raises(ValueError, match="delta_capacity must be positive"):
+        KernelConfig(delta_capacity=0)
+    with pytest.raises(ValueError, match="delta_capacity must be positive"):
         KernelConfig(delta_capacity=0, n_shards=2)
     with pytest.raises(ValueError, match="n_shards"):
         KernelConfig(delta_capacity=64, n_shards=-1)

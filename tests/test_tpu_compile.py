@@ -105,7 +105,7 @@ def programs(topo, sizes: dict, full: bool = False) -> dict:
         from foundationdb_tpu.models.types import CommitTransaction
         from foundationdb_tpu.utils import packing
 
-        served = bench_pipeline.kernel_config(sizes["uniform"], tiered=True)
+        served = bench_pipeline.kernel_config(sizes["uniform"])
         one_txn = CommitTransaction(
             read_conflict_ranges=[(b"a", b"b")],
             write_conflict_ranges=[(b"a", b"b")], read_snapshot=0,
