@@ -767,6 +767,8 @@ class ResolverRole:
             "txns": 0,
             "copies": 0,
             "decode_allocs": 0,
+            # batches whose key sample was counted in numpy
+            "key_sample_fast_batches": 0,
         }
         # -- conflict-range key sample (ISSUE 20): the wire twin of the
         # sim resolver's ResolutionBalancer sample — begin keys by touch
@@ -1272,33 +1274,20 @@ class ResolverRole:
 
     def _note_key_sample(self, req) -> None:
         """Feed the conflict-range key sample from BOTH frame kinds
-        without materializing transactions: the columnar blob's
-        canonical key order (read begins, read ends, write begins,
-        write ends — packing._KEY_ORDER_DOC) lets the begin keys slice
-        straight out of the key_lens offsets."""
+        without materializing transactions: a columnar frame's begin
+        keys slice straight out of its blob, and a full batch's are
+        counted in numpy (sampling.note_columnar_begin_keys)."""
         from foundationdb_tpu.cluster import sampling as _sampling
 
-        sample = self._key_sample
         if isinstance(req, codec.ResolveBatchColumnar):
-            cols = req.cols
-            if len(cols.key_lens) == 0:
-                return
-            import numpy as _np
-
-            offs = _np.concatenate(
-                ([0], _np.cumsum(cols.key_lens, dtype=_np.int64))
-            )
-            blob = bytes(cols.key_blob)
-            nr, nw = cols.n_reads, cols.n_writes
-            for i in (*range(nr), *range(2 * nr, 2 * nr + nw)):
-                b = blob[offs[i]:offs[i + 1]]
-                sample[b] = sample.get(b, 0) + 1
-        else:
-            for t in req.transactions:
-                for b, _e in t.read_conflict_ranges + t.write_conflict_ranges:
-                    sample[b] = sample.get(b, 0) + 1
-        if len(sample) > _sampling.KEY_SAMPLE_LIMIT:
-            _sampling.decay_key_sample(sample)
+            if _sampling.note_columnar_begin_keys(self._key_sample, req.cols):
+                self.path_stats["key_sample_fast_batches"] += 1
+            return
+        _sampling.note_begin_keys(self._key_sample, (
+            b
+            for t in req.transactions
+            for b, _e in t.read_conflict_ranges + t.write_conflict_ranges
+        ))
 
     def _resolve_now(self, req) -> ResolveTransactionBatchReply:
         """One batch, from its key sample to its reply. In the

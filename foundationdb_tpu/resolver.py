@@ -44,6 +44,11 @@ from foundationdb_tpu.models.types import (
     ResolveTransactionBatchRequest,
     TransactionResult,
 )
+from foundationdb_tpu.cluster.sampling import (
+    KEY_SAMPLE_LIMIT,
+    key_sample_qos,
+    note_begin_keys,
+)
 from foundationdb_tpu.runtime.flow import Notified, Scheduler, Trigger, any_of
 from foundationdb_tpu.utils.metrics import CounterCollection, LatencySample
 from foundationdb_tpu.utils import commit_debug as _cd
@@ -61,9 +66,6 @@ declare(
 
 #: ServerKnobs.RESOLVER_STATE_MEMORY_LIMIT (fdbclient/ServerKnobs.cpp).
 DEFAULT_STATE_MEMORY_LIMIT = 1_000_000
-
-#: key-sample capacity before decay (VERDICT r1 weakness 7)
-KEY_SAMPLE_LIMIT = 4096
 
 
 @dataclasses.dataclass
@@ -413,10 +415,10 @@ class Resolver:
                 # > 1): the future balancer and today's hotspot sensors
                 # both need conflict-range density on single-resolver
                 # clusters too
-                for b, _e in tr.read_conflict_ranges + tr.write_conflict_ranges:
-                    self._key_sample[b] = self._key_sample.get(b, 0) + 1
-                if len(self._key_sample) > KEY_SAMPLE_LIMIT:
-                    self._decay_key_sample()
+                note_begin_keys(self._key_sample, (
+                    b for b, _e in
+                    tr.read_conflict_ranges + tr.write_conflict_ranges
+                ), KEY_SAMPLE_LIMIT)
 
             if self.conflict_set is None:
                 self._route_backend(req.transactions)
@@ -629,21 +631,7 @@ class Resolver:
     def _key_sample_qos(self) -> dict:
         """The key-sample sensor block (sampling.key_sample_qos so the
         sim and wire resolvers can never report divergent shapes)."""
-        from foundationdb_tpu.cluster.sampling import key_sample_qos
-
         return key_sample_qos(self._key_sample)
-
-    def _decay_key_sample(self) -> None:
-        """Halve all counts, dropping zeros; if the key set itself is too
-        wide, keep the heaviest half. Split points stay representative
-        (hot boundaries survive decay by construction) while memory stays
-        O(KEY_SAMPLE_LIMIT) forever."""
-        self._key_sample = {
-            k: c // 2 for k, c in self._key_sample.items() if c // 2 > 0
-        }
-        if len(self._key_sample) > KEY_SAMPLE_LIMIT:
-            top = sorted(self._key_sample.items(), key=lambda kv: -kv[1])
-            self._key_sample = dict(top[: KEY_SAMPLE_LIMIT // 2])
 
     def metrics(self) -> int:
         """ResolutionMetricsRequest: total sampled conflict-range ops."""

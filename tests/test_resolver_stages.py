@@ -228,6 +228,46 @@ def test_stage_timer_is_cheap_without_a_profiler_session(monkeypatch):
     assert sample.count == 50_000
 
 
+def _point_frame(prev, version, reads, writes):
+    """A columnar resolve frame of point-range txns, txn i reading
+    reads[i] and writing writes[i]."""
+    txns = [
+        CommitTransaction(read_conflict_ranges=[(r, r + b"\0")],
+                          write_conflict_ranges=[(w, w + b"\0")],
+                          read_snapshot=max(0, version - 1))
+        for r, w in zip(reads, writes)
+    ]
+    return codec.ResolveBatchColumnar(
+        prev_version=prev, version=version, last_received_version=prev,
+        cols=packing.pack_columnar(txns),
+    )
+
+
+def test_key_sample_counts_full_batches_in_numpy():
+    """A full 64K-txn frame of 8-byte keys is counted in numpy, a 4-txn
+    YCSB frame by the loop; the status block is the loop's either way."""
+    from foundationdb_tpu.cluster import sampling
+
+    rng = random.Random(28)
+    keys = [rng.randrange(20_000_000).to_bytes(8, "big")
+            for _ in range(2 * 65536)]
+    users = [b"user%d" % rng.randrange(1000) for _ in range(8)]
+    frames = [
+        (-1, 10, keys[:65536], keys[65536:], 1),
+        (10, 20, users[:4], users[4:], 1),
+    ]
+    role = mp.ResolverRole("native")
+    loop: dict = {}
+    for prev, version, reads, writes, fast_batches in frames:
+        asyncio.run(role.resolve(_point_frame(prev, version, reads, writes)))
+        # a columnar frame's order: every read begin, then every write's
+        sampling.note_begin_keys(loop, reads + writes)
+        qos = role.status()["qos"]
+        assert qos["resolve_path"]["key_sample_fast_batches"] == fast_batches
+        assert qos["key_sample"] == sampling.key_sample_qos(loop)
+        assert list(role._key_sample.items()) == list(loop.items())
+
+
 _NATIVE_ROLE = """
 import asyncio, sys
 from foundationdb_tpu.cluster import multiprocess as mp

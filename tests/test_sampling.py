@@ -10,16 +10,21 @@ flat stays quiet, starved range samples never flag).
 
 import random
 
+import numpy as np
 import pytest
 
 from foundationdb_tpu.cluster.sampling import (
     DOMINANCE_FRAC,
     HOT_RANGE_MIN_KEYS,
+    KEY_SAMPLE_LIMIT,
+    KEY_SAMPLE_NUMPY_MIN_KEYS,
     ByteSample,
     TagCounter,
     attribute_hotspot,
     decay_key_sample,
     key_sample_qos,
+    note_begin_keys,
+    note_columnar_begin_keys,
     tag_of_key,
 )
 
@@ -234,6 +239,183 @@ def test_decay_key_sample_and_qos():
     assert len(wide) == 5  # heaviest half of the limit kept
     qos = key_sample_qos({b"x/1": 5, b"x/2": 2}, top_n=1)
     assert qos == {"keys": 2, "top": [{"key": "x/1", "count": 5}]}
+
+
+# ---------------------------------------------------------------------------
+# columnar begin-key counting: numpy path against the loop
+
+
+def _loop_reference(sample, cols, limit=KEY_SAMPLE_LIMIT):
+    """The resolver role's key-sample loop over a columnar frame, as it
+    stood before the numpy path: the reference the numpy path must
+    match in keys, counts and insertion order."""
+    if len(cols.key_lens) == 0:
+        return
+    offs = np.concatenate(([0], np.cumsum(cols.key_lens, dtype=np.int64)))
+    blob = bytes(cols.key_blob)
+    nr, nw = cols.n_reads, cols.n_writes
+    for i in (*range(nr), *range(2 * nr, 2 * nr + nw)):
+        b = blob[offs[i]:offs[i + 1]]
+        sample[b] = sample.get(b, 0) + 1
+    if len(sample) > limit:
+        decay_key_sample(sample, limit)
+
+
+def _frame(reads, writes=()):
+    """A columnar frame of point-range txns: txn i reads reads[i] (if
+    any) and writes writes[i] (if any)."""
+    from foundationdb_tpu.models.types import CommitTransaction
+    from foundationdb_tpu.utils.packing import pack_columnar
+
+    n = max(len(reads), len(writes))
+    return pack_columnar([
+        CommitTransaction(
+            read_conflict_ranges=(
+                [(reads[i], reads[i] + b"\0")] if i < len(reads) else []
+            ),
+            write_conflict_ranges=(
+                [(writes[i], writes[i] + b"\0")] if i < len(writes) else []
+            ),
+        )
+        for i in range(n)
+    ])
+
+
+def _words8(rng, n, hi=20_000_000):
+    return [int(v).to_bytes(8, "big")
+            for v in rng.integers(0, hi, n, dtype=np.uint64)]
+
+
+def _case_uniform_8_byte(rng):
+    # the resolver cell's shape: 64K begin keys over 20M ids, a decay
+    # on every batch
+    return [], [(_frame(_words8(rng, 32768), _words8(rng, 32768)), True)
+                for _ in range(3)]
+
+
+def _case_hot_keys(rng):
+    # a few hot keys repeated within and across batches, over a cold
+    # uniform tail; the decay runs on every batch
+    hot = _words8(rng, 40)
+    out = []
+    for _ in range(4):
+        keys = [hot[int(i)] for i in rng.zipf(1.5, 6000) % len(hot)]
+        keys += _words8(rng, 6000)
+        rng.shuffle(keys)
+        out.append((_frame(keys[:6000], keys[6000:]), True))
+    return [], out
+
+
+def _case_no_decay(rng):
+    # repeats from a small pool: the sample stays under the limit, so
+    # every new key is kept in first-appearance order
+    pool = _words8(rng, 1500)
+    return [], [
+        (_frame([pool[int(i)] for i in rng.integers(0, 1500, 2500)]), True)
+        for _ in range(3)
+    ]
+
+
+def _case_truncation_ties(rng):
+    # 10K keys seen twice each: after halving all tie at 1, more than
+    # the limit, so the heaviest half keeps dict order; old keys carry
+    # ties of their own across batches
+    out = []
+    for _ in range(3):
+        keys = _words8(rng, 10000) * 2
+        rng.shuffle(keys)
+        out.append((_frame(keys[:10000], keys[10000:]), True))
+    return [], out
+
+
+def _case_mixed_short(rng):
+    # mixed lengths 0-7 with repeats: the length rides in the word
+    pool = [bytes(rng.integers(0, 256, int(n), dtype=np.uint8))
+            for n in rng.integers(0, 8, 3000)]
+    out = []
+    for _ in range(3):
+        keys = [pool[int(i)] for i in rng.integers(0, 3000, 5000)]
+        out.append((_frame(keys[:2500], keys[2500:]), True))
+    return [], out
+
+
+def _case_fixed_short(rng):
+    # every begin key 4 bytes: zero-padded words
+    keys = [k[:4] for k in _words8(rng, 3000, hi=2**64 - 1)] * 2
+    return [], [(_frame(keys[:3000], keys[3000:]), True)]
+
+
+def _case_long_keys_fall_back(rng):
+    keys = [b"%012d" % int(v) for v in rng.integers(0, 10**12, 4096)]
+    return [], [(_frame(keys[:2048], keys[2048:]), False)]
+
+
+def _case_mixed_8_9_fall_back(rng):
+    keys = _words8(rng, 4095) + [b"123456789"]
+    return [], [(_frame(keys[:2048], keys[2048:]), False)]
+
+
+def _case_empty_and_reads_only(rng):
+    return [b"k1", b"k2"], [
+        (_frame([]), False),
+        (_frame(_words8(rng, 3000)), True),
+        (_frame([]), False),
+    ]
+
+
+def _case_small_batch_keeps_the_loop(rng):
+    users = [b"user%d" % i for i in range(4)]
+    return [], [(_frame(users[:2], users[2:]), False)]
+
+
+def _case_crossover(rng):
+    # one key under the crossover keeps the loop; at it, numpy
+    n = KEY_SAMPLE_NUMPY_MIN_KEYS
+    return [], [(_frame(_words8(rng, n - 1)), False),
+                (_frame(_words8(rng, n)), True)]
+
+
+def _case_union_at_the_limit(rng):
+    # the union reaches the limit exactly (no decay), then passes it by
+    # one key (decay)
+    prior = [b"user%d" % i for i in range(KEY_SAMPLE_LIMIT - 2048)]
+    first = _words8(rng, 2048, hi=2**64 - 1)
+    second = first[1:] + _words8(rng, 1, hi=2**64 - 1)
+    return prior, [(_frame(first), True), (_frame(second), True)]
+
+
+def _case_sample_from_object_frames(rng):
+    # keys of other lengths noted earlier (object frames), some 8-byte
+    # keys among them reappearing in the columnar batch
+    shared = _words8(rng, 300)
+    prior = [b"user%d" % i for i in range(200)] + [b"longer-key-%d" % i
+                                                    for i in range(200)]
+    prior += shared + shared[:100]
+    keys = shared * 3 + _words8(rng, 3000)
+    rng.shuffle(keys)
+    wider = shared + _words8(rng, 5000)  # the second batch decays
+    return prior, [(_frame(keys[:2000], keys[2000:]), True),
+                   (_frame(wider), True)]
+
+
+COLUMNAR_CASES = {
+    name[len("_case_"):]: fn
+    for name, fn in sorted(globals().items())
+    if name.startswith("_case_")
+}
+
+
+@pytest.mark.parametrize("case", sorted(COLUMNAR_CASES))
+def test_columnar_begin_keys_match_the_loop(case):
+    rng = np.random.default_rng(28)
+    prior, batches = COLUMNAR_CASES[case](rng)
+    ref, got = {}, {}
+    note_begin_keys(ref, prior)
+    note_begin_keys(got, prior)
+    for cols, fast in batches:
+        _loop_reference(ref, cols)
+        assert note_columnar_begin_keys(got, cols) is fast
+        assert list(got.items()) == list(ref.items())
 
 
 # ---------------------------------------------------------------------------
