@@ -1398,9 +1398,9 @@ class ResolverRole:
         # compile-cache counters)
         qos["kernel"] = {**self._kernel_metrics.qos(), **self._device_info}
         # per-batch stage samples in STAGE_NAMES order, then the bytes
-        # each batch hands the device, the rows it dispatches at and, on
-        # a sharded conflict set, its partition skew (the benchmark's
-        # per-layer readers)
+        # each batch hands the device, the rows it dispatches at, the
+        # fixpoint applications it ran and, on a sharded conflict set,
+        # its partition skew (the benchmark's per-layer readers)
         stages = {
             "decode": self.decode_time.as_dict(),
             "key_sample": self.key_sample_time.as_dict(),
@@ -1411,7 +1411,8 @@ class ResolverRole:
         qos["stages"] = {
             k: stages[k]
             for k in (*STAGE_NAMES, "arg_bytes", "device_arg_bytes",
-                      "dispatch_rows", "overlap", "shard_skew")
+                      "dispatch_rows", "fixpoint_iters", "overlap",
+                      "shard_skew")
             if k in stages
         }
         # columnar-vs-object frame accounting (r12): bench_pipeline

@@ -54,11 +54,12 @@ class KernelStageMetrics:
     compaction dispatch and the periodic overflow sync, which fall
     between the dispatch and the end of the issue. `arg_bytes` and
     `device_arg_bytes` sample, per transfer, the host bytes handed to
-    the device and the bytes they take there, and `dispatch_rows` the
-    read + write rows of the rung the batch was packed at. Counters are
-    event counts and deterministic per run; the periodic trace_counters
-    flush ships only those, so traced simulation output stays
-    bit-reproducible.
+    the device and the bytes they take there, `dispatch_rows` the
+    read + write rows of the rung the batch was packed at, and
+    `fixpoint_iters`, per completed batch, the intra-batch fixpoint
+    applications its kernel ran. Counters are event counts and
+    deterministic per run; the periodic trace_counters flush ships only
+    those, so traced simulation output stays bit-reproducible.
     """
 
     def __init__(self):
@@ -105,6 +106,7 @@ class KernelStageMetrics:
         self.arg_bytes = LatencySample("argBytes")
         self.device_arg_bytes = LatencySample("deviceArgBytes")
         self.dispatch_rows = LatencySample("dispatchRows")
+        self.fixpoint_iters = LatencySample("fixpointIters")
         # tier occupancy (tiered kernel): live boundary rows per tier,
         # sampled at the overflow-check syncs (no extra device fences).
         # On a MESH-SHARDED instance the samples are the WORST shard's
@@ -157,9 +159,9 @@ class KernelStageMetrics:
 
     def stages(self) -> dict:
         """The conflict set's share of the role's `qos.stages`: each
-        stage sample, the two byte samples and the dispatched rows,
-        as_dict() by status key, and on a sharded instance the
-        partition skew."""
+        stage sample, the two byte samples, the dispatched rows and the
+        fixpoint applications, as_dict() by status key, and on a sharded
+        instance the partition skew."""
         out = {
             "pack": self.pack.as_dict(),
             "transfer": self.transfer.as_dict(),
@@ -171,6 +173,7 @@ class KernelStageMetrics:
             "arg_bytes": self.arg_bytes.as_dict(),
             "device_arg_bytes": self.device_arg_bytes.as_dict(),
             "dispatch_rows": self.dispatch_rows.as_dict(),
+            "fixpoint_iters": self.fixpoint_iters.as_dict(),
         }
         if self.shard_count > 1:
             out["shard_skew"] = self.shard_skew.as_dict()

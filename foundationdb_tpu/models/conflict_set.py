@@ -132,6 +132,9 @@ def _resolve_tiered_jit(short_span_limit: int, fixpoint_unroll: int = 3,
         _TIERED_JITS[key] = fn
     return fn
 
+#: each verdict code's TransactionResult, for the reply's verdict list
+_RESULTS = {int(r): r for r in TransactionResult}
+
 #: Overflow is checked host-side every this many batches (each check
 #: forces a device sync; the merge itself is async).
 OVERFLOW_CHECK_INTERVAL = 32
@@ -148,9 +151,10 @@ def _stack_one(args: dict) -> dict:
 
 
 def _result_columns(out: _D.BatchVerdict) -> tuple:
-    """The result columns a reply is assembled from."""
+    """The result columns a reply is assembled from, and the batch's
+    fixpoint applications."""
     return (out.verdict, out.overflow, out.hist_conflict_read,
-            out.intra_first_range)
+            out.intra_first_range, out.fixpoint_iters)
 
 
 @dataclasses.dataclass
@@ -161,7 +165,8 @@ class PendingBatch:
 
     batch: packing.PackedBatch
     out: _D.BatchVerdict
-    report: list
+    #: per txn, whether it asked for the conflicting-key report
+    report: np.ndarray | list
     begin_key_of_row: Callable[[int], bytes]
     #: when the issue ended (perf_counter): the readback runs from here
     issued_at: float
@@ -323,13 +328,12 @@ class TpuConflictSet:
     ) -> PendingBatch:
         """The dispatch half of resolve_columnar_packed. The
         conflicting-key report's begin keys slice out of the blob
-        lazily — only the (rare) rows the kernel flagged are touched."""
+        lazily: only the history-hit rows of the txns that asked for
+        the report are touched."""
         return self._issue(
             batch,
-            report=[
-                bool(int(f) & packing.COLUMNAR_FLAG_REPORT)
-                for f in cols.flags
-            ],
+            report=(np.asarray(cols.flags) & packing.COLUMNAR_FLAG_REPORT)
+            != 0,
             begin_key_of_row=lambda r: packing.columnar_key(cols, r),
         )
 
@@ -398,9 +402,10 @@ class TpuConflictSet:
         t0 = time.perf_counter()
         readback_s = t0 - pending.issued_at
         self.metrics.readback.sample(readback_s)
-        verdict, overflow, hist_read, intra_first = pending.host
+        verdict, overflow, hist_read, intra_first, iters = pending.host
         if bool(overflow):
             self._raise_overflow()
+        self.metrics.fixpoint_iters.sample(int(iters))
         n = pending.batch.n_txns
         with stage(self.metrics.assemble, STAGE_NAMES["assemble"]):
             result = self._assemble(
@@ -555,6 +560,7 @@ class TpuConflictSet:
                     conflict_count=outs.conflict_count[0],
                     too_old_count=outs.too_old_count[0],
                     overflow=outs.overflow[0],
+                    fixpoint_iters=outs.fixpoint_iters[0],
                 )
         self.state = state2
         self._batches_since_check += kb - 1
@@ -883,27 +889,29 @@ class TpuConflictSet:
         `report[t]` = the txn asked for the conflicting-key report;
         `begin_key_of_row(r)` = flat read row r's range BEGIN key bytes
         (object path: through the transaction list; columnar: sliced
-        from the frame's key blob) — only the rows the kernel flagged
-        as history hits are ever touched.
+        from the frame's key blob). Only the txns that asked and
+        CONFLICTed are walked, and only their history-hit read rows are
+        touched: past the verdict list, the work grows with the reports
+        asked for, not with the batch or its history hits.
         """
-        n = batch.n_txns
-        verdicts = [TransactionResult(int(v)) for v in verdict]
-
+        verdict = np.asarray(verdict)
+        verdicts = list(map(_RESULTS.__getitem__, verdict.tolist()))
+        asked = np.asarray(report, bool) & (
+            verdict == int(TransactionResult.CONFLICT)
+        )
         conflicting: dict[int, list[int]] = {}
-        # group per-read-range history hits by txn
+        if not asked.any():
+            return BatchResult(verdicts=verdicts,
+                               conflicting_key_ranges=conflicting)
+        # the asking txns' per-read-range history hits, grouped by txn
+        read_txn = batch.read_txn[: batch.n_reads]
         hist_hits_by_txn: dict[int, list[tuple[bytes, int]]] = {}
-        for r in range(batch.n_reads):
-            if hist_read[r]:
-                t = int(batch.read_txn[r])
-                idx = int(batch.read_index[r])
-                hist_hits_by_txn.setdefault(t, []).append(
-                    (begin_key_of_row(r), idx)
-                )
-        for t in range(n):
-            if not report[t]:
-                continue
-            if verdicts[t] != TransactionResult.CONFLICT:
-                continue
+        rows = np.flatnonzero(hist_read[: batch.n_reads] & asked[read_txn])
+        for r in rows.tolist():
+            hist_hits_by_txn.setdefault(int(read_txn[r]), []).append(
+                (begin_key_of_row(r), int(batch.read_index[r]))
+            )
+        for t in np.flatnonzero(asked).tolist():
             if t in hist_hits_by_txn:
                 hits = sorted(hist_hits_by_txn[t])  # begin-key order
                 conflicting[t] = [i for _, i in hits]

@@ -42,11 +42,14 @@ scheduled by the host every `compact_interval` batches.
 
 Device-side hot-key dedup (`dedup_reads=U`): identical read conflict
 ranges are sort+unique'd and only U DISTINCT ranges probe main, so the
-binary-search traffic scales with distinct keys, not points (the zipf
-attack — a zipf-0.99 64K batch has a few thousand distinct ranges). A
-batch with more distinct live ranges than U trips the unconverged
-latch: state unchanged, host re-dispatches the exact kernel. Loud
-refusal, never a silent wrong answer.
+binary-search traffic scales with distinct keys, not points. Zipf skew
+alone does not make them few: a 64K-txn batch of 8 scrambled-zipfian
+(0.99) point reads a txn over 10M keys has about 305K distinct read
+ranges of its 524K (seeded batches of the benchmark's hot_rmw stream,
+simulated on a CPU host), so U would have to be 512K there. A batch
+with more distinct live ranges than U trips the unconverged latch:
+state unchanged, host re-dispatches the exact kernel. Loud refusal,
+never a silent wrong answer.
 
 Sorted-endpoint RANGE SWEEP (`range_sweep=True`, ISSUE 14 — the
 device-native range-overlap path): the default main-tier probe pays a
@@ -114,6 +117,8 @@ class BatchVerdict(NamedTuple):
     #   this batch's merge (or latched earlier). Surfaced in the verdict so
     #   the sync the host already pays to read verdicts also proves the
     #   history they were computed against didn't truncate (ADVICE r1).
+    fixpoint_iters: jnp.ndarray   # [] int32 — intra-batch fixpoint
+    #   applications run (GroupVerdict.fixpoint_iters)
 
 
 def init(config: KernelConfig) -> TieredState:
@@ -387,6 +392,7 @@ def resolve_group_tiered(state: TieredState, g: dict, *,
         # per-batch delta latch (capacity/span) | the main tier's own
         overflow=outs.overflow | state.main.overflow,
         unconverged=jnp.broadcast_to(trip, (gn,)),
+        fixpoint_iters=outs.fixpoint_iters,
     )
 
 

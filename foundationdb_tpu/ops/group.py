@@ -95,6 +95,9 @@ class GroupVerdict(NamedTuple):
     #   The returned STATE is the UNCHANGED input state and the verdicts
     #   are not trustworthy; the host re-dispatches with the exact
     #   (while_loop) kernel. Always False with fixpoint_latch=False.
+    fixpoint_iters: jnp.ndarray      # [G] int32 — intra-batch fixpoint
+    #   applications the batch ran: the unrolled ones plus the residual
+    #   while_loop's iterations
 
 
 def _next_pow2(n: int) -> int:
@@ -532,21 +535,23 @@ def resolve_group(state: H.VersionHistory, g: dict, *,
             return (minw < rtxn) & rlive
 
         def cond(c):
-            committed_g, prev, _h = c
+            committed_g, prev, _h, _n = c
             return jnp.any(committed_g != prev)
 
         def body(c):
-            committed_g, _prev, _h = c
+            committed_g, _prev, _h, n = c
             h = same_hits_g(committed_g)
-            return ok_g & ~per_txn(h & ok_g[rtxn]), committed_g, h
+            return ok_g & ~per_txn(h & ok_g[rtxn]), committed_g, h, n + 1
 
         if "fixpoint" in _ablate:
             committed_g = ok_g
             final_same_g = jnp.zeros((nr,), bool)
+            iters = jnp.int32(0)
         elif "fix1" in _ablate:  # diagnostic: exactly one application
             h0 = same_hits_g(ok_g)
             committed_g = ok_g & ~per_txn(h0 & ok_g[rtxn])
             final_same_g = h0 & ok_g[rtxn]
+            iters = jnp.int32(1)
         else:
             # Unrolled applications first, residual while_loop after: a
             # while ITERATION under the batch scan measured ~5x an
@@ -556,10 +561,11 @@ def resolve_group(state: H.VersionHistory, g: dict, *,
             # workload's typical convergence depth and the loop usually
             # runs ZERO iterations. Deeper chains still resolve exactly
             # in the loop — the unroll is a perf knob, never semantics.
+            unrolled = max(1, fixpoint_unroll)
             h_prev = same_hits_g(ok_g)
             c_prev = ok_g
             c_cur = ok_g & ~per_txn(h_prev & ok_g[rtxn])
-            for _ in range(max(1, fixpoint_unroll) - 1):
+            for _ in range(unrolled - 1):
                 h_prev = same_hits_g(c_cur)
                 c_prev, c_cur = c_cur, ok_g & ~per_txn(
                     h_prev & ok_g[rtxn]
@@ -574,10 +580,12 @@ def resolve_group(state: H.VersionHistory, g: dict, *,
                 # while kernel (the short_span_limit refusal pattern).
                 converged = ~jnp.any(c_cur != c_prev)
                 committed_g, last_h = c_cur, h_prev
+                iters = jnp.int32(unrolled)
             else:
-                committed_g, _, last_h = jax.lax.while_loop(
-                    cond, body, (c_cur, c_prev, h_prev)
+                committed_g, _, last_h, looped = jax.lax.while_loop(
+                    cond, body, (c_cur, c_prev, h_prev, jnp.int32(0))
                 )
+                iters = unrolled + looped
             # last_h is the hits AT the fixpoint (carried from prev ==
             # fixpoint — the round-2 kernel's argument).
             final_same_g = last_h & ok_g[rtxn]
@@ -612,7 +620,7 @@ def resolve_group(state: H.VersionHistory, g: dict, *,
         fidx = ridx[jnp.clip(p, 0, nr - 1)]
         first_g = jnp.where(tot_h > 0, fidx, INT32_POS)
         return (seg_ver, span_ok, fix_ok & converged), (
-            committed_g, final_same_g, cross_g, first_g
+            committed_g, final_same_g, cross_g, first_g, iters
         )
 
     # The initial carry must inherit the axis-varying type of the traced
@@ -630,9 +638,9 @@ def resolve_group(state: H.VersionHistory, g: dict, *,
         too_old2, txn_valid2, read_index2, versions,
         win_lo - lane_base, win_hi - lane_base,
     )
-    (seg_ver, span_ok, fix_ok), (committed2, same2, cross2, first2) = (
-        jax.lax.scan(batch_step, (seg_ver0, span_ok, fix_ok0), xs)
-    )
+    (seg_ver, span_ok, fix_ok), (
+        committed2, same2, cross2, first2, fixpoint_iters
+    ) = jax.lax.scan(batch_step, (seg_ver0, span_ok, fix_ok0), xs)
     committed = committed2.reshape(-1)
     final_same = same2.reshape(-1)
     # The cross-batch report is NOT masked by `ok`: sequentially these
@@ -748,5 +756,6 @@ def resolve_group(state: H.VersionHistory, g: dict, *,
         too_old_count=too_old_count,
         overflow=jnp.broadcast_to(overflow, (gn,)),
         unconverged=jnp.broadcast_to(unconv, (gn,)),
+        fixpoint_iters=fixpoint_iters,
     )
     return new_state, out

@@ -299,6 +299,8 @@ def _shard_resolve_group_tiered(state, g: dict, lo, hi, *,
     )
     # dedup/fixpoint latch: ANY shard tripping refuses the whole group
     trip_any = jax.lax.pmax(trip.astype(jnp.int32), axis) > 0
+    # the deepest shard's fixpoint is the batch's
+    fixpoint_iters = jax.lax.pmax(outs.fixpoint_iters, axis)
 
     # decision counts from the COMBINED verdict (a local count would
     # count phantom commits): TransactionResult CONFLICT=0 / TOO_OLD=1 /
@@ -334,6 +336,7 @@ def _shard_resolve_group_tiered(state, g: dict, lo, hi, *,
         too_old_count=too_old,
         overflow=overflow,
         unconverged=jnp.broadcast_to(trip_any, (gn,)),
+        fixpoint_iters=fixpoint_iters,
     )
 
 

@@ -39,7 +39,7 @@ TINY_KERNEL = (
 BATCHES = 34
 PER_BATCH = ("decode", "key_sample", "pack", "transfer", "dispatch",
              "readback", "assemble", "encode", "arg_bytes",
-             "device_arg_bytes", "dispatch_rows")
+             "device_arg_bytes", "dispatch_rows", "fixpoint_iters")
 #: the stages inside ResolverRole._resolve_now (computeTime)
 IN_COMPUTE = ("key_sample", "pack", "transfer", "dispatch", "compact",
               "overflow_check", "readback", "assemble")
@@ -115,7 +115,7 @@ def test_status_lists_the_stages_in_batch_order(served):
     _role, qos, _reqs = served
     assert list(qos["stages"]) == [*STAGE_NAMES, "arg_bytes",
                                    "device_arg_bytes", "dispatch_rows",
-                                   "overlap"]
+                                   "fixpoint_iters", "overlap"]
 
 
 @pytest.mark.parametrize("key", PER_BATCH)
